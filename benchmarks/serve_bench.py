@@ -1031,6 +1031,8 @@ def main(argv=None) -> int:
             print(f"# {args.validate}: valid schema v{SCHEMA_VERSION}")
         return 1 if problems else 0
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rec = run(smoke=args.smoke, quantize="int8" if args.int8 else None,
               n_slots=args.slots, chunk=args.chunk,
               autotune_cache=args.autotune_cache, sharded=args.sharded)

@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.importer import load_graph, save_graph
 from repro.core.ir import Graph, Node, TensorSpec, topological_order
@@ -73,8 +74,9 @@ class Program:
     """
 
     def __init__(self, graph: Graph, assignment: Mapping[str, str],
-                 pass_stats: Sequence[PassStats] = ()):
+                 pass_stats: Sequence[PassStats] = (), mesh: Any = None):
         from repro.core.passes import infer_shapes
+        self._mesh = mesh
         # freeze the partition layout stamped by the `partition` pass before
         # any Graph rebuild below can drop the dynamic attributes
         part_specs = getattr(graph, "partition_specs", None)
@@ -172,10 +174,18 @@ class Program:
     def _stored_params(self) -> Dict[str, Any]:
         """Device copies of the graph params, built once and shared by
         every entry point (``__call__`` and each ``bind()``) so N bound
-        callables don't hold N copies of the weights."""
+        callables don't hold N copies of the weights.  A Program compiled
+        for a mesh places each param on its partition's NamedSharding, so
+        no call has to move weights between devices."""
         if self._stored is None:
-            self._stored = {k: jnp.asarray(v)
-                            for k, v in self._graph.params.items()}
+            params = self._graph.params.items()
+            if self._mesh is not None and self._partition is not None:
+                specs = self._partition["specs"]
+                self._stored = {k: jax.device_put(
+                    v, jax.sharding.NamedSharding(self._mesh, specs[k]))
+                    for k, v in params}
+            else:
+                self._stored = {k: jnp.asarray(v) for k, v in params}
         return self._stored
 
     def __call__(self, **inputs: Any) -> Tuple[Any, ...]:
@@ -230,7 +240,10 @@ class Program:
     # ------------------------------------------------------------------ #
     def lower(self, **input_specs: jax.ShapeDtypeStruct):
         """``jax.jit(...).lower(...)`` for dry-run / cost analysis."""
-        stored = {k: jax.ShapeDtypeStruct(jnp.shape(v), jnp.asarray(v).dtype)
+        # shapes only: no param is copied to a device to be lowered
+        stored = {k: jax.ShapeDtypeStruct(
+                      np.shape(v),
+                      jax.dtypes.canonicalize_dtype(np.result_type(v)))
                   for k, v in self._graph.params.items()}
         specs = input_specs or {
             k: jax.ShapeDtypeStruct(s.shape, jnp.dtype(s.dtype))
@@ -321,6 +334,7 @@ class Program:
             from repro.sharding.specs import check_mesh_compat
             check_mesh_compat(part["mesh"], mesh)
         prog = compile(g, policy=policy, pipeline=())
+        prog._mesh = mesh
         prog._partition = {
             "mesh": MappingProxyType(
                 {a: int(s) for a, s in part["mesh"].items()}),
@@ -412,4 +426,4 @@ def compile(graph: Graph, policy: Optional[BackendPolicy] = None,
     for node in topological_order(g):
         in_specs = [g.spec_of(v) for v in node.inputs]
         assignment[node.name] = policy.resolve(node, in_specs)
-    return Program(g, assignment, pass_stats=pass_stats)
+    return Program(g, assignment, pass_stats=pass_stats, mesh=mesh)

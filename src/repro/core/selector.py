@@ -307,27 +307,26 @@ class AutotunePolicy(BackendPolicy):
         Incremental against the (possibly preloaded) cache: only backends
         with no cached timing are benchmarked, so a cache written under a
         different ``candidates`` restriction is topped up rather than
-        trusted blindly.  Unrunnable backends are recorded as ``inf`` so
-        they are not retried every compile.  The returned dict is filtered
-        to the current candidate set."""
+        trusted blindly.  ``supports()`` (via ``backends_for``) is the only
+        filter: a backend it admits that then fails to compile or run is a
+        bug, and its exception propagates instead of silently dropping the
+        backend out of the choice.  The returned dict is filtered to the
+        current candidate set."""
         key = _sig_key(op, in_specs, attrs)
         avail = backends_for(op, in_specs, attrs)
         if self.candidates is not None:
             avail = [b for b in avail if b in self.candidates]
-        times = dict(self._timings.get(key, {}))
+        # an ``inf`` in a cache file written before failures propagated
+        # marks a backend that was never measured: measure it now
+        times = {b: t for b, t in self._timings.get(key, {}).items()
+                 if t != float("inf")}
         missing = [b for b in avail if b not in times]
         if missing:
             inputs = self._random_inputs(in_specs)
             for b in missing:
                 fn = get_impl(op, b)
                 jf = jax.jit(lambda args: fn(args, attrs))
-                try:
-                    res = jf(inputs)
-                    jax.block_until_ready(res)
-                except Exception:
-                    # backend cannot execute on this platform; remember that
-                    times[b] = float("inf")
-                    continue
+                jax.block_until_ready(jf(inputs))      # compile + warm up
                 best = float("inf")
                 for _ in range(self.reps):
                     t0 = time.perf_counter()
@@ -338,8 +337,7 @@ class AutotunePolicy(BackendPolicy):
             self.n_measured += 1
             if self.cache_path:
                 self._save_cache()
-        return {b: t for b, t in times.items()
-                if b in avail and t != float("inf")}
+        return {b: t for b, t in times.items() if b in avail}
 
     def choose(self, node: Node, in_specs: Sequence[TensorSpec]) -> str:
         key = _sig_key(node.op, in_specs, node.attrs)

@@ -152,11 +152,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def _chunk_flash_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, *,
                         scale: float, bq: int, bkv: int, n_kv_blocks: int,
-                        ksc_ref=None, vsc_ref=None):
+                        k_scale=None, v_scale=None):
     """Same online-softmax recurrence as :func:`_flash_kernel`, with the
     query offset a per-sequence runtime value: query row t of the chunk
     sits at absolute position ``start + t`` and attends cache columns
-    ``<= start + t`` (offset-causal).  ``start`` arrives via SMEM."""
+    ``<= start + t`` (offset-causal).  The whole (B*Hq,) ``start`` array
+    sits in SMEM and grid row bh reads entry bh."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -166,7 +167,7 @@ def _chunk_flash_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = start_ref[0]
+    start = start_ref[pl.program_id(0)]
     row0 = start + qi * bq                      # abs position of block row 0
     col0 = ki * bkv
     # the block is live iff its max row position reaches its min column
@@ -177,11 +178,11 @@ def _chunk_flash_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
         k = k_ref[0].astype(jnp.float32)                  # (bkv, D)
         v = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:
+        if k_scale is not None:
             # int8 tiles: dequantize in-register with the per-(page, head)
-            # scalar that rode along in SMEM — no fp32 cache copy exists
-            k = k * ksc_ref[0]
-            v = v * vsc_ref[0]
+            # scalars read from SMEM — no fp32 cache copy exists
+            k = k * k_scale
+            v = v * v_scale
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (bq,bkv)
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
@@ -247,8 +248,7 @@ def flash_chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid=(b * hq, nq, nkv),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, qi, ki: (bh,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # whole (B*Hq,) start
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bkv, d), kv_map),
             pl.BlockSpec((1, bkv, dv), kv_map),
@@ -271,8 +271,9 @@ def flash_chunk_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # PHYSICAL page block_tables[b, pi], reached via a scalar-prefetched index
 # map (same trick as flash_decode.flash_paged_decode); the dense gather
 # copy the ref/xla paged backends pay never exists here.  Optional int8
-# mode: per-(page, head) scales ride along in SMEM through the same table
-# indices and dequant happens in-register inside the online-softmax loop.
+# mode: the per-(page, head) scale sidecars sit whole in SMEM, each grid
+# step reads its tile's scalar through the same table lookup, and dequant
+# happens in-register inside the online-softmax loop.
 # --------------------------------------------------------------------------- #
 
 def _paged_chunk_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
@@ -286,12 +287,16 @@ def _paged_chunk_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _paged_chunk_q_kernel(bt_ref, start_ref, q_ref, k_ref, ksc_ref, v_ref,
                           vsc_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                          scale: float, bq: int, page: int, n_pages: int):
-    del bt_ref
+                          scale: float, bq: int, page: int, n_pages: int,
+                          hq: int, hkv: int):
+    # the (N*Hk,) scale sidecars sit whole in SMEM; read the scalar of the
+    # (block, kv head) tile the K/V index map fetched for this grid step
+    bh, pi = pl.program_id(0), pl.program_id(2)
+    row = bt_ref[bh // hq, pi] * hkv + (bh % hq) // (hq // hkv)
     _chunk_flash_kernel(start_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, scale=scale, bq=bq,
                         bkv=page, n_kv_blocks=n_pages,
-                        ksc_ref=ksc_ref, vsc_ref=vsc_ref)
+                        k_scale=ksc_ref[row], v_scale=vsc_ref[row])
 
 
 def flash_paged_chunk_attention(q: jax.Array, pages_k: jax.Array,
@@ -342,29 +347,28 @@ def flash_paged_chunk_attention(q: jax.Array, pages_k: jax.Array,
         # physical (block, head) row: sequence bh//Hq, kv head of q head
         return (bt[bh // hq, pi] * hkv + (bh % hq) // group, 0, 0)
 
-    def sc_map(bh, qi, pi, bt):
-        return (bt[bh // hq, pi] * hkv + (bh % hq) // group,)
-
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole array in SMEM
     in_specs = [
-        pl.BlockSpec((1,), lambda bh, qi, pi, bt: (bh,),
-                     memory_space=pltpu.SMEM),
+        smem,                                       # (B*Hq,) start
         pl.BlockSpec((1, bq, d), q_map),
         pl.BlockSpec((1, page, d), kv_map),
         pl.BlockSpec((1, page, dv), kv_map),
     ]
     operands = [start_r, qr, kr, vr]
+    kw = {}
     if quant:
-        sc_spec = pl.BlockSpec((1,), sc_map, memory_space=pltpu.SMEM)
-        in_specs = in_specs[:3] + [sc_spec, in_specs[3], sc_spec]
+        # (N*Hk,) float32 sidecars, 8*N*Hk bytes of SMEM for the pair
+        in_specs = in_specs[:3] + [smem, in_specs[3], smem]
         operands = [start_r, qr, kr,
                     jnp.asarray(k_scales, jnp.float32).reshape(-1),
                     vr, jnp.asarray(v_scales, jnp.float32).reshape(-1)]
         body = _paged_chunk_q_kernel
+        kw = {"hq": hq, "hkv": hkv}
     else:
         body = _paged_chunk_kernel
 
     kernel = functools.partial(body, scale=scale, bq=bq, page=page,
-                               n_pages=n_pages)
+                               n_pages=n_pages, **kw)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                     # the block table
         grid=(b * hq, nq, n_pages),

@@ -13,8 +13,9 @@ TPU adaptation of GPU "flash decoding":
   MXU/VPU some sublane parallelism (group is 1..32 across our archs).
 * Grid = (B * Hkv, Skv/bkv), KV axis innermost and sequential; acc/m/l
   scratch carries across KV blocks.
-* Variable cache lengths are masked via a per-sequence length operand
-  (block (1,1) int32 in SMEM).
+* Variable cache lengths are masked via a per-(batch, kv head) length
+  operand: the whole (B*Hkv,) int32 array sits in SMEM and grid row bh
+  reads entry bh (Mosaic refuses rank-1 SMEM blocks smaller than 128).
 * The kernel also emits its running (m, l) so callers can combine partials
   across devices — this is the building block of the sequence-parallel
   "tree decode" in ``repro/sharding/collectives.py`` (KV cache sharded over
@@ -40,7 +41,7 @@ _NEG_INF = -1e30
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                    acc_ref, m_ref, l_ref, *,
                    scale: float, bkv: int, n_kv_blocks: int, emit_stats: bool,
-                   ksc_ref=None, vsc_ref=None):
+                   k_scale=None, v_scale=None):
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -49,7 +50,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     live = (ki * bkv) < length  # block has at least one valid entry
 
     @pl.when(live)
@@ -57,11 +58,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         q = q_ref[0].astype(jnp.float32) * scale        # (group, D)
         k = k_ref[0].astype(jnp.float32)                # (bkv, D)
         v = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:
+        if k_scale is not None:
             # int8 tiles: dequantize in-register with the per-(page, head)
-            # scalar that rode along in SMEM — no fp32 cache copy exists
-            k = k * ksc_ref[0]
-            v = v * vsc_ref[0]
+            # scalars read from SMEM — no fp32 cache copy exists
+            k = k * k_scale
+            v = v * v_scale
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (group,bkv)
         cols = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -116,7 +117,7 @@ def _flash_decode(q, k, v, lengths, scale, block_kv, interpret, emit_stats):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, ki: (bh,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # whole (B*Hkv,) lengths
             pl.BlockSpec((1, group, d), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, bkv, d), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, bkv, dv), lambda bh, ki: (bh, ki, 0)),
@@ -192,15 +193,17 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref,
 def _paged_decode_q_kernel(bt_ref, len_ref, q_ref, k_ref, ksc_ref, v_ref,
                            vsc_ref, o_ref, m_out_ref, l_out_ref,
                            acc_ref, m_ref, l_ref,
-                           *, scale: float, page: int, n_pages: int):
-    # int8 variant: the per-(page, head) dequant scales follow the same
-    # scalar-prefetched table indices as the K/V tiles, one SMEM scalar
-    # per grid step; dequant happens inside _decode_kernel's compute body.
-    del bt_ref
+                           *, scale: float, page: int, n_pages: int, hkv: int):
+    # int8 variant: the whole flattened (N*Hkv,) scale sidecars sit in
+    # SMEM and each grid step reads the scalar of the (block, head) tile
+    # its K/V index map fetched — same table lookup, done here by hand;
+    # dequant happens inside _decode_kernel's compute body.
+    bh, pi = pl.program_id(0), pl.program_id(1)
+    row = bt_ref[bh // hkv, pi] * hkv + bh % hkv
     _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                    acc_ref, m_ref, l_ref, scale=scale, bkv=page,
                    n_kv_blocks=n_pages, emit_stats=False,
-                   ksc_ref=ksc_ref, vsc_ref=vsc_ref)
+                   k_scale=ksc_ref[row], v_scale=vsc_ref[row])
 
 
 def flash_paged_decode(q: jax.Array, pages_k: jax.Array, pages_v: jax.Array,
@@ -244,30 +247,28 @@ def flash_paged_decode(q: jax.Array, pages_k: jax.Array, pages_v: jax.Array,
         # physical (block, head) row of logical page pi of sequence bh//Hkv
         return (bt[bh // hkv, pi] * hkv + bh % hkv, 0, 0)
 
-    def sc_map(bh, pi, bt):
-        # the matching scalar in the flattened (N*Hkv,) scale sidecar
-        return (bt[bh // hkv, pi] * hkv + bh % hkv,)
-
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole array in SMEM
     in_specs = [
-        pl.BlockSpec((1,), lambda bh, pi, bt: (bh,),
-                     memory_space=pltpu.SMEM),
+        smem,                                       # (B*Hkv,) lengths
         pl.BlockSpec((1, group, d), lambda bh, pi, bt: (bh, 0, 0)),
         pl.BlockSpec((1, page, d), kv_map),
         pl.BlockSpec((1, page, dv), kv_map),
     ]
     operands = [len_r, qr, kr, vr]
+    kw = {}
     if quant:
-        sc_spec = pl.BlockSpec((1,), sc_map, memory_space=pltpu.SMEM)
-        in_specs = in_specs[:3] + [sc_spec, in_specs[3], sc_spec]
+        # (N*Hkv,) float32 sidecars, 8*N*Hkv bytes of SMEM for the pair
+        in_specs = in_specs[:3] + [smem, in_specs[3], smem]
         operands = [len_r, qr, kr,
                     jnp.asarray(k_scales, jnp.float32).reshape(-1),
                     vr, jnp.asarray(v_scales, jnp.float32).reshape(-1)]
         body = _paged_decode_q_kernel
+        kw = {"hkv": hkv}
     else:
         body = _paged_decode_kernel
 
     kernel = functools.partial(body, scale=scale, page=page,
-                               n_pages=n_pages)
+                               n_pages=n_pages, **kw)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                     # the block table
         grid=(b * hkv, n_pages),

@@ -9,7 +9,6 @@ and benches see the real single CPU device).
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_serving_mesh"]
 
@@ -34,24 +33,9 @@ def make_test_mesh(data: int = 2, model: int = 2):
 def make_serving_mesh(tp: int = 1, *, devices=None):
     """1-D ("model",) mesh over the first ``tp`` local devices — the shape
     the tensor-parallel serving engine wants (``build_lm_serving(tp=...)``
-    and the ``--tp`` launch knob).
-
-    Version-portable: ``make_production_mesh``/``make_test_mesh`` need the
-    explicit-sharding ``axis_types`` API of modern jax, but TP serving
-    must also run where only the legacy ``Mesh`` constructor exists (and
-    in the forced-host-device exactness tests on either), so this tries
-    the modern spellings first and falls back."""
+    and the ``--tp`` launch knob)."""
     devs = list(devices) if devices is not None else jax.devices()
     if tp < 1 or tp > len(devs):
         raise ValueError(f"tp={tp} needs 1..{len(devs)} devices")
-    try:
-        return jax.make_mesh(
-            (tp,), ("model",), devices=devs[:tp],
-            axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.make_mesh((tp,), ("model",), devices=devs[:tp])
-    except (AttributeError, TypeError):
-        pass
-    return jax.sharding.Mesh(np.asarray(devs[:tp]).reshape(tp), ("model",))
+    return jax.make_mesh((tp,), ("model",), devices=devs[:tp],
+                         axis_types=(jax.sharding.AxisType.Auto,))

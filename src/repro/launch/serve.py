@@ -117,6 +117,8 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.engine:
         run_engine(args)
         return

@@ -1656,7 +1656,11 @@ class UnbatchedReference:
 
     def generate(self, prompt: np.ndarray, max_new_tokens: int, *,
                  chunk: Optional[int] = None, eos_id: int = -1,
-                 record: Optional[List] = None) -> List[int]:
+                 record: Optional[List] = None,
+                 logits_out: Optional[List[np.ndarray]] = None) -> List[int]:
+        """Greedy tokens for ``prompt``.  ``record`` receives every Program
+        call's inputs (calibration); ``logits_out`` receives, per generated
+        token, the (V,) logits row it was chosen from."""
         prompt = np.asarray(prompt, np.int32)
         if len(prompt) == 0 or max_new_tokens < 1:
             raise ValueError("need a non-empty prompt and max_new_tokens >= 1")
@@ -1672,10 +1676,11 @@ class UnbatchedReference:
                   init_cache_inputs(self.cfg, 1, self.cache_cap).items()}
 
         def call(fn, outs, tokens, start, n_new, kind):
-            inputs = {"tokens": tokens, "start": start, "n_new": n_new,
-                      **{k: np.asarray(v) for k, v in caches.items()}}
             if record is not None:
-                record.append((kind, inputs))
+                # host copies of the caches, read before the call donates them
+                record.append((kind, {
+                    "tokens": tokens, "start": start, "n_new": n_new,
+                    **{k: np.asarray(v) for k, v in caches.items()}}))
             res = fn(jnp.asarray(tokens), jnp.asarray(start),
                      jnp.asarray(n_new), *[caches[k] for k in sorted(caches)])
             for name, arr in zip(outs, res[1:]):
@@ -1692,7 +1697,10 @@ class UnbatchedReference:
                           toks, np.asarray([pos], np.int32),
                           np.asarray([n], np.int32), "prefill")
             pos += n
-        out = [int(np.argmax(logits[0, n - 1]))]
+        row = logits[0, n - 1]
+        out = [int(np.argmax(row))]
+        if logits_out is not None:
+            logits_out.append(row.copy())
         dec, dec_outs = self._decode_fn()
         length = len(prompt)
         while out[-1] != eos_id and len(out) < max_new_tokens:
@@ -1702,6 +1710,8 @@ class UnbatchedReference:
                           np.asarray([1], np.int32), "decode")
             length += 1
             out.append(int(np.argmax(logits[0])))
+            if logits_out is not None:
+                logits_out.append(logits[0].copy())
         return out
 
 

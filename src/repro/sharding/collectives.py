@@ -13,12 +13,11 @@
   ``collective_permute`` (the classic ring schedule that hides comm behind
   MXU work on TPU).
 
-* ``shard_map_compat`` / ``replicate`` / ``allgather_bytes`` — the pieces
-  the serving engine's tensor-parallel attention backends are built on
-  (``kernels/serving_ops.py``'s ``tp`` impls): a version-portable
-  shard_map, a with_sharding_constraint that forces an (exact) all-gather
-  of the head-sharded attention output, and the cost-model accounting for
-  that gather's traffic.
+* ``replicate`` / ``allgather_bytes`` — the pieces the serving engine's
+  tensor-parallel attention backends (``kernels/serving_ops.py``'s ``tp``
+  impls) use beside ``jax.shard_map``: a with_sharding_constraint that
+  forces an (exact) all-gather of the head-sharded attention output, and
+  the cost-model accounting for that gather's traffic.
 """
 
 from __future__ import annotations
@@ -34,29 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels.ops import decode_attention_partial
 
 __all__ = ["tree_decode_attention", "ring_allgather_matmul",
-           "shard_map_compat", "replicate", "allgather_bytes"]
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Modern jax exposes ``jax.shard_map`` (vma-checked); older releases
-    only have ``jax.experimental.shard_map.shard_map`` (rep-checked).  The
-    serving bodies are per-head-local closures over host scalars, which
-    neither checker can see through, so the static replication check is
-    disabled in both forms (the engine's token-identity tests verify the
-    numerics end to end)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # pre-vma signature spells it check_rep
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+           "replicate", "allgather_bytes"]
 
 
 def replicate(x: jax.Array, mesh: Mesh) -> jax.Array:

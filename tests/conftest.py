@@ -19,25 +19,10 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Subprocess multi-device tests force virtual host devices via XLA_FLAGS,
-# so raw device count is not the limiting condition — the mesh code some
-# of them drive is: the explicit-sharding API (jax.sharding.AxisType,
-# jax.make_mesh(axis_types=...)), which this host's jax may predate.
-# Encoding the real condition here keeps local `pytest -x -q` and CI in
-# agreement without a deselect list.  Tests that only need the
-# version-portable serving path (shard_map_compat / make_serving_mesh)
-# run everywhere and should NOT carry this marker.
-multidev = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="needs jax.sharding.AxisType (explicit-sharding mesh API); "
-           "this jax predates it")
-
 
 def run_sub(code: str, n_dev: int = 8, timeout: int = 560) -> str:
     """Run ``code`` in a fresh interpreter with ``n_dev`` forced host
@@ -85,10 +70,7 @@ def engine_variants(*names):
     ``@pytest.mark.parametrize("variant,engine_kw", engine_variants(...))``.
     No names selects every variant.  Tests that include ``tp2`` must
     dispatch through :func:`run_sub` (a TP engine cannot build in the
-    single-device test process); the serving TP path itself is built on
-    the version-portable shard_map_compat mesh, so ``tp2`` carries no
-    :data:`multidev` version skip — only suites driving the
-    explicit-sharding API need that marker."""
+    single-device test process)."""
     out = []
     for name in names or tuple(ENGINE_VARIANTS):
         out.append(pytest.param(name, dict(ENGINE_VARIANTS[name]), id=name))

@@ -192,3 +192,86 @@ def test_gemm_bf16_accumulates_f32():
     ref = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
                                rtol=2e-2, atol=2e-1)
+
+
+# --------------------------------------------------------------------------- #
+# SMEM operand layouts: per-row lengths/starts are whole arrays indexed by the
+# grid row, and int8 scale sidecars are whole arrays indexed through the block
+# table.  Every row gets a different length/start and every (page, head) tile
+# a different scale, so reading any other entry than its own breaks parity.
+# --------------------------------------------------------------------------- #
+
+from repro.kernels.flash_attention import (flash_chunk_attention,  # noqa: E402
+                                           flash_paged_chunk_attention)
+from repro.kernels.flash_decode import flash_paged_decode  # noqa: E402
+from repro.kernels.serving_ops import chunk_attention  # noqa: E402
+
+
+@pytest.mark.parametrize("lengths", [(1, 23, 64, 40), (64, 40, 23, 1)])
+def test_flash_decode_lengths_indexed_by_grid_row(lengths):
+    q, k, v = randn((4, 4, 32)), randn((4, 64, 2, 32)), randn((4, 64, 2, 32))
+    lens = jnp.asarray(lengths, jnp.int32)
+    out = flash_decode(q, k, v, lens, block_kv=16, interpret=True)
+    ref = R.decode_attention_ref(q, k, v, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("starts", [(0, 9, 56, 17), (56, 17, 0, 9)])
+def test_flash_chunk_attention_starts_indexed_by_grid_row(starts):
+    q, k, v = randn((4, 8, 4, 32)), randn((4, 64, 2, 32)), randn((4, 64, 2, 32))
+    start = jnp.asarray(starts, jnp.int32)
+    out = flash_chunk_attention(q, k, v, start, block_q=8, block_kv=16,
+                                interpret=True)
+    ref = chunk_attention(q, k, v, start, backend="ref")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def _int8_pool(n_blocks, page, hk, d):
+    """int8 pages with a distinct scale for every (page, kv head) tile."""
+    pk = jnp.asarray(rng.integers(-127, 128, (n_blocks, page, hk, d)), jnp.int8)
+    pv = jnp.asarray(rng.integers(-127, 128, (n_blocks, page, hk, d)), jnp.int8)
+    n = n_blocks * hk
+    # dequantized values stay within +-2, every tile on its own scale
+    ks, vs = [jnp.asarray((1 + rng.permutation(n)).reshape(n_blocks, hk)
+                          * (2.0 / (127 * n)), jnp.float32) for _ in "kv"]
+    return pk, ks, pv, vs
+
+
+def _dequant_gather(pages, scales, tables):
+    """(B, MP*P, H, D) fp32 view of each sequence's int8 pages."""
+    g = jnp.take(pages, tables, axis=0).astype(jnp.float32)
+    g = g * jnp.take(scales, tables, axis=0)[:, :, None, :, None]
+    return g.reshape(tables.shape[0], -1, *pages.shape[2:])
+
+
+@pytest.mark.parametrize("hq,hk", [(4, 2), (4, 4)])
+def test_flash_paged_decode_int8_sidecar_rows(hq, hk):
+    n_blocks, page, d, b, mp = 12, 8, 32, 3, 4
+    pk, ks, pv, vs = _int8_pool(n_blocks, page, hk, d)
+    tables = jnp.asarray(rng.permutation(n_blocks)[:b * mp].reshape(b, mp),
+                         jnp.int32)
+    lens = jnp.asarray([5, 32, 19], jnp.int32)
+    q = randn((b, hq, d))
+    out = flash_paged_decode(q, pk, pv, tables, lens, k_scales=ks,
+                             v_scales=vs, interpret=True)
+    ref = R.decode_attention_ref(q, _dequant_gather(pk, ks, tables),
+                                 _dequant_gather(pv, vs, tables), lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-4)
+
+
+@pytest.mark.parametrize("hq,hk", [(4, 2), (4, 4)])
+def test_flash_paged_chunk_int8_sidecar_rows(hq, hk):
+    n_blocks, page, d, b, mp, t = 12, 8, 32, 3, 4, 8
+    pk, ks, pv, vs = _int8_pool(n_blocks, page, hk, d)
+    tables = jnp.asarray(rng.permutation(n_blocks)[:b * mp].reshape(b, mp),
+                         jnp.int32)
+    start = jnp.asarray([0, 24, 11], jnp.int32)
+    q = randn((b, t, hq, d))
+    out = flash_paged_chunk_attention(q, pk, pv, tables, start, k_scales=ks,
+                                      v_scales=vs, interpret=True)
+    ref = chunk_attention(q, _dequant_gather(pk, ks, tables),
+                          _dequant_gather(pv, vs, tables), start,
+                          backend="ref")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-4)

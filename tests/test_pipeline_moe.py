@@ -9,7 +9,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.models.lm import LM
-from tests.test_sharding_multidev import multidev, run_sub
+from tests.test_sharding_multidev import run_sub
 
 
 class TestMoEDispatchModes:
@@ -48,7 +48,6 @@ class TestMoEDispatchModes:
 
 
 class TestPipeline:
-    @multidev
     def test_pipeline_matches_sequential(self):
         run_sub("""
 import numpy as np, jax, jax.numpy as jnp
@@ -69,7 +68,6 @@ assert float(jnp.abs(y - ref).max()) < 1e-5
 print("OK")
 """)
 
-    @multidev
     def test_seq_shard_decode_matches_replicated(self):
         """The §Perf seq-shard cache fallback must be numerics-neutral."""
         run_sub("""

@@ -109,3 +109,28 @@ def test_pinned_unsupported_backend_raises():
     node.backend = "pallas"
     with pytest.raises(ValueError, match="pinned backend"):
         FixedPolicy().resolve(node, specs)
+
+
+def test_autotune_measure_raises_when_admitted_backend_fails(monkeypatch):
+    """supports() is the only filter: a backend it admits that then fails
+    to run is an error, not a silent ``inf`` that drops it from the
+    choice (on a chip that would hide a kernel Mosaic refuses)."""
+    from repro.core.registry import OpImpl, get_op
+
+    def broken(inputs, attrs):
+        raise RuntimeError("kernel refused by the compiler")
+
+    node = Node("a", "chunk_attention", ["q", "k", "v", "s"], ["o"])
+    specs = [TensorSpec((1, 2, 2, 4), "float32"),
+             TensorSpec((1, 8, 1, 4), "float32"),
+             TensorSpec((1, 8, 1, 4), "float32"),
+             TensorSpec((1,), "int32")]
+    monkeypatch.setitem(get_op("chunk_attention").impls, "broken",
+                        OpImpl("chunk_attention", "broken", broken,
+                               supports=lambda specs, attrs: True))
+    assert "broken" in backends_for(node.op, specs, node.attrs)
+    pol = AutotunePolicy(reps=1, candidates=("xla", "broken"))
+    with pytest.raises(RuntimeError, match="refused"):
+        pol.measure(node.op, specs, node.attrs)
+    with pytest.raises(RuntimeError, match="refused"):
+        pol.resolve(node, specs)

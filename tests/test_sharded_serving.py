@@ -3,10 +3,7 @@ plus the engine's multi-device path.
 
 Everything runs in SUBPROCESSES with forced host devices (the main test
 process keeps the real single CPU device, per the dry-run isolation
-rule).  Unlike ``test_sharding_multidev.py`` these tests carry no
-version skip: the serving stack is built on the version-portable
-``shard_map_compat`` / ``make_serving_mesh``, so the exactness bar holds
-on every jax the repo supports.
+rule).
 
 The bar is strict token IDENTITY, not closeness: the TP=2 engine must
 emit exactly the single-device engine's tokens for dense, paged-fp32

@@ -7,7 +7,7 @@ Covers: TP/DP train-step numerics vs single-device, tree-decode
 all-gather matmul, and the dry-run cell machinery on a small mesh.
 """
 
-from conftest import multidev, run_sub
+from conftest import run_sub
 
 
 PREAMBLE = """
@@ -19,7 +19,6 @@ mesh = jax.make_mesh((2, 4), ("data", "model"),
 """
 
 
-@multidev
 def test_sharded_train_step_matches_single_device():
     run_sub(PREAMBLE + """
 from repro.configs import get_reduced
@@ -58,7 +57,6 @@ print("OK train", err)
 """)
 
 
-@multidev
 def test_tree_decode_matches_dense():
     run_sub(PREAMBLE + """
 from repro.sharding.collectives import tree_decode_attention
@@ -78,7 +76,6 @@ print("OK tree-decode", err)
 """)
 
 
-@multidev
 def test_compressed_psum_and_ring_matmul():
     run_sub(PREAMBLE + """
 from repro.optim.compress import compressed_psum_mean
@@ -105,7 +102,6 @@ print("OK compress+ring", err)
 """)
 
 
-@multidev
 def test_dryrun_cell_machinery_small_mesh():
     """build_cell -> lower -> compile -> cost/memory/collective parse, on a
     (2,4) mesh with reduced configs — the dry-run pipeline end-to-end."""
@@ -134,7 +130,6 @@ for name in ["stablelm-12b", "qwen2-moe-a2.7b", "mamba2-370m"]:
 """)
 
 
-@multidev
 def test_elastic_reshard_across_meshes():
     """Save on a (2,4) mesh, restore onto (4,2) and (8,1) — values equal."""
     run_sub(PREAMBLE + """

@@ -124,10 +124,6 @@ class TestCheckpoint:
         mgr.wait()
         assert mgr.latest_step() == 3
 
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="needs jax.sharding.AxisType (explicit-sharding mesh API); "
-               "this jax predates it")
     def test_elastic_restore_different_sharding(self, tmp_path):
         """Checkpoint written 'on one mesh', restored with explicit new
         shardings (single-device here; the reshard path is device_put)."""
