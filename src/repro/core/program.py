@@ -151,7 +151,10 @@ class Program:
         for node in self._order:
             fn = get_impl(node.op, self._assignment[node.name])
             args = [env[v] for v in node.inputs]
-            outs = fn(args, node.attrs)
+            # every XLA op of the node carries ``<op>/<node name>`` in its
+            # op_name metadata, so a device trace names the node it ran for
+            with jax.named_scope(node.op), jax.named_scope(node.name):
+                outs = fn(args, node.attrs)
             for v, val in zip(node.outputs, outs):
                 env[v] = val
         return tuple(env[v] for v in self._graph.outputs)
@@ -230,6 +233,9 @@ class Program:
         def positional(params: Dict[str, Any], *args: Any) -> Tuple[Any, ...]:
             return self._trace(params, dict(zip(order, args)))
 
+        # the compiled module, and so each device op in a trace, is named
+        # after the graph (``jit_graph_lm_paged_decode_b16_t1``)
+        positional.__name__ = positional.__qualname__ = self._graph.name
         jf = jax.jit(positional, donate_argnums=donate_argnums)
 
         def fast(*args: Any) -> Tuple[Any, ...]:

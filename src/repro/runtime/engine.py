@@ -385,13 +385,28 @@ class ProgramStepper:
                     jax.sharding.NamedSharding(self.mesh, specs[k]))
                 for k, v in caches.items()}
 
-    def _call(self, fn, tokens, start, n_new, *extra):
-        cache_args = [self.caches[n] for n in sorted(self.caches)]
-        with self._mesh_ctx():
-            outs = fn(jnp.asarray(tokens), jnp.asarray(start),
-                      jnp.asarray(n_new),
-                      *[jnp.asarray(e) for e in extra], *cache_args)
-        logits = np.asarray(outs[0])
+    def _stage(self, tokens: np.ndarray, start: np.ndarray,
+               n_new: np.ndarray) -> Tuple[np.ndarray, ...]:
+        """Host work before a step call; returns the inputs it adds
+        after ``n_new`` (none for the dense caches)."""
+        return ()
+
+    def _call(self, fn, tokens, start, n_new):
+        """One step Program call, in three profiler spans: staging and
+        launch (``stepper.stage``), the host waiting on the device
+        (``stepper.wait``), and the logits copy to the host
+        (``stepper.fetch``)."""
+        with jax.profiler.TraceAnnotation("stepper.stage"):
+            extra = self._stage(tokens, start, n_new)
+            cache_args = [self.caches[n] for n in sorted(self.caches)]
+            with self._mesh_ctx():
+                outs = fn(jnp.asarray(tokens), jnp.asarray(start),
+                          jnp.asarray(n_new),
+                          *[jnp.asarray(e) for e in extra], *cache_args)
+        with jax.profiler.TraceAnnotation("stepper.wait"):
+            outs[0].block_until_ready()
+        with jax.profiler.TraceAnnotation("stepper.fetch"):
+            logits = np.asarray(outs[0])
         for name, arr in zip(self.cache_names, outs[1:]):
             self.caches[name.replace("new_", "")] = arr
         return logits
@@ -737,15 +752,10 @@ class PagedProgramStepper(ProgramStepper):
             bt[s, :len(table)] = table
         return bt
 
-    def prefill(self, tokens: np.ndarray, start: np.ndarray,
-                n_new: np.ndarray) -> np.ndarray:
+    def _stage(self, tokens: np.ndarray, start: np.ndarray,
+               n_new: np.ndarray) -> Tuple[np.ndarray, ...]:
         self._record_writes(tokens, start, n_new)
-        return self._call(self._pre, tokens, start, n_new, self._tables())
-
-    def decode(self, tokens: np.ndarray, start: np.ndarray,
-               n_new: np.ndarray) -> np.ndarray:
-        self._record_writes(tokens, start, n_new)
-        return self._call(self._dec, tokens, start, n_new, self._tables())
+        return (self._tables(),)
 
     def verify(self, tokens: np.ndarray, start: np.ndarray,
                n_new: np.ndarray) -> np.ndarray:
@@ -778,8 +788,7 @@ class PagedProgramStepper(ProgramStepper):
                              *cols, *masks, *cache_args)
             self._pending_kv = list(outs[w:])
             return np.stack([np.asarray(o) for o in outs[:w]], axis=1)
-        self._record_writes(tokens, start, n_new)
-        return self._call(self._ver, tokens, start, n_new, self._tables())
+        return self._call(self._ver, tokens, start, n_new)
 
     def commit_spec(self, start: np.ndarray, n_acc: np.ndarray) -> None:
         """kv8 only: replay the accepted prefix (``n_acc[b]`` rows) of the
@@ -1152,9 +1161,40 @@ class Engine:
             self._t0 = time.perf_counter()
         self.tick += 1
         self.metrics.ticks += 1
-        self._expire()
-        if self.tier_aware:
-            self._overload_control()
+        with jax.profiler.TraceAnnotation("engine.step", tick=self.tick):
+            with jax.profiler.TraceAnnotation("engine.schedule"):
+                self._expire()
+                if self.tier_aware:
+                    self._overload_control()
+                self._admit()
+                prefill = [i for i, st in enumerate(self.slots)
+                           if st is not None and not st.decoding]
+                decode = [i for i, st in enumerate(self.slots)
+                          if st is not None and st.decoding]
+                ckpt = (self.checkpoint()
+                        if self.self_heal and (prefill or decode) else None)
+            try:
+                if prefill and (not decode or not self._last_was_prefill):
+                    self._prefill_tick(prefill)
+                    self._last_was_prefill = True
+                elif decode:
+                    if self.spec_k:
+                        self._spec_decode_tick(decode)
+                    else:
+                        self._decode_tick(decode)
+                    self._last_was_prefill = False
+                self._consec_failures = 0
+                if self.coordinator is not None:
+                    self.coordinator.heartbeat(self.host_id)
+            except TickFailure as failure:
+                if not self.self_heal:
+                    raise
+                self._recover(ckpt, failure)
+        self.metrics.wall_s = time.perf_counter() - self._t0
+
+    def _admit(self) -> None:
+        """Move queued requests into free slots (and resume requeued
+        ones from the rows they kept)."""
         if self.paged:
             # admission is gated on BLOCK availability, not slot count
             # alone.  The gate performs the pool admission (claims cached
@@ -1230,30 +1270,6 @@ class Engine:
                 self._dense_rows[slot] = req.uid
             if moves:
                 self.stepper.relocate_slots(moves)
-        prefill = [i for i, st in enumerate(self.slots)
-                   if st is not None and not st.decoding]
-        decode = [i for i, st in enumerate(self.slots)
-                  if st is not None and st.decoding]
-        ckpt = (self.checkpoint() if self.self_heal and (prefill or decode)
-                else None)
-        try:
-            if prefill and (not decode or not self._last_was_prefill):
-                self._prefill_tick(prefill)
-                self._last_was_prefill = True
-            elif decode:
-                if self.spec_k:
-                    self._spec_decode_tick(decode)
-                else:
-                    self._decode_tick(decode)
-                self._last_was_prefill = False
-            self._consec_failures = 0
-            if self.coordinator is not None:
-                self.coordinator.heartbeat(self.host_id)
-        except TickFailure as failure:
-            if not self.self_heal:
-                raise
-            self._recover(ckpt, failure)
-        self.metrics.wall_s = time.perf_counter() - self._t0
 
     def _guarded_call(self, fn, *args) -> np.ndarray:
         """One stepper Program call under the ft/ watchdogs.
@@ -1303,17 +1319,18 @@ class Engine:
         logits = self._guarded_call(self.stepper.prefill, tokens, start, n_new)
         self.metrics.prefill_ticks += 1
         self.metrics.busy_slot_ticks += len(slots)
-        for s in slots:
-            st = self.slots[s]
-            n = int(n_new[s])
-            st.pos += n
-            if st.pos >= len(st.prompt):
-                st.decoding = True
-                st.length = len(st.prompt)
-                first = int(np.argmax(logits[s, n - 1]))
-                st.next_token = first
-                self._emit(st, first)
-                self._maybe_finish(s, first)
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s in slots:
+                st = self.slots[s]
+                n = int(n_new[s])
+                st.pos += n
+                if st.pos >= len(st.prompt):
+                    st.decoding = True
+                    st.length = len(st.prompt)
+                    first = int(np.argmax(logits[s, n - 1]))
+                    st.next_token = first
+                    self._emit(st, first)
+                    self._maybe_finish(s, first)
 
     def _decode_tick(self, slots: List[int]) -> None:
         t_begin = time.perf_counter()
@@ -1329,13 +1346,14 @@ class Engine:
         logits = self._guarded_call(self.stepper.decode, tokens, start, n_new)
         self.metrics.decode_ticks += 1
         self.metrics.busy_slot_ticks += len(slots)
-        for s in slots:
-            st = self.slots[s]
-            st.length += 1
-            tok = int(np.argmax(logits[s]))
-            st.next_token = tok
-            self._emit(st, tok)
-            self._maybe_finish(s, tok)
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s in slots:
+                st = self.slots[s]
+                st.length += 1
+                tok = int(np.argmax(logits[s]))
+                st.next_token = tok
+                self._emit(st, tok)
+                self._maybe_finish(s, tok)
         self.metrics.decode_tokens += len(slots)
         self.metrics.decode_wall_s += time.perf_counter() - t_begin
 
@@ -1413,22 +1431,23 @@ class Engine:
         # draft token IS that argmax.  Walk every slot BEFORE touching any
         # state — the kv8 commit below is one batched (guarded) call.
         emits: Dict[int, List[int]] = {}
-        for s in slots:
-            st = self.slots[s]
-            n = int(vn_new[s])
-            emit: List[int] = []
-            for i in range(n):
-                g = int(np.argmax(logits[s, i]))
-                emit.append(g)
-                if g == self.eos_id or \
-                        len(st.req.out_tokens) + len(emit) \
-                        >= st.req.max_new_tokens:
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s in slots:
+                st = self.slots[s]
+                n = int(vn_new[s])
+                emit: List[int] = []
+                for i in range(n):
+                    g = int(np.argmax(logits[s, i]))
+                    emit.append(g)
+                    if g == self.eos_id or \
+                            len(st.req.out_tokens) + len(emit) \
+                            >= st.req.max_new_tokens:
+                        break
+                    if i + 1 < n and int(vtokens[s, i + 1]) == g:
+                        continue
                     break
-                if i + 1 < n and int(vtokens[s, i + 1]) == g:
-                    continue
-                break
-            emits[s] = emit         # len >= 1: position 0 re-scores the
-            #                         committed token, so it always emits
+                emits[s] = emit         # len >= 1: position 0 re-scores the
+                #                         committed token, so it always emits
         if self.paged:
             # roll back the rejected speculative rows; rows
             # 0..length+e-1 hold exactly the committed stream
@@ -1445,20 +1464,21 @@ class Engine:
                 commit_n[s] = len(emits[s])
             self._guarded_call(self.stepper.commit_spec, vstart, commit_n)
         emitted_total = 0
-        for s in slots:
-            st = self.slots[s]
-            emit = emits[s]
-            e = len(emit)
-            n = int(vn_new[s])
-            self.metrics.spec_proposed += n - 1
-            self.metrics.spec_accepted += e - 1
-            st.length += e
-            st.draft_len = st.length   # accepted rows == draft-cache rows
-            st.next_token = emit[-1]
-            for tok in emit:
-                self._emit(st, tok)
-            emitted_total += e
-            self._maybe_finish(s, emit[-1])
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s in slots:
+                st = self.slots[s]
+                emit = emits[s]
+                e = len(emit)
+                n = int(vn_new[s])
+                self.metrics.spec_proposed += n - 1
+                self.metrics.spec_accepted += e - 1
+                st.length += e
+                st.draft_len = st.length   # accepted rows == draft-cache rows
+                st.next_token = emit[-1]
+                for tok in emit:
+                    self._emit(st, tok)
+                emitted_total += e
+                self._maybe_finish(s, emit[-1])
         self.metrics.decode_tokens += emitted_total
         self.metrics.decode_wall_s += time.perf_counter() - t_begin
 
