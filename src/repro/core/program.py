@@ -208,14 +208,25 @@ class Program:
 
         ``donate`` names inputs whose buffers the caller will not reuse —
         functional state threaded through the call, like the serving
-        engine's KV caches — letting XLA alias them into same-shaped
-        outputs instead of copying (a no-op on backends without donation
-        support, e.g. CPU).  A donated buffer is consumed: pass the
-        previous call's output, never the same array twice.
+        engine's KV caches — letting XLA alias each into the output
+        ``new_<name>`` that updates it instead of copying (a no-op on
+        backends without donation support, e.g. CPU).  A donated buffer is
+        consumed: pass the previous call's output, never the same array
+        twice.
+
+        XLA aliases each donated argument to the first output of its shape
+        not yet taken, in argument order; in any other order an output is
+        handed another input's buffer, and the step then copies whole
+        buffers (a KV page pool per layer) to keep both alive.  So the
+        jitted step takes the donated inputs last, in the order of the
+        ``new_<name>`` outputs they become, whatever order ``names`` gives.
 
         With no arguments, inputs bind in the graph's declared order.
         Each ``bind()`` builds its own jitted entry point — bind once and
-        reuse the returned callable."""
+        reuse the returned callable.  Its ``lower(params, *args)`` lowers
+        that entry point for the given params and arguments (shapes will
+        do, in the bound order), so the step can be compiled as it runs
+        for a described device."""
         order: Tuple[str, ...] = names or tuple(self._graph.inputs)
         unknown = set(order) - set(self._graph.inputs)
         if unknown:
@@ -227,20 +238,28 @@ class Program:
         if bad_donate:
             raise ValueError(f"donate names not inputs: {sorted(bad_donate)}")
         stored = self._stored_params()
-        donate_argnums = tuple(1 + i for i, n in enumerate(order)
-                               if n in set(donate))
+        rank = {v: i for i, v in enumerate(self._graph.outputs)}
+        donated = sorted((n for n in order if n in set(donate)),
+                         key=lambda n: rank.get(f"new_{n}", len(rank)))
+        call_order = tuple(n for n in order if n not in set(donate)) \
+            + tuple(donated)
+        perm = tuple(order.index(n) for n in call_order)
+        kept = len(call_order) - len(donated)
 
         def positional(params: Dict[str, Any], *args: Any) -> Tuple[Any, ...]:
-            return self._trace(params, dict(zip(order, args)))
+            return self._trace(params, dict(zip(call_order, args)))
 
         # the compiled module, and so each device op in a trace, is named
         # after the graph (``jit_graph_lm_paged_decode_b16_t1``)
         positional.__name__ = positional.__qualname__ = self._graph.name
-        jf = jax.jit(positional, donate_argnums=donate_argnums)
+        jf = jax.jit(positional,
+                     donate_argnums=tuple(range(1 + kept, 1 + len(call_order))))
 
         def fast(*args: Any) -> Tuple[Any, ...]:
-            return jf(stored, *args)
+            return jf(stored, *[args[i] for i in perm])
 
+        fast.lower = lambda params, *args: jf.lower(
+            params, *[args[i] for i in perm])
         return fast
 
     # ------------------------------------------------------------------ #

@@ -309,7 +309,7 @@ def flash_paged_chunk_attention(q: jax.Array, pages_k: jax.Array,
                                 interpret: bool = False) -> jax.Array:
     """Chunked-prefill flash attention reading K/V through block tables.
 
-    q (B, T, Hq, D), pages_k/v (N, P, Hk, D), block_tables (B, MP) int32,
+    q (B, T, Hq, D), pages_k/v (N, P, Hk*D), block_tables (B, MP) int32,
     start (B,) int32 -> (B, T, Hq, D).  Query row t of sequence b sits at
     absolute position ``start[b] + t`` and attends cache positions
     ``<= start[b] + t`` — the ``paged_chunk_attention`` op contract.
@@ -320,8 +320,9 @@ def flash_paged_chunk_attention(q: jax.Array, pages_k: jax.Array,
     With ``k_scales``/``v_scales`` ((N, Hk) float32) the pages are int8
     and each (page, head) tile is dequantized in-register."""
     b, t, hq, d = q.shape
-    n_blocks, page, hkv = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    dv = pages_v.shape[3]
+    n_blocks, page = pages_k.shape[0], pages_k.shape[1]
+    hkv = pages_k.shape[2] // d
+    dv = pages_v.shape[2] // hkv
     n_pages = block_tables.shape[1]
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
@@ -333,10 +334,12 @@ def flash_paged_chunk_attention(q: jax.Array, pages_k: jax.Array,
     nq = t // bq
 
     qr = q.transpose(0, 2, 1, 3).reshape(b * hq, t, d)
-    # pages: (N, P, Hk, D) -> head-major (N*Hk, P, D) so one (block, head)
+    # pages: (N, P, Hk*D) -> head-major (N*Hk, P, D) so one (block, head)
     # pair is a contiguous (P, D) tile the index map can address directly
-    kr = pages_k.transpose(0, 2, 1, 3).reshape(n_blocks * hkv, page, d)
-    vr = pages_v.transpose(0, 2, 1, 3).reshape(n_blocks * hkv, page, dv)
+    kr = (pages_k.reshape(n_blocks, page, hkv, d).transpose(0, 2, 1, 3)
+          .reshape(n_blocks * hkv, page, d))
+    vr = (pages_v.reshape(n_blocks, page, hkv, dv).transpose(0, 2, 1, 3)
+          .reshape(n_blocks * hkv, page, dv))
     start_r = jnp.repeat(start.astype(jnp.int32), hq)           # (B*Hq,)
     tables = jnp.clip(block_tables, 0, n_blocks - 1).astype(jnp.int32)
 

@@ -213,7 +213,7 @@ def flash_paged_decode(q: jax.Array, pages_k: jax.Array, pages_v: jax.Array,
                        v_scales: Optional[jax.Array] = None,
                        scale: Optional[float] = None,
                        interpret: bool = False) -> jax.Array:
-    """q (B, Hq, D), pages_k/v (N, P, Hkv, D), block_tables (B, MP) int32,
+    """q (B, Hq, D), pages_k/v (N, P, Hkv*D), block_tables (B, MP) int32,
     lengths (B,) -> (B, Hq, D), softmax-normalised.
 
     Logical position ``pi * P + r`` of sequence b lives at physical row
@@ -224,8 +224,9 @@ def flash_paged_decode(q: jax.Array, pages_k: jax.Array, pages_v: jax.Array,
     and each (page, head) tile is dequantized in-register — the int8
     bytes are all that ever stream through VMEM."""
     b, hq, d = q.shape
-    n_blocks, page, hkv = pages_k.shape[0], pages_k.shape[1], pages_k.shape[2]
-    dv = pages_v.shape[3]
+    n_blocks, page = pages_k.shape[0], pages_k.shape[1]
+    hkv = pages_k.shape[2] // d
+    dv = pages_v.shape[2] // hkv
     n_pages = block_tables.shape[1]
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
@@ -235,11 +236,13 @@ def flash_paged_decode(q: jax.Array, pages_k: jax.Array, pages_v: jax.Array,
     if lengths is None:
         lengths = jnp.full((b,), n_pages * page, jnp.int32)
 
-    # q: (B, Hq, D) -> (B*Hkv, group, D); pages: (N, P, Hkv, D) -> head-major
+    # q: (B, Hq, D) -> (B*Hkv, group, D); pages: (N, P, Hkv*D) -> head-major
     # (N*Hkv, P, D) so one (block, head) pair is a contiguous (P, D) tile.
     qr = q.reshape(b, hkv, group, d).reshape(b * hkv, group, d)
-    kr = pages_k.transpose(0, 2, 1, 3).reshape(n_blocks * hkv, page, d)
-    vr = pages_v.transpose(0, 2, 1, 3).reshape(n_blocks * hkv, page, dv)
+    kr = (pages_k.reshape(n_blocks, page, hkv, d).transpose(0, 2, 1, 3)
+          .reshape(n_blocks * hkv, page, d))
+    vr = (pages_v.reshape(n_blocks, page, hkv, dv).transpose(0, 2, 1, 3)
+          .reshape(n_blocks * hkv, page, dv))
     len_r = jnp.repeat(lengths.astype(jnp.int32), hkv)          # (B*Hkv,)
     tables = jnp.clip(block_tables, 0, n_blocks - 1).astype(jnp.int32)
 

@@ -345,36 +345,64 @@ def chunk_attention(q, k, v, start, *, scale=None, backend: str = "ref", **kw):
 
 # --------------------------------------------------------------------------- #
 # Paged serving ops — K/V rows live in a shared page pool
-# (n_blocks, page_size, Hk, D) and each sequence reaches its rows through an
+# (n_blocks, page_size, Hk*D) and each sequence reaches its rows through an
 # int32 block table (B, max_pages): logical page -> physical block.  The
-# engine side of the contract (allocation, refcounts, prefix reuse, CoW)
-# lives in repro.runtime.kv_cache; these ops only move and read rows.
+# pool is lane-dense on purpose: with Hk*D as the minor dimension the TPU
+# keeps it page-major ({2,1,0}), so the scatter of new rows updates the
+# donated buffer in place and the gather reads whole pages as they lie.  A
+# (.., Hk, D) pool with D off the 128-lane tiling (96, 160) would be laid
+# out block-minor, and each step would copy every layer's whole pool into
+# the scatter's layout and back.  Head h owns lanes h*D .. h*D+D-1.
+# The engine side of the contract (allocation, refcounts, prefix reuse,
+# CoW) lives in repro.runtime.kv_cache; these ops only move and read rows.
 # Garbage table entries (unallocated logical pages, filled with 0) are
 # harmless: reads of those positions are masked by start/lengths, writes
 # never target them (start .. start+n_new-1 always lies in allocated pages).
 # --------------------------------------------------------------------------- #
 
+def _pool_heads(q, pages) -> int:
+    """Kv heads of a flat (N, P, Hk*D) pool read with queries whose last
+    dimension is D; 0 when the pool's row is not whole heads."""
+    d, hd = q.shape[-1], pages.shape[-1]
+    return hd // d if len(pages.shape) == 3 and hd % d == 0 else 0
+
+
+def _whole_groups(q, pages) -> bool:
+    """The pool's rows are whole kv heads and Hq is whole GQA groups."""
+    hk = _pool_heads(q, pages)
+    return hk > 0 and q.shape[-2] % hk == 0
+
+
+def _split_heads(x, d):
+    """(..., Hk*D) -> (..., Hk, D)."""
+    return x.reshape(*x.shape[:-1], x.shape[-1] // d, d)
+
+
 def _gather_pages(pages, tables):
-    """(N, P, H, D) pages + (B, MP) tables -> dense (B, MP*P, H, D) view."""
-    n, p = pages.shape[0], pages.shape[1]
-    g = jnp.take(pages, jnp.clip(tables, 0, n - 1), axis=0)  # (B, MP, P, H, D)
-    return g.reshape(tables.shape[0], tables.shape[1] * p, *pages.shape[2:])
+    """(N, P, Hk*D) pool + (B, MP) tables -> dense (B, MP*P, Hk*D) view.
+
+    The engine's tables hold valid block ids only (unallocated entries are
+    0), so the gather promises in-bounds indices: ``mode="clip"`` lowers
+    to a plain gather with no fill mask over the gathered view."""
+    g = jnp.take(pages, tables, axis=0, mode="clip")        # (B, MP, P, HD)
+    return g.reshape(tables.shape[0], tables.shape[1] * pages.shape[1],
+                     pages.shape[2])
 
 
 def _gathered_bytes(pages_spec, tables_spec) -> float:
     """HBM bytes of one gathered dense K or V view."""
-    n, p, h, d = pages_spec.shape
+    n, p, hd = pages_spec.shape
     b, mp = tables_spec.shape
     itemsize = pages_spec.nbytes / max(pages_spec.nelems, 1)
-    return float(b * mp * p * h * d) * itemsize
+    return float(b * mp * p * hd) * itemsize
 
 
 # ---- paged_cache_update --------------------------------------------------- #
-# inputs (pages (N,P,H,D), new (B,T,H,D), tables (B,MP) i32, start, n_new)
+# inputs (pages (N,P,Hk*D), new (B,T,Hk,D), tables (B,MP) i32, start, n_new)
 
 def _paged_update_shape(specs, attrs):
     pages, new, tables = specs[0], specs[1], specs[2]
-    if pages.shape[2:] != new.shape[2:]:
+    if len(pages.shape) != 3 or pages.shape[2] != new.shape[2] * new.shape[3]:
         raise ValueError(f"page/new head mismatch: {pages.shape} vs {new.shape}")
     if new.shape[0] != tables.shape[0]:
         raise ValueError(f"batch mismatch: {new.shape} vs {tables.shape}")
@@ -389,7 +417,7 @@ def _paged_update_cost(specs, attrs):
 
 defop("paged_cache_update", _paged_update_shape, _paged_update_cost,
       doc="scatter n_new K/V rows into a shared page pool through per-"
-          "sequence block tables; inputs (pages (N,P,H,D), new (B,T,H,D), "
+          "sequence block tables; inputs (pages (N,P,Hk*D), new (B,T,Hk,D), "
           "tables (B,MP) int32, start (B,), n_new (B,))")
 
 
@@ -402,6 +430,11 @@ def _paged_rows(tables, start, t, p, n_blocks):
     return jnp.clip(blk, 0, n_blocks - 1), pos % p
 
 
+def _flat_rows(new):
+    """(B, T, Hk, D) rows -> (B, T, Hk*D), the pool's row shape."""
+    return jnp.asarray(new).reshape(new.shape[0], new.shape[1], -1)
+
+
 @impl("paged_cache_update", "ref",
       note="per-slot python loop of masked row scatters (the oracle); "
           "n_new==0 slots are exact no-ops")
@@ -410,17 +443,19 @@ def _paged_cache_update_ref(inputs, attrs):
     n_blocks, p = pages.shape[0], pages.shape[1]
     b, t = new.shape[0], new.shape[1]
     blk, row = _paged_rows(tables, start, t, p, n_blocks)
+    rows = _flat_rows(new)
     out = jnp.asarray(pages)
     for bi in range(b):
         valid = jnp.arange(t) < n_new[bi]
         tgt = jnp.where(valid, blk[bi], n_blocks)      # OOB rows are dropped
-        out = out.at[tgt, row[bi]].set(new[bi], mode="drop")
+        out = out.at[tgt, row[bi]].set(rows[bi], mode="drop")
     return [out]
 
 
 @impl("paged_cache_update", "xla",
-      note="one flat (B*T)-row scatter; bit-identical to ref because write "
-           "targets are unique (each writable page belongs to one sequence)")
+      note="one flat (B*T)-row scatter into the donated pool, in place; "
+           "bit-identical to ref because write targets are unique (each "
+           "writable page belongs to one sequence)")
 def _paged_cache_update_xla(inputs, attrs):
     pages, new, tables, start, n_new = inputs
     n_blocks, p = pages.shape[0], pages.shape[1]
@@ -429,7 +464,7 @@ def _paged_cache_update_xla(inputs, attrs):
     valid = jnp.arange(t)[None, :] < jnp.asarray(n_new)[:, None]
     tgt = jnp.where(valid, blk, n_blocks)
     return [jnp.asarray(pages).at[tgt.reshape(-1), row.reshape(-1)].set(
-        jnp.asarray(new).reshape((b * t,) + new.shape[2:]), mode="drop")]
+        _flat_rows(new).reshape(b * t, -1), mode="drop")]
 
 
 def paged_cache_update(pages, new, tables, start, n_new, *,
@@ -439,7 +474,7 @@ def paged_cache_update(pages, new, tables, start, n_new, *,
 
 
 # ---- paged_chunk_attention ------------------------------------------------ #
-# inputs (q (B,T,Hq,D), pages_k (N,P,Hk,D), pages_v, tables (B,MP), start)
+# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP), start)
 
 def _paged_chunk_shape(specs, attrs):
     return [specs[0]]
@@ -456,7 +491,7 @@ def _paged_chunk_cost(specs, attrs):
 
 defop("paged_chunk_attention", _paged_chunk_shape, _paged_chunk_cost,
       doc="chunked-prefill attention reading K/V through block tables; "
-          "inputs (q (B,T,Hq,D), pages_k (N,P,Hk,D), pages_v, "
+          "inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, "
           "tables (B,MP) int32, start (B,)); attrs: scale")
 
 
@@ -477,9 +512,10 @@ def _paged_chunk_ref_cost(specs, attrs):
            "causal oracle")
 def _paged_chunk_attention_ref(inputs, attrs):
     q, pk, pv, tables, start = inputs
+    d = q.shape[-1]
     return _chunk_attention_ref(
-        [q, _gather_pages(pk, tables), _gather_pages(pv, tables), start],
-        attrs)
+        [q, _split_heads(_gather_pages(pk, tables), d),
+         _split_heads(_gather_pages(pv, tables), d), start], attrs)
 
 
 def _paged_chunk_xla_cost(specs, attrs):
@@ -495,10 +531,16 @@ def _paged_chunk_xla_cost(specs, attrs):
       note="gather pages to a dense view + the GQA-grouped einsum "
            "(repeated-KV never materialised)")
 def _paged_chunk_attention_xla(inputs, attrs):
+    """Splits the gathered (B, S, Hk*D) view into heads, one relayout of
+    the view on the TPU.  With T query rows per slot the block-diagonal
+    form of the decode path (:func:`_decode_attention_flat`) would be a
+    real matmul Hk times over, with a (B, T*Hq, Hk*D) product to reduce:
+    dearer than the relayout."""
     q, pk, pv, tables, start = inputs
+    d = q.shape[-1]
     return _chunk_attention_xla(
-        [q, _gather_pages(pk, tables), _gather_pages(pv, tables), start],
-        attrs)
+        [q, _split_heads(_gather_pages(pk, tables), d),
+         _split_heads(_gather_pages(pv, tables), d), start], attrs)
 
 
 # The paged Pallas kernels keep their block table, the per-row lengths or
@@ -522,7 +564,7 @@ def _paged_chunk_pallas_supports(specs, attrs):
     q, pk = specs[0], specs[1]
     bq = min(int(attrs.get("block_q", 256)), q.shape[1])
     return (q.shape[1] % bq == 0 and pk.shape[1] % 8 == 0
-            and q.shape[2] % pk.shape[2] == 0 and _smem_fits(q, specs[3]))
+            and _whole_groups(q, pk) and _smem_fits(q, specs[3]))
 
 
 @impl("paged_chunk_attention", "pallas",
@@ -546,7 +588,7 @@ def paged_chunk_attention(q, pages_k, pages_v, tables, start, *, scale=None,
 
 
 # ---- paged_decode_attention ----------------------------------------------- #
-# inputs (q (B,Hq,D), pages_k (N,P,Hk,D), pages_v, tables (B,MP), lengths)
+# inputs (q (B,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP), lengths)
 
 def _paged_dec_shape(specs, attrs):
     return [specs[0]]
@@ -563,7 +605,7 @@ def _paged_dec_cost(specs, attrs):
 
 defop("paged_decode_attention", _paged_dec_shape, _paged_dec_cost,
       doc="single-token attention reading the KV cache through block "
-          "tables; inputs (q (B,Hq,D), pages_k (N,P,Hk,D), pages_v, "
+          "tables; inputs (q (B,Hq,D), pages_k (N,P,Hk*D), pages_v, "
           "tables (B,MP) int32, lengths (B,)); attrs: scale")
 
 
@@ -583,15 +625,16 @@ def _paged_dec_ref_cost(specs, attrs):
       note="gather pages to a dense view + the dense fp32 decode oracle")
 def _paged_decode_attention_ref(inputs, attrs):
     q, pk, pv, tables, lengths = inputs
-    k = _gather_pages(pk, tables)
-    v = _gather_pages(pv, tables)
+    k = _split_heads(_gather_pages(pk, tables), q.shape[-1])
+    v = _split_heads(_gather_pages(pv, tables), q.shape[-1])
     return [R.decode_attention_ref(q, k, v, lengths,
                                    scale=attrs.get("scale"))]
 
 
 def _paged_dec_xla_cost(specs, attrs):
     """Charges the materialised dense gather on top of the op's
-    streaming cost; GQA stays grouped in the einsum."""
+    streaming cost.  The block-diagonal einsum's zero products are not
+    charged: with one query row per slot the bytes bound the step."""
     q, pk, tables = specs[0], specs[1], specs[3]
     base = _paged_dec_cost(specs, attrs)
     return Cost(flops=base.flops,
@@ -614,14 +657,57 @@ def _decode_attention_xla_dense(q, k, v, lengths, attrs):
     return o.reshape(b, hq, d).astype(q.dtype)
 
 
+def _head_mask(hq: int, hk: int):
+    """(Hq, Hk) bool: query head i reads kv head i // (Hq / Hk)."""
+    assert hq % hk == 0, (hq, hk)
+    return (jnp.arange(hq)[:, None] // (hq // hk)) == jnp.arange(hk)[None, :]
+
+
+def _decode_attention_flat(q, k, v, lengths, attrs):
+    """Decode attention over gathered (B, S, Hk*D) K/V as they lie.
+
+    Splitting the lane axis Hk*D into (Hk, D) would be a relayout copy of
+    the whole gathered view on the TPU (D = 96 or 160 is off the 128-lane
+    tiling).  Instead each query row is spread over all Hk*D lanes, zero
+    outside its kv head, so both matmuls contract over or produce the
+    lane axis; each output row then keeps its own head's D lanes.  The
+    added products are exact zeros.  With one query row per slot the
+    matmuls are bound by reading K and V, so Hk times the MXU work costs
+    less than the relayout would.
+
+    The precision keeps the arithmetic of the grouped einsum in
+    :func:`_decode_attention_xla_dense` on the TPU.  With whole GQA groups
+    (Hq > Hk) that einsum is a matmul at the default precision, operands
+    rounded to bf16 and sums in f32, and so is this one.  With one query
+    row per kv head (Hq == Hk) it is a matrix-vector product per head,
+    which XLA computes as an f32 multiply and reduce; the block-diagonal
+    form is a matmul there, so it runs at ``HIGHEST`` to stay f32."""
+    b, hq, d = q.shape
+    s, hkd = k.shape[1], k.shape[2]
+    hk = hkd // d
+    prec = jax.lax.Precision.HIGHEST if hq == hk else None
+    own = _head_mask(hq, hk)[:, :, None]                        # (Hq, Hk, 1)
+    qs = (q.astype(jnp.float32) * _chunk_attn_scale(attrs, d))[:, :, None]
+    qbd = jnp.where(own, qs, 0.0).reshape(b, hq, hkd)          # (B, Hq, HkD)
+    logits = jnp.einsum("bqj,bsj->bqs", qbd, k.astype(jnp.float32),
+                        precision=prec)
+    allowed = jnp.arange(s)[None, None, :] < lengths[:, None, None]
+    logits = jnp.where(allowed, logits, R._NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("bqs,bsj->bqj", p, v.astype(jnp.float32),
+                   precision=prec)                              # (B, Hq, HkD)
+    o = jnp.where(own, o.reshape(b, hq, hk, d), 0.0).sum(axis=2)
+    return o.astype(q.dtype)
+
+
 @impl("paged_decode_attention", "xla", cost_fn=_paged_dec_xla_cost,
-      note="gather pages to a dense view + GQA-grouped einsum over the "
-           "length-masked positions")
+      note="gather pages to a lane-dense (B, S, Hk*D) view + a block-"
+           "diagonal einsum over it as it lies (no head relayout), "
+           "length-masked")
 def _paged_decode_attention_xla(inputs, attrs):
     q, pk, pv, tables, lengths = inputs
-    k = _gather_pages(pk, tables)
-    v = _gather_pages(pv, tables)
-    return [_decode_attention_xla_dense(q, k, v, lengths, attrs)]
+    return [_decode_attention_flat(q, _gather_pages(pk, tables),
+                                   _gather_pages(pv, tables), lengths, attrs)]
 
 
 def _paged_dec_pallas_supports(specs, attrs):
@@ -629,7 +715,7 @@ def _paged_dec_pallas_supports(specs, attrs):
     divisible by Hk (whole GQA groups) and the SMEM operands within
     budget."""
     q, pk = specs[0], specs[1]
-    return (pk.shape[1] % 8 == 0 and q.shape[1] % pk.shape[2] == 0
+    return (pk.shape[1] % 8 == 0 and _whole_groups(q, pk)
             and _smem_fits(q, specs[3]))
 
 
@@ -669,15 +755,16 @@ _Q_MAX = 127.0
 
 
 def _gather_pages_q(pages_q, scales, tables):
-    """int8 (N,P,H,D) pages + (N,H) scales + (B,MP) tables -> dense fp32
-    (B, MP*P, H, D) view, dequantized after the gather (per-sequence
+    """int8 (N,P,Hk*D) pages + (N,Hk) scales + (B,MP) tables -> dense fp32
+    (B, MP*P, Hk*D) view, dequantized after the gather (per-sequence
     working set, never the whole pool)."""
-    n, p = pages_q.shape[0], pages_q.shape[1]
-    idx = jnp.clip(tables, 0, n - 1)
-    g = jnp.take(pages_q, idx, axis=0).astype(jnp.float32)   # (B,MP,P,H,D)
-    sc = jnp.take(scales, idx, axis=0)                       # (B,MP,H)
-    g = g * sc[:, :, None, :, None]
-    return g.reshape(tables.shape[0], tables.shape[1] * p, *pages_q.shape[2:])
+    p, hkd = pages_q.shape[1], pages_q.shape[2]
+    g = jnp.take(pages_q, tables, axis=0, mode="clip")       # (B,MP,P,HkD)
+    sc = jnp.take(scales, tables, axis=0, mode="clip")       # (B,MP,Hk)
+    # each head's scale repeated over its D lanes of the row
+    g = g.astype(jnp.float32) * jnp.repeat(sc, hkd // sc.shape[-1],
+                                           axis=-1)[:, :, None]
+    return g.reshape(tables.shape[0], tables.shape[1] * p, hkd)
 
 
 def _scale_bytes(specs) -> float:
@@ -686,17 +773,17 @@ def _scale_bytes(specs) -> float:
 
 
 # ---- paged_cache_update_q ------------------------------------------------- #
-# inputs (pages (N,P,H,D) int8, scales (N,H) f32, new (B,T,H,D) f32,
+# inputs (pages (N,P,Hk*D) int8, scales (N,Hk) f32, new (B,T,Hk,D) f32,
 #         tables (B,MP) i32, start (B,), n_new (B,)) -> [pages, scales]
 
 def _paged_update_q_shape(specs, attrs):
     pages, scales, new, tables = specs[0], specs[1], specs[2], specs[3]
     if pages.dtype != "int8":
         raise ValueError(f"quantized pages must be int8, got {pages.dtype}")
-    if scales.shape != (pages.shape[0], pages.shape[2]):
+    if scales.shape != (pages.shape[0], new.shape[2]):
         raise ValueError(f"scales {scales.shape} != (N, Hk) "
-                         f"({pages.shape[0]}, {pages.shape[2]})")
-    if pages.shape[2:] != new.shape[2:]:
+                         f"({pages.shape[0]}, {new.shape[2]})")
+    if len(pages.shape) != 3 or pages.shape[2] != new.shape[2] * new.shape[3]:
         raise ValueError(f"page/new head mismatch: {pages.shape} vs {new.shape}")
     if new.shape[0] != tables.shape[0]:
         raise ValueError(f"batch mismatch: {new.shape} vs {tables.shape}")
@@ -715,8 +802,8 @@ def _paged_update_q_cost(specs, attrs):
 
 defop("paged_cache_update_q", _paged_update_q_shape, _paged_update_q_cost,
       doc="quantize-on-write scatter into an int8 page pool with running "
-          "per-(page, kv-head) max scales; inputs (pages (N,P,H,D) int8, "
-          "scales (N,Hk) f32, new (B,T,H,D), tables (B,MP) int32, "
+          "per-(page, kv-head) max scales; inputs (pages (N,P,Hk*D) int8, "
+          "scales (N,Hk) f32, new (B,T,Hk,D), tables (B,MP) int32, "
           "start (B,), n_new (B,)); outputs [pages, scales]")
 
 
@@ -746,12 +833,13 @@ def _paged_update_q_common(inputs):
     # 1.0, so round(q * 1.0) == q and shared pages stay bit-identical
     ratio = jnp.where(new_scales > 0, jnp.asarray(scales) / new_scales, 1.0)
     pages_rq = jnp.clip(
-        jnp.round(pages.astype(jnp.float32) * ratio[:, None, :, None]),
+        jnp.round(pages.astype(jnp.float32)
+                  * jnp.repeat(ratio, new.shape[3], axis=-1)[:, None, :]),
         -_Q_MAX, _Q_MAX).astype(jnp.int8)
     # quantize the incoming rows with their target page's final scale
     tgt_scale = jnp.take(new_scales, jnp.clip(tgt, 0, n_blocks - 1),
                          axis=0)                                   # (B, T, H)
-    q_rows = _quantize_rows(jnp.asarray(new), tgt_scale[..., None])
+    q_rows = _flat_rows(_quantize_rows(jnp.asarray(new), tgt_scale[..., None]))
     return pages_rq, new_scales, q_rows, blk, row, valid, tgt
 
 
@@ -780,7 +868,7 @@ def _paged_cache_update_q_xla(inputs, attrs):
     pages_rq, new_scales, q_rows, blk, row, valid, tgt = \
         _paged_update_q_common(inputs)
     out = pages_rq.at[tgt.reshape(-1), row.reshape(-1)].set(
-        q_rows.reshape((b * t,) + new.shape[2:]), mode="drop")
+        q_rows.reshape(b * t, -1), mode="drop")
     return [out, new_scales]
 
 
@@ -791,15 +879,18 @@ def paged_cache_update_q(pages, scales, new, tables, start, n_new, *,
 
 
 # ---- paged_chunk_attention_q ---------------------------------------------- #
-# inputs (q (B,T,Hq,D), pages_k (N,P,Hk,D) i8, k_scales (N,Hk) f32,
+# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D) i8, k_scales (N,Hk) f32,
 #         pages_v i8, v_scales, tables (B,MP) i32, start (B,))
 
-def _paged_chunk_q_shape(specs, attrs):
-    pk, ks = specs[1], specs[2]
+def _check_q_pool(q, pk, ks):
     if pk.dtype != "int8":
         raise ValueError(f"quantized pages must be int8, got {pk.dtype}")
-    if ks.shape != (pk.shape[0], pk.shape[2]):
+    if ks.shape != (pk.shape[0], _pool_heads(q, pk)):
         raise ValueError(f"k_scales {ks.shape} != (N, Hk)")
+
+
+def _paged_chunk_q_shape(specs, attrs):
+    _check_q_pool(*specs[:3])
     return [specs[0]]
 
 
@@ -829,8 +920,8 @@ def _paged_chunk_q_gather_cost(specs, attrs):
     q, pk, tables = specs[0], specs[1], specs[5]
     base = _paged_chunk_q_cost(specs, attrs)
     b, mp = tables.shape
-    n, p, h, d = pk.shape
-    dense_f32 = 4.0 * b * mp * p * h * d
+    n, p, hd = pk.shape
+    dense_f32 = 4.0 * b * mp * p * hd
     return Cost(flops=base.flops, bytes=base.bytes + 2.0 * 2.0 * dense_f32)
 
 
@@ -839,9 +930,10 @@ def _paged_chunk_q_gather_cost(specs, attrs):
            "oracle")
 def _paged_chunk_attention_q_ref(inputs, attrs):
     q, pk, ks, pv, vs, tables, start = inputs
+    d = q.shape[-1]
     return _chunk_attention_ref(
-        [q, _gather_pages_q(pk, ks, tables),
-         _gather_pages_q(pv, vs, tables), start], attrs)
+        [q, _split_heads(_gather_pages_q(pk, ks, tables), d),
+         _split_heads(_gather_pages_q(pv, vs, tables), d), start], attrs)
 
 
 @impl("paged_chunk_attention_q", "xla", cost_fn=_paged_chunk_q_gather_cost,
@@ -849,9 +941,10 @@ def _paged_chunk_attention_q_ref(inputs, attrs):
            "(repeated-KV never materialised)")
 def _paged_chunk_attention_q_xla(inputs, attrs):
     q, pk, ks, pv, vs, tables, start = inputs
+    d = q.shape[-1]
     return _chunk_attention_xla(
-        [q, _gather_pages_q(pk, ks, tables),
-         _gather_pages_q(pv, vs, tables), start], attrs)
+        [q, _split_heads(_gather_pages_q(pk, ks, tables), d),
+         _split_heads(_gather_pages_q(pv, vs, tables), d), start], attrs)
 
 
 def _paged_chunk_q_pallas_supports(specs, attrs):
@@ -861,7 +954,7 @@ def _paged_chunk_q_pallas_supports(specs, attrs):
     q, pk = specs[0], specs[1]
     bq = min(int(attrs.get("block_q", 256)), q.shape[1])
     return (q.shape[1] % bq == 0 and pk.shape[1] % 8 == 0
-            and q.shape[2] % pk.shape[2] == 0
+            and _whole_groups(q, pk)
             and _smem_fits(q, specs[5], specs[2], specs[4]))
 
 
@@ -888,15 +981,11 @@ def paged_chunk_attention_q(q, pages_k, k_scales, pages_v, v_scales, tables,
 
 
 # ---- paged_decode_attention_q --------------------------------------------- #
-# inputs (q (B,Hq,D), pages_k (N,P,Hk,D) i8, k_scales (N,Hk) f32,
+# inputs (q (B,Hq,D), pages_k (N,P,Hk*D) i8, k_scales (N,Hk) f32,
 #         pages_v i8, v_scales, tables (B,MP) i32, lengths (B,))
 
 def _paged_dec_q_shape(specs, attrs):
-    pk, ks = specs[1], specs[2]
-    if pk.dtype != "int8":
-        raise ValueError(f"quantized pages must be int8, got {pk.dtype}")
-    if ks.shape != (pk.shape[0], pk.shape[2]):
-        raise ValueError(f"k_scales {ks.shape} != (N, Hk)")
+    _check_q_pool(*specs[:3])
     return [specs[0]]
 
 
@@ -925,8 +1014,8 @@ def _paged_dec_q_gather_cost(specs, attrs):
     q, pk, tables = specs[0], specs[1], specs[5]
     base = _paged_dec_q_cost(specs, attrs)
     b, mp = tables.shape
-    n, p, h, d = pk.shape
-    dense_f32 = 4.0 * b * mp * p * h * d
+    n, p, hd = pk.shape
+    dense_f32 = 4.0 * b * mp * p * hd
     return Cost(flops=base.flops, bytes=base.bytes + 2.0 * 2.0 * dense_f32)
 
 
@@ -934,20 +1023,20 @@ def _paged_dec_q_gather_cost(specs, attrs):
       note="dequantize after the gather + the dense fp32 decode oracle")
 def _paged_decode_attention_q_ref(inputs, attrs):
     q, pk, ks, pv, vs, tables, lengths = inputs
-    k = _gather_pages_q(pk, ks, tables)
-    v = _gather_pages_q(pv, vs, tables)
+    k = _split_heads(_gather_pages_q(pk, ks, tables), q.shape[-1])
+    v = _split_heads(_gather_pages_q(pv, vs, tables), q.shape[-1])
     return [R.decode_attention_ref(q, k, v, lengths,
                                    scale=attrs.get("scale"))]
 
 
 @impl("paged_decode_attention_q", "xla", cost_fn=_paged_dec_q_gather_cost,
-      note="dequantize after the gather + GQA-grouped einsum over the "
-           "length-masked positions")
+      note="dequantize after the gather + a block-diagonal einsum over "
+           "the lane-dense view (no head relayout), length-masked")
 def _paged_decode_attention_q_xla(inputs, attrs):
     q, pk, ks, pv, vs, tables, lengths = inputs
-    k = _gather_pages_q(pk, ks, tables)
-    v = _gather_pages_q(pv, vs, tables)
-    return [_decode_attention_xla_dense(q, k, v, lengths, attrs)]
+    return [_decode_attention_flat(q, _gather_pages_q(pk, ks, tables),
+                                   _gather_pages_q(pv, vs, tables), lengths,
+                                   attrs)]
 
 
 def _paged_dec_q_pallas_supports(specs, attrs):
@@ -955,7 +1044,7 @@ def _paged_dec_q_pallas_supports(specs, attrs):
     divisible by Hk (whole GQA groups) and the table plus both scale
     sidecars within the SMEM budget."""
     q, pk = specs[0], specs[1]
-    return (pk.shape[1] % 8 == 0 and q.shape[1] % pk.shape[2] == 0
+    return (pk.shape[1] % 8 == 0 and _whole_groups(q, pk)
             and _smem_fits(q, specs[5], specs[2], specs[4]))
 
 
@@ -1069,12 +1158,12 @@ def verify_attention(q, k, v, start, *, scale=None, backend: str = "ref",
 
 
 # ---- paged_verify_attention ----------------------------------------------- #
-# inputs (q (B,T,Hq,D), pages_k (N,P,Hk,D), pages_v, tables (B,MP), start)
+# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP), start)
 
 defop("paged_verify_attention", _paged_chunk_shape, _paged_chunk_cost,
       doc="speculative-verify attention reading K/V through block tables "
           "(paged_chunk_attention semantics at T = K+1); inputs "
-          "(q (B,T,Hq,D), pages_k (N,P,Hk,D), pages_v, tables (B,MP) "
+          "(q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP) "
           "int32, start (B,)); attrs: scale")
 
 
@@ -1106,8 +1195,8 @@ def paged_verify_attention(q, pages_k, pages_v, tables, start, *, scale=None,
 
 
 # ---- paged_verify_attention_q --------------------------------------------- #
-# inputs (q (B,T,Hq,D), pages_k i8, k_scales (N,Hk), pages_v i8, v_scales,
-#         tables (B,MP), start, k_new (B,T,Hk,D) f32, v_new (B,T,Hk,D) f32)
+# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D) i8, k_scales (N,Hk), pages_v i8,
+#         v_scales, tables (B,MP), start, k_new (B,T,Hk,D) f32, v_new)
 #
 # TWO-SOURCE on purpose: the committed prefix streams from the int8 pages,
 # but this call's own K+1 speculative rows come in as fp32 ``k_new/v_new``
@@ -1120,11 +1209,8 @@ def paged_verify_attention(q, pages_k, pages_v, tables, start, *, scale=None,
 
 def _paged_verify_q_shape(specs, attrs):
     q, pk, ks, kn, vn = specs[0], specs[1], specs[2], specs[7], specs[8]
-    if pk.dtype != "int8":
-        raise ValueError(f"quantized pages must be int8, got {pk.dtype}")
-    if ks.shape != (pk.shape[0], pk.shape[2]):
-        raise ValueError(f"k_scales {ks.shape} != (N, Hk)")
-    want = (q.shape[0], q.shape[1], pk.shape[2], pk.shape[3])
+    _check_q_pool(q, pk, ks)
+    want = (q.shape[0], q.shape[1], ks.shape[1], q.shape[3])
     for name, spec in (("k_new", kn), ("v_new", vn)):
         if spec.shape != want:
             raise ValueError(f"{name} {spec.shape} != (B, T, Hk, D) {want}")
@@ -1162,8 +1248,11 @@ def _patch_new_rows(dense, new, start):
 
 def _paged_verify_q_sources(inputs):
     q, pk, ks, pv, vs, tables, start, kn, vn = inputs
-    k = _patch_new_rows(_gather_pages_q(pk, ks, tables), kn, start)
-    v = _patch_new_rows(_gather_pages_q(pv, vs, tables), vn, start)
+    d = q.shape[-1]
+    k = _patch_new_rows(_split_heads(_gather_pages_q(pk, ks, tables), d),
+                        kn, start)
+    v = _patch_new_rows(_split_heads(_gather_pages_q(pv, vs, tables), d),
+                        vn, start)
     return q, k, v, start
 
 
@@ -1188,7 +1277,7 @@ def _paged_verify_q_pallas_supports(specs, attrs):
     (block clamped to T) and whole GQA groups."""
     q, pk = specs[0], specs[1]
     bq = min(int(attrs.get("block_q", 256)), q.shape[1])
-    return q.shape[1] % bq == 0 and q.shape[2] % pk.shape[2] == 0
+    return q.shape[1] % bq == 0 and _whole_groups(q, pk)
 
 
 @impl("paged_verify_attention_q", "pallas",
@@ -1221,8 +1310,9 @@ def paged_verify_attention_q(q, pages_k, k_scales, pages_v, v_scales, tables,
 # GQA-small model falls back to the replicated backends instead.
 # --------------------------------------------------------------------------- #
 
-_HS4 = P(None, None, "model", None)   # (B,T,H,D) / (B,S,H,D) / (N,P,H,D)
+_HS4 = P(None, None, "model", None)   # (B,T,H,D) / (B,S,H,D)
 _HS3 = P(None, "model", None)         # decode q (B,H,D)
+_POOL = P(None, None, "model")        # pool (N,P,Hk*D): whole heads each
 _SS2 = P(None, "model")               # scale sidecar (N,Hk)
 _REP = P()
 
@@ -1233,9 +1323,9 @@ def _tp_attn_supports(specs, attrs):
     mesh, tp = _tp_state()
     if mesh is None:
         return False
-    hq = specs[0].shape[-2]
-    hk = specs[1].shape[2]
-    return hq % tp == 0 and hk % tp == 0
+    q, k = specs[0], specs[1]
+    hk = k.shape[2] if len(k.shape) == 4 else _pool_heads(q, k)
+    return hk > 0 and q.shape[-2] % tp == 0 and hk % tp == 0
 
 
 def _tp_cost_fn(op: str):
@@ -1306,7 +1396,7 @@ def _paged_chunk_attention_tp(inputs, attrs):
     def local(q_, pk_, pv_, t_, s_):
         return _paged_chunk_attention_xla([q_, pk_, pv_, t_, s_], attrs)[0]
     return [_tp_call(local, (q, pk, pv, tables, start),
-                     (_HS4, _HS4, _HS4, _REP, _REP), _HS4)]
+                     (_HS4, _POOL, _POOL, _REP, _REP), _HS4)]
 
 
 @impl("paged_decode_attention", "tp", supports=_tp_attn_supports,
@@ -1316,7 +1406,7 @@ def _paged_decode_attention_tp(inputs, attrs):
     def local(q_, pk_, pv_, t_, l_):
         return _paged_decode_attention_xla([q_, pk_, pv_, t_, l_], attrs)[0]
     return [_tp_call(local, (q, pk, pv, tables, lengths),
-                     (_HS3, _HS4, _HS4, _REP, _REP), _HS3)]
+                     (_HS3, _POOL, _POOL, _REP, _REP), _HS3)]
 
 
 @impl("paged_verify_attention", "tp", supports=_tp_attn_supports,
@@ -1326,7 +1416,7 @@ def _paged_verify_attention_tp(inputs, attrs):
     def local(q_, pk_, pv_, t_, s_):
         return _paged_verify_attention_xla([q_, pk_, pv_, t_, s_], attrs)[0]
     return [_tp_call(local, (q, pk, pv, tables, start),
-                     (_HS4, _HS4, _HS4, _REP, _REP), _HS4)]
+                     (_HS4, _POOL, _POOL, _REP, _REP), _HS4)]
 
 
 @impl("paged_chunk_attention_q", "tp", supports=_tp_attn_supports,
@@ -1337,7 +1427,7 @@ def _paged_chunk_attention_q_tp(inputs, attrs):
         return _paged_chunk_attention_q_xla(
             [q_, pk_, ks_, pv_, vs_, t_, s_], attrs)[0]
     return [_tp_call(local, (q, pk, ks, pv, vs, tables, start),
-                     (_HS4, _HS4, _SS2, _HS4, _SS2, _REP, _REP), _HS4)]
+                     (_HS4, _POOL, _SS2, _POOL, _SS2, _REP, _REP), _HS4)]
 
 
 @impl("paged_decode_attention_q", "tp", supports=_tp_attn_supports,
@@ -1348,7 +1438,7 @@ def _paged_decode_attention_q_tp(inputs, attrs):
         return _paged_decode_attention_q_xla(
             [q_, pk_, ks_, pv_, vs_, t_, l_], attrs)[0]
     return [_tp_call(local, (q, pk, ks, pv, vs, tables, lengths),
-                     (_HS3, _HS4, _SS2, _HS4, _SS2, _REP, _REP), _HS3)]
+                     (_HS3, _POOL, _SS2, _POOL, _SS2, _REP, _REP), _HS3)]
 
 
 @impl("paged_verify_attention_q", "tp", supports=_tp_attn_supports,
@@ -1359,7 +1449,7 @@ def _paged_verify_attention_q_tp(inputs, attrs):
         return _paged_verify_attention_q_xla(
             [q_, pk_, ks_, pv_, vs_, t_, s_, kn_, vn_], attrs)[0]
     return [_tp_call(local, (q, pk, ks, pv, vs, tables, start, kn, vn),
-                     (_HS4, _HS4, _SS2, _HS4, _SS2, _REP, _REP, _HS4, _HS4),
+                     (_HS4, _POOL, _SS2, _POOL, _SS2, _REP, _REP, _HS4, _HS4),
                      _HS4)]
 
 

@@ -114,12 +114,15 @@ def init_paged_cache_inputs(cfg: GraphLMConfig, n_blocks: int,
     """Zeroed page-pool arrays matching the paged graphs' cache input
     names.  Unlike the dense layout there is no batch dimension — one
     shared pool of ``n_blocks`` fixed-size pages per layer, indexed
-    through per-sequence block tables.  With ``kv_dtype="int8"`` the
+    through per-sequence block tables, each page ``(page_size, Hk*D)``:
+    head h holds lanes ``h*D .. h*D+D-1`` of a row, so the TPU keeps the
+    pool page-major and updates it in place (see the paged ops in
+    :mod:`repro.kernels.serving_ops`).  With ``kv_dtype="int8"`` the
     pools are int8 and each gains a ``cache_{k,v}{i}_scale`` sidecar
     ((n_blocks, Hk) float32, all zeros = every page empty)."""
     if kv_dtype not in ("float32", "int8"):
         raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
-    shape = (n_blocks, page_size, cfg.n_kv_heads, cfg.d_head)
+    shape = (n_blocks, page_size, cfg.n_kv_heads * cfg.d_head)
     dt = np.int8 if kv_dtype == "int8" else np.float32
     out: Dict[str, np.ndarray] = {}
     for i in range(cfg.n_layers):
@@ -158,7 +161,7 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
         n_blocks, page_size, max_pages = paged
         inputs["block_tables"] = TensorSpec((batch, max_pages), "int32")
         for i in range(cfg.n_layers):
-            spec = TensorSpec((n_blocks, page_size, hk, dh), kv_dtype)
+            spec = TensorSpec((n_blocks, page_size, hk * dh), kv_dtype)
             inputs[f"cache_k{i}"] = spec
             inputs[f"cache_v{i}"] = spec
             if kv8:
@@ -344,7 +347,7 @@ def build_paged_decode_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
                              max_pages: int,
                              kv_dtype: str = "float32") -> Graph:
     """Paged decode step: the dense caches are replaced by one shared
-    page pool per layer (``(n_blocks, page_size, Hk, D)``) plus an int32
+    page pool per layer (``(n_blocks, page_size, Hk*D)``) plus an int32
     ``block_tables`` input ``(B, max_pages)`` mapping each slot's logical
     page to a physical block.  Every activation value name matches the
     dense variant, so one calibration drives int8 quantization of both
@@ -440,7 +443,7 @@ def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
         inputs[f"tokens.s{j}"] = TensorSpec((batch, 1), "int32")
         inputs[f"n_new.s{j}"] = TensorSpec((batch,), "int32")
     for i in range(cfg.n_layers):
-        spec = TensorSpec((n_blocks, page_size, hk, dh), "int8")
+        spec = TensorSpec((n_blocks, page_size, hk * dh), "int8")
         sspec = TensorSpec((n_blocks, hk), "float32")
         inputs[f"cache_k{i}"] = spec
         inputs[f"cache_v{i}"] = spec
@@ -574,9 +577,9 @@ def build_spec_commit_graph(cfg: GraphLMConfig, *, batch: int, width: int,
             inputs[f"v_new{i}.s{j}"] = TensorSpec((batch, 1, hk, dh),
                                                   "float32")
     for i in range(cfg.n_layers):
-        inputs[f"cache_k{i}"] = TensorSpec((n_blocks, page_size, hk, dh),
+        inputs[f"cache_k{i}"] = TensorSpec((n_blocks, page_size, hk * dh),
                                            "int8")
-        inputs[f"cache_v{i}"] = TensorSpec((n_blocks, page_size, hk, dh),
+        inputs[f"cache_v{i}"] = TensorSpec((n_blocks, page_size, hk * dh),
                                            "int8")
         inputs[f"cache_k{i}_scale"] = TensorSpec((n_blocks, hk), "float32")
         inputs[f"cache_v{i}_scale"] = TensorSpec((n_blocks, hk), "float32")

@@ -126,9 +126,11 @@ class BlockPool:
 
     Sharding-oblivious by design: the pool tracks *block ids*, never
     tensor data, so it works unchanged when the engine serves
-    tensor-parallel and the device page arrays ``(N_pages, page, Hk, D)``
-    are head-sharded over the mesh's "model" axis (dim 2 — see
-    ``repro.sharding.specs.cache_specs``).  The CoW copies it schedules
+    tensor-parallel and the device page arrays ``(N_pages, page, Hk*D)``
+    (lane-dense, so the TPU keeps them page-major and writes them in
+    place) are head-sharded over the mesh's "model" axis (dim 2, whole
+    heads per device — see ``repro.sharding.specs.graph_partition_specs``).
+    The CoW copies it schedules
     (``pending_copies`` → the engine's ``arr.at[dst].set(arr[src])``)
     index axis 0, which is never sharded, so each device copies exactly
     its own head slice and ``snapshot()``/``restore()`` of the id-level

@@ -200,16 +200,8 @@ def cache_specs(cache_shape: Any, cfg: ArchConfig, mesh: Mesh, batch: int,
         core = list(shape[lead:])
         spec: list = [None] * len(core)
         leaf_name = names[-1]
-        paged_kv = (leaf_name in ("pages_k", "pages_v")
-                    or (leaf_name in ("k", "v") and len(core) == 4
-                        and core[0] != batch))
-        if paged_kv and len(core) == 4:
-            # paged pool (N_pages, page, Hk, D): rows are block-addressed
-            # through tables, so neither the pool dim nor the page dim can
-            # shard usefully — the kv-head dim carries TP, with full
-            # replication as the GQA-small fallback (never a crash).
-            if _div(core[2], mesh, "model"):
-                spec[2] = "model"
+        if leaf_name in ("pages_k", "pages_v") and len(core) == 3:
+            spec[2] = _pool_axis(getattr(cfg, "n_kv_heads", 0), mesh)
             return P(*([None] * lead + spec))
         if leaf_name.endswith("_scale") and len(core) == 2:
             # (N_pages, Hk) dequant sidecar: mirrors its pool's head shard
@@ -298,7 +290,8 @@ def serving_value_role(name: str, shape: Tuple[int, ...], *,
     below), ``col`` (column-parallel projection weight), ``kv_col``
     (column-parallel iff whole kv heads divide the model axis),
     ``dense_cache`` ((B, S, Hk, D) cache), ``paged_pool``
-    ((N_pages, page, Hk, D) pool), ``kv_scale`` ((N_pages, Hk) sidecar).
+    ((N_pages, page, Hk*D) pool, lane-dense so the TPU keeps it
+    page-major), ``kv_scale`` ((N_pages, Hk) sidecar).
 
     wo/wd (and embed/head_w) are kept replicated rather than row-parallel:
     a row-parallel matmul splits the contraction dim and combines partial
@@ -315,14 +308,46 @@ def serving_value_role(name: str, shape: Tuple[int, ...], *,
     if base.startswith("cache_k") or base.startswith("cache_v"):
         if base.endswith("_scale"):
             return "kv_scale" if len(shape) == 2 else "replicated"
-        if len(shape) == 4:
-            return "paged_pool" if paged else "dense_cache"
-        return "replicated"
+        if paged and len(shape) == 3:
+            return "paged_pool"
+        return "dense_cache" if len(shape) == 4 else "replicated"
     if leaf in ("wq", "wg", "wu"):
         return "col"
     if leaf in ("wk", "wv"):
         return "kv_col"
     return "replicated"
+
+
+def _pool_axis(kv_heads: int, mesh: Mesh) -> Optional[str]:
+    """Mesh axis of a paged pool's lane axis.  The pool is (N_pages, page,
+    Hk*D), lane-dense so the TPU keeps it page-major; its rows are
+    block-addressed through tables, so neither the pool dim nor the page
+    dim can shard usefully.  The Hk*D axis carries TP when whole kv heads
+    land on each device (tp | Hk); otherwise, or when Hk is unknown, the
+    pool replicates (the GQA-small fallback, never a crash)."""
+    return "model" if kv_heads and _div(kv_heads, mesh, "model") else None
+
+
+def _graph_kv_heads(graph: Any) -> int:
+    """kv-head count of a serving graph, read from its cache operands as
+    the ops lay them out: dim 2 of a dense (B, S, Hk, D) cache, dim 1 of
+    an (N_pages, Hk) scale sidecar, else dim 2 of the (B, T, Hk, D) rows
+    that a paged write takes.  0 when none is found."""
+    for n, ts in graph.inputs.items():
+        if n.startswith("cache_k") and len(ts.shape) == 4:
+            return int(ts.shape[2])
+        if n.startswith("cache_k") and n.endswith("_scale"):
+            return int(ts.shape[1])
+    writes = [nd for nd in graph.nodes if nd.op == "paged_cache_update"]
+    if not writes:
+        return 0
+    if not graph.value_info:
+        from repro.core.passes import infer_shapes
+        graph = infer_shapes(graph)
+    rows = graph.value_info.get(writes[0].inputs[1]) \
+        or graph.inputs.get(writes[0].inputs[1])
+    return int(rows.shape[2]) if rows is not None and len(rows.shape) == 4 \
+        else 0
 
 
 def graph_partition_specs(graph: Any, mesh: Mesh) -> Dict[str, P]:
@@ -338,13 +363,7 @@ def graph_partition_specs(graph: Any, mesh: Mesh) -> Dict[str, P]:
     (``new_<name>`` strips to ``<name>``); unknown names replicate.
     """
     paged = "block_tables" in graph.inputs
-    # kv-head count from any 4-D cache input (dim 2, dense and paged alike)
-    kv_heads = 0
-    for n, ts in graph.inputs.items():
-        if (n.startswith("cache_k") or n.startswith("cache_v")) \
-                and not n.endswith("_scale") and len(ts.shape) == 4:
-            kv_heads = int(ts.shape[2])
-            break
+    kv_heads = _graph_kv_heads(graph)
 
     def spec_for(name: str, shape: Tuple[int, ...]) -> P:
         role = serving_value_role(name, shape, paged=paged)
@@ -357,9 +376,10 @@ def graph_partition_specs(graph: Any, mesh: Mesh) -> Dict[str, P]:
                     and nd >= 1 and _div(shape[-1], mesh, "model"):
                 return P(*([None] * (nd - 1) + ["model"]))
             return P()
-        if role in ("dense_cache", "paged_pool") and nd == 4 \
-                and _div(shape[2], mesh, "model"):
+        if role == "dense_cache" and nd == 4 and _div(shape[2], mesh, "model"):
             return P(None, None, "model", None)
+        if role == "paged_pool" and _pool_axis(kv_heads, mesh):
+            return P(None, None, "model")
         if role == "kv_scale" and nd == 2 and _div(shape[1], mesh, "model"):
             return P(None, "model")
         return P()

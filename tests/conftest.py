@@ -95,6 +95,21 @@ def make_engine(variant, **overrides):
     return build_lm_serving(GraphLMConfig(**TINY_LM), **kw)
 
 
+# (Hq, Hk, D) of the paged-op parity cases: a small GQA default, then the
+# benchmark's two head sizes, both off the TPU's 128-lane tiling — phi3's
+# MHA heads of 96 and stablelm's GQA 4:1 heads of 160 — on flat
+# (N, P, Hk*D) pools.  The default's suffix is empty, so its ids stay.
+PAGED_HEADS = {"": (4, 2, 8), "-d96": (2, 2, 96), "-d160": (4, 1, 160)}
+
+
+def paged_head_cases(*leads):
+    """``pytest.param(lead, hq, hk, d)`` for every lead value and head
+    shape, for ``@pytest.mark.parametrize("<lead>,hq,hk,d", ...)``; each
+    id is the lead's own plus the head suffix."""
+    return [pytest.param(lead, *heads, id=f"{lead}{sfx}")
+            for sfx, heads in PAGED_HEADS.items() for lead in leads]
+
+
 @pytest.fixture(scope="session")
 def fault_seed():
     """Base seed for randomized fault-injection tick indices.  CI's

@@ -227,9 +227,11 @@ def test_flash_chunk_attention_starts_indexed_by_grid_row(starts):
 
 
 def _int8_pool(n_blocks, page, hk, d):
-    """int8 pages with a distinct scale for every (page, kv head) tile."""
-    pk = jnp.asarray(rng.integers(-127, 128, (n_blocks, page, hk, d)), jnp.int8)
-    pv = jnp.asarray(rng.integers(-127, 128, (n_blocks, page, hk, d)), jnp.int8)
+    """Flat (N, P, Hk*D) int8 pages with a distinct scale for every
+    (page, kv head) tile."""
+    shape = (n_blocks, page, hk * d)
+    pk = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    pv = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
     n = n_blocks * hk
     # dequantized values stay within +-2, every tile on its own scale
     ks, vs = [jnp.asarray((1 + rng.permutation(n)).reshape(n_blocks, hk)
@@ -238,10 +240,13 @@ def _int8_pool(n_blocks, page, hk, d):
 
 
 def _dequant_gather(pages, scales, tables):
-    """(B, MP*P, H, D) fp32 view of each sequence's int8 pages."""
-    g = jnp.take(pages, tables, axis=0).astype(jnp.float32)
+    """(B, MP*P, H, D) fp32 view of each sequence's flat int8 pages."""
+    n, p, hkd = pages.shape
+    hk = scales.shape[1]
+    heads = pages.reshape(n, p, hk, hkd // hk)
+    g = jnp.take(heads, tables, axis=0).astype(jnp.float32)
     g = g * jnp.take(scales, tables, axis=0)[:, :, None, :, None]
-    return g.reshape(tables.shape[0], -1, *pages.shape[2:])
+    return g.reshape(tables.shape[0], -1, hk, hkd // hk)
 
 
 @pytest.mark.parametrize("hq,hk", [(4, 2), (4, 4)])

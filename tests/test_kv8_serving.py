@@ -13,7 +13,7 @@ cold, prefix-hit and copy-on-write paths, with logit error bounded.
 
 import numpy as np
 import pytest
-from conftest import TINY_LM, make_engine
+from conftest import TINY_LM, make_engine, paged_head_cases
 
 import repro  # noqa: F401  (registers every op/backend)
 from repro.core import backends_for, compile
@@ -36,23 +36,28 @@ def _rng():
 
 
 def _quantize_pages(pages):
-    """Symmetric per-(page, kv-head) int8 — the scheme the ops implement."""
+    """Symmetric per-(page, kv-head) int8 — the scheme the ops implement:
+    (N, P, Hk, D) fp32 -> flat (N, P, Hk*D) int8 pages + (N, Hk) scales."""
     amax = np.abs(pages).max(axis=(1, 3))                    # (N, Hk)
     scales = (amax / 127.0).astype(np.float32)
     safe = np.where(scales > 0, scales, 1.0)
     q = np.clip(np.round(pages / safe[:, None, :, None]),
                 -127, 127).astype(np.int8)
-    return q, scales
+    return q.reshape(*q.shape[:2], -1), scales
 
 
 def _dequant(pages_q, scales):
-    return pages_q.astype(np.float32) * scales[:, None, :, None]
+    """Flat (N, P, Hk*D) int8 pages -> flat fp32 pages."""
+    n, p, hkd = pages_q.shape
+    heads = pages_q.reshape(n, p, scales.shape[1], -1).astype(np.float32)
+    return (heads * scales[:, None, :, None]).reshape(n, p, hkd)
 
 
 def _q_layout(rng, *, b=3, cap=32, hk=2, d=8, n_blocks=12, page=8,
               lengths=(14, 9, 5)):
     """Quantized paged K/V under a scrambled block mapping, plus the
-    dequantized fp32 pages the ``*_q`` ops must agree with."""
+    dequantized fp32 pages the ``*_q`` ops must agree with.  Pools are
+    flat (N, P, Hk*D)."""
     perm = rng.permutation(n_blocks)
     tables = np.zeros((b, cap // page), np.int32)
     used = iter(perm)
@@ -100,7 +105,8 @@ def test_cache_update_q_ref_xla_identical_and_roundtrips():
             pos = int(start[bi]) + t
             blk, row = tables[bi, pos // 8], pos % 8
             tol = ref_s[blk].max() * 0.5 + 1e-7   # half a quantum per head
-            np.testing.assert_allclose(deq[blk, row], new[bi, t], atol=2 * tol)
+            np.testing.assert_allclose(deq[blk, row], new[bi, t].reshape(-1),
+                                       atol=2 * tol)
     # idle slot 1: its pages and scales are bit-untouched
     for pi in range(2):
         blk = tables[1, pi]
@@ -115,7 +121,7 @@ def test_cache_update_q_all_zero_rows_keep_zero_scale():
     sentinel for 'only zeros ever stored') and int8 zeros — and attention
     over such pages must stay finite (the falsy-scale guard: dequant is
     'treat as 0', never a division)."""
-    qk = np.zeros((4, 8, 2, 8), np.int8)
+    qk = np.zeros((4, 8, 2 * 8), np.int8)
     sk = np.zeros((4, 2), np.float32)
     qv, sv = qk.copy(), sk.copy()
     tables = np.asarray([[0, 1]], np.int32)
@@ -138,7 +144,7 @@ def test_cache_update_q_requantizes_when_scale_grows():
     scale and requantize the existing rows under it — old content still
     dequantizes to itself within the NEW (coarser) quantum."""
     rng = _rng()
-    pages = np.zeros((2, 8, 2, 8), np.float32)
+    pages = np.zeros((2, 8, 2, 8), np.float32)           # (N, P, Hk, D)
     quiet = 0.05 * rng.standard_normal((1, 4, 2, 8)).astype(np.float32)
     tables = np.asarray([[0, 1]], np.int32)
     qp, sc = _quantize_pages(pages)            # all-zero start
@@ -153,8 +159,10 @@ def test_cache_update_q_requantizes_when_scale_grows():
         np.asarray([1], np.int32), backend="xla"))
     assert (sc2[0] > quiet_scale[0]).all()     # grew for the loud row
     deq = _dequant(qp2, sc2)
-    np.testing.assert_allclose(deq[0, :4], quiet[0], atol=sc2[0].max() + 1e-7)
-    np.testing.assert_allclose(deq[0, 4], loud[0, 0], atol=sc2[0].max())
+    np.testing.assert_allclose(deq[0, :4], quiet[0].reshape(4, -1),
+                               atol=sc2[0].max() + 1e-7)
+    np.testing.assert_allclose(deq[0, 4], loud[0, 0].reshape(-1),
+                               atol=sc2[0].max())
     # page 1 never written: still exactly zero with zero scale
     assert (qp2[1] == 0).all() and (sc2[1] == 0.0).all()
 
@@ -163,11 +171,12 @@ def test_cache_update_q_requantizes_when_scale_grows():
 # quantized attention parity vs the fp32 paged ops on dequantized pages
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("backend", ["ref", "xla", "pallas"])
-def test_paged_decode_attention_q_parity(backend):
+@pytest.mark.parametrize("backend,hq,hk,d",
+                         paged_head_cases("ref", "xla", "pallas"))
+def test_paged_decode_attention_q_parity(backend, hq, hk, d):
     rng = _rng()
-    qk, sk, qv, sv, tables, lengths = _q_layout(rng)
-    q = rng.standard_normal((3, 4, 8)).astype(np.float32)
+    qk, sk, qv, sv, tables, lengths = _q_layout(rng, hk=hk, d=d)
+    q = rng.standard_normal((3, hq, d)).astype(np.float32)
     want = np.asarray(paged_decode_attention(
         q, _dequant(qk, sk), _dequant(qv, sv), tables, lengths,
         backend="ref"))
@@ -176,11 +185,12 @@ def test_paged_decode_attention_q_parity(backend):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("backend", ["ref", "xla", "pallas"])
-def test_paged_chunk_attention_q_parity(backend):
+@pytest.mark.parametrize("backend,hq,hk,d",
+                         paged_head_cases("ref", "xla", "pallas"))
+def test_paged_chunk_attention_q_parity(backend, hq, hk, d):
     rng = _rng()
-    qk, sk, qv, sv, tables, _ = _q_layout(rng)
-    q = rng.standard_normal((3, 4, 4, 8)).astype(np.float32)
+    qk, sk, qv, sv, tables, _ = _q_layout(rng, hk=hk, d=d)
+    q = rng.standard_normal((3, 4, hq, d)).astype(np.float32)
     start = np.asarray([10, 4, 1], np.int32)
     want = np.asarray(paged_chunk_attention(
         q, _dequant(qk, sk), _dequant(qv, sv), tables, start, backend="ref"))
@@ -195,8 +205,8 @@ def test_paged_q_pallas_supports_guards():
     tb = TensorSpec((1, 4), "int32")
     ln = TensorSpec((1,), "int32")
     sc = TensorSpec((8, 2))
-    ok = TensorSpec((8, 8, 2, 8), "int8")
-    bad = TensorSpec((8, 6, 2, 8), "int8")
+    ok = TensorSpec((8, 8, 16), "int8")
+    bad = TensorSpec((8, 6, 16), "int8")
     qd = TensorSpec((1, 4, 8))
     assert "pallas" in backends_for(
         "paged_decode_attention_q", [qd, ok, sc, ok, sc, tb, ln], {})
@@ -221,12 +231,15 @@ def test_cache_update_q_rejects_bad_specs():
     tb = TensorSpec((1, 2), "int32")
     z = TensorSpec((1,), "int32")
     with pytest.raises(ValueError, match="int8"):
-        shape_fn([TensorSpec((4, 8, 2, 8)), sc, new, tb, z, z], {})
-    pages = TensorSpec((4, 8, 2, 8), "int8")
+        shape_fn([TensorSpec((4, 8, 16)), sc, new, tb, z, z], {})
+    pages = TensorSpec((4, 8, 16), "int8")
     with pytest.raises(ValueError, match="scales"):
         shape_fn([pages, TensorSpec((4, 1)), new, tb, z, z], {})
+    # a pool row must be exactly the rows' Hk*D lanes
+    with pytest.raises(ValueError, match="head mismatch"):
+        shape_fn([TensorSpec((4, 8, 2, 8), "int8"), sc, new, tb, z, z], {})
     assert [s.shape for s in shape_fn([pages, sc, new, tb, z, z], {})] \
-        == [(4, 8, 2, 8), (4, 2)]
+        == [(4, 8, 16), (4, 2)]
 
 
 # --------------------------------------------------------------------------- #
@@ -259,7 +272,7 @@ def test_kv8_prefill_logits_bounded_and_top1_exact():
     for kv in "kv":
         for i in range(cfg.n_layers):
             feeds[f"cache_{kv}{i}"] = np.zeros(
-                (n_blocks, page, cfg.n_kv_heads, cfg.d_head), np.int8)
+                (n_blocks, page, cfg.n_kv_heads * cfg.d_head), np.int8)
             feeds[f"cache_{kv}{i}_scale"] = np.zeros(
                 (n_blocks, cfg.n_kv_heads), np.float32)
     got = np.asarray(compile(g8)(**feeds)[0])

@@ -11,6 +11,7 @@ against their dense equivalents on a scrambled physical block layout.
 
 import numpy as np
 import pytest
+from conftest import PAGED_HEADS, paged_head_cases
 
 import repro  # noqa: F401  (registers every op/backend)
 from repro.core import backends_for
@@ -33,8 +34,9 @@ def _rng():
 
 def _paged_layout(rng, *, b=3, cap=16, hk=2, d=8, n_blocks=10, page=4,
                   lengths=(14, 9, 5)):
-    """A dense cache plus the equivalent paged layout under a scrambled
-    block mapping (so parity failures can't hide behind identity maps)."""
+    """A dense cache plus the equivalent flat (N, P, Hk*D) paged layout
+    under a scrambled block mapping (so parity failures can't hide behind
+    identity maps)."""
     perm = rng.permutation(n_blocks)
     mp = cap // page
     tables = np.zeros((b, mp), np.int32)
@@ -50,7 +52,9 @@ def _paged_layout(rng, *, b=3, cap=16, hk=2, d=8, n_blocks=10, page=4,
             tables[bi, pi] = blk
             pages_k[blk] = dense_k[bi, pi * page:(pi + 1) * page]
             pages_v[blk] = dense_v[bi, pi * page:(pi + 1) * page]
-    return dense_k, dense_v, pages_k, pages_v, tables, lengths
+    flat = (n_blocks, page, hk * d)
+    return (dense_k, dense_v, pages_k.reshape(flat), pages_v.reshape(flat),
+            tables, lengths)
 
 
 # --------------------------------------------------------------------------- #
@@ -58,47 +62,54 @@ def _paged_layout(rng, *, b=3, cap=16, hk=2, d=8, n_blocks=10, page=4,
 # --------------------------------------------------------------------------- #
 
 def test_paged_cache_update_matches_dense_rows():
-    rng = _rng()
-    dk, _, pk, _, tables, lengths = _paged_layout(rng)
-    new = rng.standard_normal((3, 4, 2, 8)).astype(np.float32)
-    start, n_new = lengths.copy(), np.asarray([2, 0, 3], np.int32)
-    ref = np.asarray(paged_cache_update(pk, new, tables, start, n_new,
+    for _, hk, d in PAGED_HEADS.values():
+        rng = _rng()
+        dk, _, pk, _, tables, lengths = _paged_layout(rng, hk=hk, d=d)
+        new = rng.standard_normal((3, 4, hk, d)).astype(np.float32)
+        start, n_new = lengths.copy(), np.asarray([2, 0, 3], np.int32)
+        ref = np.asarray(paged_cache_update(pk, new, tables, start, n_new,
+                                            backend="ref"))
+        xla = np.asarray(paged_cache_update(pk, new, tables, start, n_new,
+                                            backend="xla"))
+        np.testing.assert_array_equal(ref, xla)
+        dense = np.asarray(cache_update(dk, new, start, n_new,
                                         backend="ref"))
-    xla = np.asarray(paged_cache_update(pk, new, tables, start, n_new,
-                                        backend="xla"))
-    np.testing.assert_array_equal(ref, xla)
-    dense = np.asarray(cache_update(dk, new, start, n_new, backend="ref"))
-    for bi in range(3):
-        for t in range(int(n_new[bi])):
-            pos = int(start[bi]) + t
-            np.testing.assert_array_equal(
-                ref[tables[bi, pos // 4], pos % 4], dense[bi, pos])
-    # idle slot's pages untouched
-    np.testing.assert_array_equal(ref[tables[1, 0]], pk[tables[1, 0]])
+        for bi in range(3):
+            for t in range(int(n_new[bi])):
+                pos = int(start[bi]) + t
+                # head h of the row sits in lanes h*D .. h*D+D-1
+                np.testing.assert_array_equal(
+                    ref[tables[bi, pos // 4], pos % 4],
+                    dense[bi, pos].reshape(-1))
+        # idle slot's pages untouched
+        np.testing.assert_array_equal(ref[tables[1, 0]], pk[tables[1, 0]])
 
 
-@pytest.mark.parametrize("backend", ["ref", "xla", "pallas"])
-def test_paged_decode_attention_parity(backend):
+@pytest.mark.parametrize("backend,hq,hk,d",
+                         paged_head_cases("ref", "xla", "pallas"))
+def test_paged_decode_attention_parity(backend, hq, hk, d):
     rng = _rng()
-    dk, dv, pk, pv, tables, lengths = _paged_layout(rng)
-    q = rng.standard_normal((3, 4, 8)).astype(np.float32)
+    dk, dv, pk, pv, tables, lengths = _paged_layout(rng, hk=hk, d=d)
+    q = rng.standard_normal((3, hq, d)).astype(np.float32)
     want = np.asarray(decode_attention(q, dk, dv, lengths, backend="ref"))
     got = np.asarray(paged_decode_attention(q, pk, pv, tables, lengths,
                                             backend=backend, interpret=True))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("backend", ["ref", "xla"])
+@pytest.mark.parametrize("backend,hq,hk,d",
+                         paged_head_cases("ref", "xla", "pallas"))
 @pytest.mark.parametrize("scale", [None, 0.0])
-def test_paged_chunk_attention_parity(backend, scale):
+def test_paged_chunk_attention_parity(backend, hq, hk, d, scale):
     rng = _rng()
-    dk, dv, pk, pv, tables, _ = _paged_layout(rng)
-    q = rng.standard_normal((3, 4, 4, 8)).astype(np.float32)
+    dk, dv, pk, pv, tables, _ = _paged_layout(rng, hk=hk, d=d)
+    q = rng.standard_normal((3, 4, hq, d)).astype(np.float32)
     start = np.asarray([10, 4, 1], np.int32)
     want = np.asarray(chunk_attention(q, dk, dv, start, scale=scale,
                                       backend="ref"))
     got = np.asarray(paged_chunk_attention(q, pk, pv, tables, start,
-                                           scale=scale, backend=backend))
+                                           scale=scale, backend=backend,
+                                           interpret=True))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
@@ -106,8 +117,8 @@ def test_paged_decode_pallas_supports_guard():
     qs = TensorSpec((1, 4, 8))
     tb = TensorSpec((1, 4), "int32")
     ln = TensorSpec((1,), "int32")
-    ok = TensorSpec((8, 8, 2, 8))       # page 8 % 8 == 0
-    bad = TensorSpec((8, 6, 2, 8))      # page 6 % 8 != 0
+    ok = TensorSpec((8, 8, 16))         # page 8 % 8 == 0
+    bad = TensorSpec((8, 6, 16))        # page 6 % 8 != 0
     assert "pallas" in backends_for("paged_decode_attention",
                                     [qs, ok, ok, tb, ln], {})
     avail = backends_for("paged_decode_attention", [qs, bad, bad, tb, ln], {})
@@ -296,8 +307,8 @@ def test_paged_graph_compiles_under_cost_model_policy():
         tokens=rng.integers(0, 37, size=(2, 4)).astype(np.int32),
         start=np.zeros((2,), np.int32), n_new=np.full((2,), 4, np.int32),
         block_tables=np.asarray([[0, 1, 0, 0], [2, 3, 0, 0]], np.int32),
-        cache_k0=np.zeros((8, 4, 2, 4), np.float32),
-        cache_v0=np.zeros((8, 4, 2, 4), np.float32))
+        cache_k0=np.zeros((8, 4, 2 * 4), np.float32),
+        cache_v0=np.zeros((8, 4, 2 * 4), np.float32))
     assert np.isfinite(np.asarray(logits)).all()
 
 

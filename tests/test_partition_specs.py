@@ -40,17 +40,18 @@ def _leaf(shape):
 
 @pytest.mark.parametrize("hk", [1, 2, 4])
 def test_cache_specs_paged_pool_divides_or_replicates(hk):
-    """(N_pages, page, Hk, D) pools + (N_pages, Hk) sidecars: the kv-head
-    dim shards on "model" when divisible, replicates otherwise — never a
-    crash, whatever the GQA ratio."""
-    tree = {"l0": {"pages_k": _leaf((10, 4, hk, 8)),
-                   "pages_v": _leaf((10, 4, hk, 8)),
+    """(N_pages, page, Hk*D) pools + (N_pages, Hk) sidecars: the lane axis
+    shards on "model" when whole kv heads divide it, replicates otherwise
+    — never a crash, whatever the GQA ratio."""
+    tree = {"l0": {"pages_k": _leaf((10, 4, hk * 8)),
+                   "pages_v": _leaf((10, 4, hk * 8)),
                    "pages_k_scale": _leaf((10, hk)),
                    "pages_v_scale": _leaf((10, hk))}}
-    specs = cache_specs(tree, None, MESH2, batch=3)
+    cfg = types.SimpleNamespace(n_kv_heads=hk)
+    specs = cache_specs(tree, cfg, MESH2, batch=3)
     want_axis = "model" if hk % 2 == 0 else None
-    assert specs["l0"]["pages_k"] == P(None, None, want_axis, None)
-    assert specs["l0"]["pages_v"] == P(None, None, want_axis, None)
+    assert specs["l0"]["pages_k"] == P(None, None, want_axis)
+    assert specs["l0"]["pages_v"] == P(None, None, want_axis)
     assert specs["l0"]["pages_k_scale"] == P(None, want_axis)
     assert specs["l0"]["pages_v_scale"] == P(None, want_axis)
 
@@ -60,9 +61,10 @@ def test_cache_specs_paged_pool_never_batch_sharded():
     the two collide numerically it must not pick up a data-parallel
     shard (rows are block-addressed across every request)."""
     mesh = fake_mesh(data=2, model=2)
-    tree = {"pages_k": _leaf((4, 4, 2, 8))}   # N_pages == 2*dp on purpose
-    specs = cache_specs(tree, None, mesh, batch=3)
-    assert specs["pages_k"] == P(None, None, "model", None)
+    tree = {"pages_k": _leaf((4, 4, 2 * 8))}   # N_pages == 2*dp on purpose
+    specs = cache_specs(tree, types.SimpleNamespace(n_kv_heads=2), mesh,
+                        batch=3)
+    assert specs["pages_k"] == P(None, None, "model")
 
 
 # --------------------------------------------------------------------------- #
@@ -80,7 +82,7 @@ def test_serving_value_role_classification():
     for name in ("tokens", "start", "n_new", "block_tables"):
         assert serving_value_role(name, (3,)) == "replicated", name
     assert serving_value_role("cache_k0", (3, 16, 2, 8)) == "dense_cache"
-    assert serving_value_role("cache_k0", (10, 4, 2, 8),
+    assert serving_value_role("cache_k0", (10, 4, 2 * 8),
                               paged=True) == "paged_pool"
     assert serving_value_role("cache_v1_scale", (10, 2),
                               paged=True) == "kv_scale"
@@ -124,6 +126,14 @@ def test_graph_specs_gqa_fallback(hk, want):
     assert specs["l0.wo"] == P()
     assert specs["tokens"] == P()
     assert specs["logits"] == P()
+    # the fp32 paged pool (N, P, Hk*D) has no head axis of its own: the
+    # kv-head count comes from the rows its write takes
+    g = build_paged_decode_graph(cfg, params, batch=2, n_blocks=8,
+                                 page_size=4, max_pages=4)
+    specs = graph_partition_specs(g, MESH2)
+    assert specs["cache_k0"] == (P(None, None, "model") if want else P())
+    assert specs["new_cache_v0"] == specs["cache_k0"]
+    assert specs["l0.wk"] == (P(None, "model") if want else P())
 
 
 def test_compile_mesh_stamps_frozen_partition():
@@ -136,7 +146,7 @@ def test_compile_mesh_stamps_frozen_partition():
     part = prog.partition
     assert part is not None
     assert dict(part["mesh"]) == {"data": 1, "model": 2}
-    assert part["specs"]["cache_k0"] == P(None, None, "model", None)
+    assert part["specs"]["cache_k0"] == P(None, None, "model")
     assert part["specs"]["cache_k0_scale"] == P(None, "model")
     # frozen: the mappings reject mutation
     with pytest.raises(TypeError):

@@ -13,6 +13,7 @@ import math
 
 import numpy as np
 import pytest
+from conftest import paged_head_cases
 
 import repro  # noqa: F401  (registers every op/backend)
 from repro.core import (AutotunePolicy, CostModelPolicy, FixedPolicy,
@@ -260,8 +261,9 @@ def test_engine_runs_under_fixed_pallas_policy():
 
 def _paged_verify_case(*, b=2, t=4, n_blocks=8, page=4, mp=4, hq=4, hk=2,
                        d=8, start=(0, 7)):
-    """int8 pages under a scrambled block layout + this call's fp32 rows,
-    plus the patched DENSE fp32 equivalent the two-source op must match."""
+    """Flat (N, P, Hk*D) int8 pages under a scrambled block layout + this
+    call's fp32 rows, plus the patched DENSE fp32 equivalent the
+    two-source op must match."""
     rng = _rng()
     start = np.asarray(start, np.int32)
     tables = rng.permutation(n_blocks)[:b * mp].reshape(b, mp).astype(np.int32)
@@ -281,6 +283,7 @@ def _paged_verify_case(*, b=2, t=4, n_blocks=8, page=4, mp=4, hq=4, hk=2,
         for ti in range(t):
             k_dense[bi, start[bi] + ti] = k_new[bi, ti]
             v_dense[bi, start[bi] + ti] = v_new[bi, ti]
+    pages = pages.reshape(n_blocks, page, hk * d)
     return (q, pages, scales, pages.copy(), scales.copy(), tables, start,
             k_new, v_new, k_dense, v_dense)
 
@@ -310,11 +313,11 @@ def test_verify_attention_matches_chunk_attention(scale):
             "scale=0.0 was swallowed by a falsy default"
 
 
-@pytest.mark.parametrize("scale", [None, 0.0])
-def test_paged_verify_attention_backend_parity(scale):
+@pytest.mark.parametrize("scale,hq,hk,d", paged_head_cases(None, 0.0))
+def test_paged_verify_attention_backend_parity(scale, hq, hk, d):
     from repro.kernels.serving_ops import paged_verify_attention
     rng = _rng()
-    b, t, n_blocks, page, mp, hq, hk, d = 2, 4, 8, 4, 4, 4, 2, 8
+    b, t, n_blocks, page, mp = 2, 4, 8, 4, 4
     q = rng.standard_normal((b, t, hq, d)).astype(np.float32)
     pk = rng.standard_normal((n_blocks, page, hk, d)).astype(np.float32)
     pv = rng.standard_normal((n_blocks, page, hk, d)).astype(np.float32)
@@ -327,23 +330,24 @@ def test_paged_verify_attention_backend_parity(scale):
                    for bi in range(b)])
     want = np.asarray(chunk_attention(q, kd, vd, start, scale=scale,
                                       backend="ref"))
+    flat = (n_blocks, page, hk * d)
     for backend in ("ref", "xla", "pallas"):
         assert backend in backends_for("paged_verify_attention")
         out = np.asarray(paged_verify_attention(
-            q, pk, pv, tables, start, scale=scale, backend=backend,
-            interpret=True))
+            q, pk.reshape(flat), pv.reshape(flat), tables, start,
+            scale=scale, backend=backend, interpret=True))
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5,
                                    err_msg=f"{backend} scale={scale}")
 
 
-@pytest.mark.parametrize("scale", [None, 0.0])
-def test_paged_verify_attention_q_two_source_parity(scale):
+@pytest.mark.parametrize("scale,hq,hk,d", paged_head_cases(None, 0.0))
+def test_paged_verify_attention_q_two_source_parity(scale, hq, hk, d):
     """The two-source kv8 verify op: committed prefix dequantized from the
     int8 pages, this call's K+1 rows patched in from fp32 — all backends
     must match the patched-dense fp32 oracle, scale=0.0 included."""
     from repro.kernels.serving_ops import paged_verify_attention_q
     (q, pk, ks, pv, vs, tables, start, k_new, v_new,
-     k_dense, v_dense) = _paged_verify_case()
+     k_dense, v_dense) = _paged_verify_case(hq=hq, hk=hk, d=d)
     want = np.asarray(chunk_attention(q, k_dense, v_dense, start,
                                       scale=scale, backend="ref"))
     for backend in ("ref", "xla", "pallas"):
@@ -363,8 +367,8 @@ def test_verify_attention_pallas_supports_guards():
              TensorSpec((1, 16, 1, 8)), TensorSpec((1,), "int32")]
     avail = backends_for("verify_attention", dense, {"block_q": 2})
     assert "pallas" not in avail and {"ref", "xla"} <= set(avail)
-    qspecs = [TensorSpec((1, 3, 2, 8)), TensorSpec((8, 4, 1, 8), "int8"),
-              TensorSpec((8, 1)), TensorSpec((8, 4, 1, 8), "int8"),
+    qspecs = [TensorSpec((1, 3, 2, 8)), TensorSpec((8, 4, 8), "int8"),
+              TensorSpec((8, 1)), TensorSpec((8, 4, 8), "int8"),
               TensorSpec((8, 1)), TensorSpec((1, 4), "int32"),
               TensorSpec((1,), "int32"), TensorSpec((1, 3, 1, 8)),
               TensorSpec((1, 3, 1, 8))]

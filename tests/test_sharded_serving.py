@@ -71,8 +71,8 @@ with serving_mesh(mesh):
 np.testing.assert_array_equal(got, want)
 
 qd = rng.standard_normal((2, 4, 8)).astype(np.float32)
-pk = rng.integers(-127, 128, size=(6, 4, 2, 8)).astype(np.int8)
-pv = rng.integers(-127, 128, size=(6, 4, 2, 8)).astype(np.int8)
+pk = rng.integers(-127, 128, size=(6, 4, 2 * 8)).astype(np.int8)
+pv = rng.integers(-127, 128, size=(6, 4, 2 * 8)).astype(np.int8)
 ks = rng.uniform(0.01, 0.1, size=(6, 2)).astype(np.float32)
 vs = rng.uniform(0.01, 0.1, size=(6, 2)).astype(np.float32)
 tables = np.array([[0, 2], [1, 3]], np.int32)

@@ -1,9 +1,11 @@
-"""The serving path's Pallas kernels compile for a TPU v5e.
+"""The serving path compiles for a TPU v5e as it should.
 
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 block shapes off the (8, 128) tiling, SMEM or VMEM over budget.  These
 tests compile each ``pallas`` backend the engine selects, at phi3-mini
 widths (B=4, Hq=Hk=32, D=96, page 16, 32 pages per sequence, 128 blocks),
+and the engine's paged decode and prefill Programs at both benchmark
+cells' widths, whose optimized HLO must touch the page pools in place,
 for a described ``v5e:2x2`` topology: the TPU compiler runs here, and no
 chip is needed.  Nothing runs, so results are checked elsewhere.
 
@@ -12,9 +14,11 @@ only the test worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import repro  # noqa: F401  (registers every op/backend)
@@ -62,7 +66,7 @@ def _specs(op, page):
     """(shape, dtype) of every input of ``op`` at the widths above."""
     n_pages = S // page
     f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
-    pool = (N_BLOCKS, page, H, D)
+    pool = (N_BLOCKS, page, H * D)
     table, scales = ((B, n_pages), i32), ((N_BLOCKS, H), f32)
     return {
         "paged_decode_attention": [((B, H, D), f32), (pool, f32),
@@ -105,3 +109,99 @@ def test_pallas_backend_compiles_for_v5e(one_chip, op, page):
     compiled = jax.jit(
         lambda *a: impl(list(a), {"interpret": False})).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --------------------------------------------------------------------------- #
+# The engine's paged step Programs at the benchmark cells' widths (two of
+# their layers each): each step touches a layer's K or V page pool only
+# through the in-place scatter of the new rows and one gather of the slots'
+# pages: no whole-pool copy, relayout or convert (a pool stored (N, P, Hk,
+# D) with D off the 128-lane tiling is laid out block-minor and copied into
+# the scatter's layout and back), no fill select over the gathered view
+# (jnp.take's default mode), and each donated pool aliased to its own
+# update (else XLA copies pools to keep both buffers alive).  An MHA
+# decode step also reads the gathered view in f32: its per-head products
+# are matrix-vector ones, which XLA computes in f32, and the op keeps that.
+# --------------------------------------------------------------------------- #
+
+from repro.core.program import compile as compile_graph  # noqa: E402
+from repro.models.graph_lm import (GraphLMConfig,  # noqa: E402
+                                   build_paged_decode_graph,
+                                   build_paged_prefill_graph)
+
+CELL_WIDTHS = {
+    "phi3-mini": GraphLMConfig(vocab=32064, d_model=3072, n_layers=2,
+                               n_heads=32, n_kv_heads=32, d_ff=8192),
+    "stablelm-2-12b": GraphLMConfig(vocab=100352, d_model=5120, n_layers=2,
+                                    n_heads=32, n_kv_heads=8, d_ff=13824),
+}
+SLOTS, MAX_PAGES, CHUNK = 16, 80, 128
+# more pages than the slots' tables reach, so a pool-sized array and a
+# gathered (SLOTS, MAX_PAGES * PAGE, Hk*D) view differ in size
+N_POOL = 1600
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(",
+                     re.M)
+_ALIAS = re.compile(r"\{(\d+)\}: \((\d+), \{\}, \w+-alias\)")
+
+
+def _zero_params(cfg):
+    """Weights of the graph's shapes; zeros the host never touches."""
+    dm, hq, hk, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    shapes = {"embed": (cfg.vocab, dm), "final_norm": (dm,),
+              "head_w": (dm, cfg.vocab)}
+    for i in range(cfg.n_layers):
+        shapes.update({
+            f"l{i}.norm1": (dm,), f"l{i}.wq": (dm, hq * dh),
+            f"l{i}.wk": (dm, hk * dh), f"l{i}.wv": (dm, hk * dh),
+            f"l{i}.wo": (hq * dh, dm), f"l{i}.norm2": (dm,),
+            f"l{i}.wg": (dm, cfg.d_ff), f"l{i}.wu": (dm, cfg.d_ff),
+            f"l{i}.wd": (cfg.d_ff, dm)})
+    return {k: np.zeros(s, np.float32) for k, s in shapes.items()}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_WIDTHS))
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
+    cfg = CELL_WIDTHS[cell]
+    kw = dict(batch=SLOTS, n_blocks=N_POOL, page_size=PAGE,
+              max_pages=MAX_PAGES)
+    params = _zero_params(cfg)
+    graph = (build_paged_decode_graph(cfg, params, **kw) if step == "decode"
+             else build_paged_prefill_graph(cfg, params, chunk=CHUNK, **kw))
+    prog = compile_graph(graph)
+    pools = sorted(n for n in graph.inputs if n.startswith("cache_"))
+    # bound as the engine binds it: pools donated, in sorted name order
+    names = ("tokens", "start", "n_new", "block_tables", *pools)
+    step_fn = prog.bind(*names, donate=pools)
+    spec = {k: jax.ShapeDtypeStruct(s.shape, jnp.dtype(s.dtype),
+                                    sharding=one_chip)
+            for k, s in graph.inputs.items()}
+    pspec = {k: jax.ShapeDtypeStruct(np.shape(v), jnp.float32,
+                                     sharding=one_chip)
+             for k, v in params.items()}
+    hlo = step_fn.lower(pspec, *[spec[n] for n in names]).compile().as_text()
+
+    pool = N_POOL * PAGE * cfg.n_kv_heads * cfg.d_head
+    view = SLOTS * MAX_PAGES * PAGE * cfg.n_kv_heads * cfg.d_head
+    typed = [(op, dt, int(np.prod([int(d) for d in dims.split(",") if d])))
+             for dt, dims, op in _HLO_OP.findall(hlo)]
+    ops = [(op, n) for op, _, n in typed]
+    assert ("scatter", pool) in ops, "no in-place pool write found"
+    whole = [op for op, n in ops
+             if op in ("copy", "transpose", "convert") and n == pool]
+    assert not whole, f"{step} {cell}: whole-pool {whole}"
+    fills = [op for op, n in ops if op == "select" and n == view]
+    assert not fills, f"{step} {cell}: select over the gathered view"
+    if step == "decode" and cfg.n_heads == cfg.n_kv_heads:
+        # one query row per kv head: the arithmetic of an f32 multiply
+        # and reduce, so no bf16 copy of the view
+        halved = [op for op, dt, n in typed if dt == "bf16" and n == view]
+        assert not halved, f"{cell}: decode rounds the gathered view"
+    # each pool is updated in its own buffer: every new_<pool> output
+    # aliases a donated parameter of its own (a pool handed another pool's
+    # buffer would need one of the whole-pool copies refused above)
+    head = hlo.split("\n", 1)[0]
+    alias = {int(o): int(p) for o, p in _ALIAS.findall(head)}
+    assert sorted(alias) == sorted(graph.outputs.index(f"new_{n}")
+                                   for n in pools)
+    assert len(set(alias.values())) == len(pools)
