@@ -303,13 +303,16 @@ defop("dense", _dense_shape, _dense_cost, doc="x @ w")
 @impl("dense", "ref")
 def _dense_ref(inputs, attrs):
     x, w = inputs
-    return [jnp.matmul(x, w, preferred_element_type=x.dtype)]
+    return [jnp.matmul(x, w, preferred_element_type=x.dtype,
+                       precision=attrs.get("precision"))]
 
 
-@impl("dense", "xla", note="lax.dot_general with fp32 accumulation")
+@impl("dense", "xla", note="lax.dot_general with fp32 accumulation, at the "
+                           "node's ``precision`` attr where it has one")
 def _dense_xla(inputs, attrs):
     x, w = inputs
     out = lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())),
+                          precision=attrs.get("precision"),
                           preferred_element_type=jnp.float32)
     return [out.astype(x.dtype)]
 

@@ -450,7 +450,10 @@ impl("conv2d_fused", "pallas",
              attrs.get("act", "none"))])
 
 
-@impl("dense", "pallas", note="MXU-tiled GEMM")
+@impl("dense", "pallas",
+      supports=lambda specs, attrs: "precision" not in attrs,
+      note="MXU-tiled GEMM at the kernel's own precision (a node that "
+           "states one is refused)")
 def _dense_pallas_impl(inputs, attrs):
     x, w = inputs
     lead = x.shape[:-1]

@@ -77,24 +77,31 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                          lengths: Optional[jax.Array] = None, *,
-                         scale: Optional[float] = None) -> jax.Array:
+                         scale: Optional[float] = None,
+                         window: Optional[int] = None,
+                         precision=None) -> jax.Array:
     """One-new-token attention against a KV cache.
 
     q (B, Hq, D); k/v (B, Skv, Hkv, D); lengths (B,) int32 = #valid cache
     entries per sequence (the new token's own K/V already written at
-    position lengths-1)."""
+    position lengths-1).  With ``window`` the new token sees only the
+    last ``window`` entries; ``precision`` goes to both products."""
     b, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     k = _repeat_kv(k, hq)
     v = _repeat_kv(v, hq)
     s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32) * scale,
-                   k.astype(jnp.float32))
+                   k.astype(jnp.float32), precision=precision)
     if lengths is not None:
-        valid = jnp.arange(skv)[None, None, :] < lengths[:, None, None]
+        pos = jnp.arange(skv)[None, None, :]
+        valid = pos < lengths[:, None, None]
+        if window:
+            valid &= pos >= lengths[:, None, None] - window
         s = jnp.where(valid, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32),
+                   precision=precision)
     return o.astype(q.dtype)
 
 

@@ -58,6 +58,7 @@ from repro.kernels.flash_attention import flash_chunk_attention
 from repro.kernels.ops import pallas_interpret
 
 __all__ = ["embedding", "cache_update", "chunk_attention", "greedy_token",
+           "moe_ffn",
            "verify_attention", "paged_verify_attention",
            "paged_verify_attention_q", "serving_mesh",
            "current_serving_mesh"]
@@ -67,6 +68,18 @@ Attrs = Dict[str, Any]
 
 def _bytes(specs: Sequence[TensorSpec]) -> float:
     return float(sum(s.nbytes for s in specs))
+
+
+def _precision(attrs: Attrs):
+    """The node's ``precision`` attr ("highest", ...) as a
+    ``jax.lax.Precision``; None (the op's default) where it has none."""
+    p = attrs.get("precision")
+    return None if p is None else jax.lax.Precision(p)
+
+
+def _no_window(specs, attrs) -> bool:
+    """For backends with no attention window: refuse a node that has one."""
+    return not attrs.get("window")
 
 
 # --------------------------------------------------------------------------- #
@@ -155,7 +168,7 @@ def _embedding_xla(inputs, attrs):
     # nearest valid row instead of one_hot's all-zero row
     ids = jnp.clip(ids, 0, table.shape[0] - 1)
     onehot = jax.nn.one_hot(ids, table.shape[0], dtype=table.dtype)
-    return [jnp.tensordot(onehot, table, axes=1)]
+    return [jnp.tensordot(onehot, table, axes=1, precision=_precision(attrs))]
 
 
 def embedding(ids, table, *, backend: str = "ref", **kw):
@@ -267,6 +280,18 @@ def _chunk_attn_scale(attrs, d: int) -> float:
     return (1.0 / math.sqrt(d)) if scale is None else scale
 
 
+def _chunk_visible(start, t: int, s: int, attrs: Attrs):
+    """(B, T, S) bool: query t of a slot (absolute position start + t)
+    sees key j iff j <= start + t and, with a ``window`` attr, also
+    start + t - j < window."""
+    qpos = start[:, None] + jnp.arange(t)[None, :]              # (B, T)
+    keys = jnp.arange(s)[None, None, :]
+    allowed = keys <= qpos[:, :, None]
+    if attrs.get("window"):
+        allowed &= keys > qpos[:, :, None] - attrs["window"]
+    return allowed
+
+
 def _chunk_attn_ref_cost(specs, attrs):
     """Adds the oracle's materialisation traffic: GQA-repeated K/V in
     fp32 plus the dense (B, Hq, T, S) logits and probability tensors."""
@@ -285,14 +310,15 @@ def _chunk_attention_ref(inputs, attrs):
     b, t, hq, d = q.shape
     s = k.shape[1]
     scale = _chunk_attn_scale(attrs, d)
+    prec = _precision(attrs)
     kf = R._repeat_kv(k, hq).astype(jnp.float32)
     vf = R._repeat_kv(v, hq).astype(jnp.float32)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kf)
-    qpos = start[:, None] + jnp.arange(t)[None, :]            # (B, T)
-    allowed = jnp.arange(s)[None, None, :] <= qpos[:, :, None]  # (B, T, S)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kf,
+                        precision=prec)
+    allowed = _chunk_visible(start, t, s, attrs)                # (B, T, S)
     logits = jnp.where(allowed[:, None, :, :], logits, R._NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, vf, precision=prec)
     return [o.astype(q.dtype)]
 
 
@@ -306,13 +332,15 @@ def _chunk_attention_xla(inputs, attrs):
     assert hq % hk == 0, (hq, hk)
     g = hq // hk
     scale = _chunk_attn_scale(attrs, d)
+    prec = _precision(attrs)
     qg = (q.astype(jnp.float32) * scale).reshape(b, t, hk, g, d)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(jnp.float32))
-    qpos = start[:, None] + jnp.arange(t)[None, :]              # (B, T)
-    allowed = jnp.arange(s)[None, None, :] <= qpos[:, :, None]  # (B, T, S)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(jnp.float32),
+                        precision=prec)
+    allowed = _chunk_visible(start, t, s, attrs)                # (B, T, S)
     logits = jnp.where(allowed[:, None, None, :, :], logits, R._NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32),
+                   precision=prec)
     return [o.reshape(b, t, hq, d).astype(q.dtype)]
 
 
@@ -559,12 +587,13 @@ def _smem_fits(q, tables, *scales) -> bool:
 
 def _paged_chunk_pallas_supports(specs, attrs):
     """T % block_q == 0 (block clamped to T), page_size % 8 == 0 (TPU
-    sublane tiling of one page per KV step), Hq divisible by Hk and the
-    SMEM operands within budget."""
+    sublane tiling of one page per KV step), Hq divisible by Hk, the
+    SMEM operands within budget, and no attention window."""
     q, pk = specs[0], specs[1]
     bq = min(int(attrs.get("block_q", 256)), q.shape[1])
     return (q.shape[1] % bq == 0 and pk.shape[1] % 8 == 0
-            and _whole_groups(q, pk) and _smem_fits(q, specs[3]))
+            and _whole_groups(q, pk) and _smem_fits(q, specs[3])
+            and _no_window(specs, attrs))
 
 
 @impl("paged_chunk_attention", "pallas",
@@ -628,7 +657,9 @@ def _paged_decode_attention_ref(inputs, attrs):
     k = _split_heads(_gather_pages(pk, tables), q.shape[-1])
     v = _split_heads(_gather_pages(pv, tables), q.shape[-1])
     return [R.decode_attention_ref(q, k, v, lengths,
-                                   scale=attrs.get("scale"))]
+                                   scale=attrs.get("scale"),
+                                   window=attrs.get("window"),
+                                   precision=_precision(attrs))]
 
 
 def _paged_dec_xla_cost(specs, attrs):
@@ -681,17 +712,24 @@ def _decode_attention_flat(q, k, v, lengths, attrs):
     rounded to bf16 and sums in f32, and so is this one.  With one query
     row per kv head (Hq == Hk) it is a matrix-vector product per head,
     which XLA computes as an f32 multiply and reduce; the block-diagonal
-    form is a matmul there, so it runs at ``HIGHEST`` to stay f32."""
+    form is a matmul there, so it runs at ``HIGHEST`` to stay f32.  A
+    node's own ``precision`` attr takes the place of both rules.  With a
+    ``window`` attr the query sees only the last ``window`` rows."""
     b, hq, d = q.shape
     s, hkd = k.shape[1], k.shape[2]
     hk = hkd // d
-    prec = jax.lax.Precision.HIGHEST if hq == hk else None
+    prec = _precision(attrs)
+    if prec is None and hq == hk:
+        prec = jax.lax.Precision.HIGHEST
     own = _head_mask(hq, hk)[:, :, None]                        # (Hq, Hk, 1)
     qs = (q.astype(jnp.float32) * _chunk_attn_scale(attrs, d))[:, :, None]
     qbd = jnp.where(own, qs, 0.0).reshape(b, hq, hkd)          # (B, Hq, HkD)
     logits = jnp.einsum("bqj,bsj->bqs", qbd, k.astype(jnp.float32),
                         precision=prec)
-    allowed = jnp.arange(s)[None, None, :] < lengths[:, None, None]
+    keys = jnp.arange(s)[None, None, :]
+    allowed = keys < lengths[:, None, None]
+    if attrs.get("window"):
+        allowed &= keys >= lengths[:, None, None] - attrs["window"]
     logits = jnp.where(allowed, logits, R._NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bqs,bsj->bqj", p, v.astype(jnp.float32),
@@ -712,11 +750,11 @@ def _paged_decode_attention_xla(inputs, attrs):
 
 def _paged_dec_pallas_supports(specs, attrs):
     """page_size % 8 == 0 (TPU sublane tiling of one page per KV step), Hq
-    divisible by Hk (whole GQA groups) and the SMEM operands within
-    budget."""
+    divisible by Hk (whole GQA groups), the SMEM operands within budget,
+    and no attention window."""
     q, pk = specs[0], specs[1]
     return (pk.shape[1] % 8 == 0 and _whole_groups(q, pk)
-            and _smem_fits(q, specs[3]))
+            and _smem_fits(q, specs[3]) and _no_window(specs, attrs))
 
 
 @impl("paged_decode_attention", "pallas", supports=_paged_dec_pallas_supports,
@@ -1068,6 +1106,166 @@ def paged_decode_attention_q(q, pages_k, k_scales, pages_v, v_scales, tables,
     return get_impl("paged_decode_attention_q", backend)(
         [q, pages_k, k_scales, pages_v, v_scales, tables, lengths],
         {"scale": scale, **kw})[0]
+
+
+# --------------------------------------------------------------------------- #
+# moe_ffn — the routed-expert SwiGLU FFN of a mixture-of-experts layer,
+# dropless: every live row reaches all of its top_k experts, however skewed
+# the routing (a capacity-bucketed dispatch would drop rows past each
+# expert's capacity, and the result would then depend on the batch).
+# inputs (x (B,T,d), n_new (B,), router (d,E), w_gate (E,d,f), w_up (E,d,f),
+#         w_down (E,f,d)) -> [y (B,T,d), counts (1,E) int32]
+# Rows at or past ``n_new`` (chunk padding, idle slots) are routed nowhere:
+# their y is 0 and ``counts`` holds the live rows sent to each expert.
+# attrs: top_k, norm_topk (softmax over the chosen experts' logits, else
+# over all of them), precision.
+# --------------------------------------------------------------------------- #
+
+def _moe_shape(specs, attrs):
+    x, n_new, router, wg, wu, wd = specs
+    e, d, f = wg.shape
+    if (x.shape[-1], router.shape) != (d, (d, e)) or wu.shape != wg.shape \
+            or wd.shape != (e, f, d) or n_new.shape != (x.shape[0],):
+        raise ValueError(f"moe_ffn shapes: x {x.shape}, n_new {n_new.shape}, "
+                         f"router {router.shape}, experts {wg.shape} "
+                         f"{wu.shape} {wd.shape}")
+    if not 0 < int(attrs.get("top_k", 0)) <= e:
+        raise ValueError(f"moe_ffn top_k {attrs.get('top_k')} of {e}")
+    return [x, TensorSpec((1, e), "int32")]
+
+
+def _moe_hit(rows: int, k: int, e: int) -> float:
+    """Experts hit by ``rows`` rows routed uniformly to k of e each."""
+    return e * (1.0 - (1.0 - k / e) ** rows)
+
+
+def _moe_cost(specs, attrs):
+    """The dropless grouped form: the router over every row, k experts'
+    SwiGLU per row, and the weights of the experts that uniform routing
+    hits read once each, plus the grouped rows in and out."""
+    x, router, wg = specs[0], specs[2], specs[3]
+    e, d, f = wg.shape
+    k = int(attrs["top_k"])
+    n = x.nelems // d
+    itemsize = x.nbytes / x.nelems
+    expert = 3.0 * wg.nbytes / e
+    return Cost(flops=2.0 * n * d * e + 6.0 * n * k * d * f,
+                bytes=(router.nbytes + _moe_hit(n, k, e) * expert
+                       + itemsize * n * k * (2.0 * d + 3.0 * f)
+                       + 2.0 * x.nbytes))
+
+
+defop("moe_ffn", _moe_shape, _moe_cost,
+      doc="dropless routed-expert SwiGLU FFN: top_k of E experts per live "
+          "row, weighted by the router's softmax; inputs (x (B,T,d), "
+          "n_new (B,), router (d,E), w_gate (E,d,f), w_up (E,d,f), "
+          "w_down (E,f,d)); outputs [y (B,T,d), counts (1,E) int32]; "
+          "attrs: top_k, norm_topk, precision")
+
+
+def _moe_route(x, n_new, router, attrs):
+    """Flat rows (N, d), live mask (N,), the top_k experts of each row
+    (N, k; ties to the lower index) and their weights (N, k)."""
+    b, t, d = x.shape
+    rows = x.reshape(b * t, d)
+    live = (jnp.arange(t)[None, :] < n_new[:, None]).reshape(-1)
+    logits = jnp.matmul(rows, router, precision=_precision(attrs))
+    top, experts = jax.lax.top_k(logits, int(attrs["top_k"]))
+    if attrs.get("norm_topk", True):
+        weights = jax.nn.softmax(top, axis=-1)
+    else:
+        weights = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
+                                      experts, axis=-1)
+    return rows, live, experts, weights
+
+
+def _moe_counts(live, experts, e: int):
+    """(1, E) int32: live rows routed to each expert."""
+    hits = jnp.where(live[:, None], experts, e).reshape(-1)
+    return jnp.bincount(hits, length=e + 1)[None, :e].astype(jnp.int32)
+
+
+def _moe_ref_cost(specs, attrs):
+    """Every expert for every row: E times the grouped form's products,
+    every expert's weights and the (N, E, f) intermediates."""
+    x, wg = specs[0], specs[3]
+    e, d, f = wg.shape
+    n = x.nelems // d
+    return Cost(flops=2.0 * n * d * e + 6.0 * n * e * d * f,
+                bytes=_bytes(specs) + 4.0 * 6.0 * n * e * f
+                      + 4.0 * 2.0 * n * e * d)
+
+
+@impl("moe_ffn", "ref", cost_fn=_moe_ref_cost,
+      note="the oracle: every expert computed for every row, batched over "
+           "the experts, the weights of the experts not chosen set to zero")
+def _moe_ffn_ref(inputs, attrs):
+    x, n_new, router, wg, wu, wd = inputs
+    e = wg.shape[0]
+    prec = _precision(attrs)
+    rows, live, experts, weights = _moe_route(x, n_new, router, attrs)
+    each = jnp.broadcast_to(rows, (e,) + rows.shape)            # (E, N, d)
+    gate = jnp.einsum("end,edf->enf", each, wg, precision=prec)
+    up = jnp.einsum("end,edf->enf", each, wu, precision=prec)
+    out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up, wd,
+                     precision=prec)
+    dense = jnp.sum(jax.nn.one_hot(experts, e, dtype=rows.dtype)
+                    * weights[..., None], axis=1)               # (N, E)
+    y = jnp.einsum("ne,end->nd", jnp.where(live[:, None], dense, 0.0), out,
+                   precision=prec)
+    return [y.reshape(x.shape).astype(x.dtype),
+            _moe_counts(live, experts, e)]
+
+
+# rows of one tile of the TPU's grouped matmul (the Mosaic kernel that
+# jax.lax.ragged_dot lowers to): it computes a whole tile of rows for every
+# expert it visits
+_GROUP_TILE = 128
+
+
+@impl("moe_ffn", "xla", cost_fn=_moe_cost,
+      note="dropless grouped matmuls: (row, expert) pairs sorted by expert, "
+           "each hit expert's weights read once (jax.lax.ragged_dot, a "
+           "Mosaic kernel on the TPU), then unsorted and summed per row; "
+           "with fewer rows than a tile and every expert likely hit, the "
+           "oracle's every-expert form, which computes the rows there are")
+def _moe_ffn_xla(inputs, attrs):
+    x, n_new, router, wg, wu, wd = inputs
+    e, k, n = wg.shape[0], int(attrs["top_k"]), x.shape[0] * x.shape[1]
+    if n <= _GROUP_TILE and e <= n * k:
+        # a decode step: the grouped kernel would compute a whole tile of
+        # rows for each of the (nearly all) experts the rows hit; every
+        # expert batched over the rows reads the same weights once and
+        # computes only the rows (on a TPU v5e: 2.14 against 5.75 ms a
+        # layer at mellum2's 16 rows)
+        return _moe_ffn_ref(inputs, attrs)
+    prec = _precision(attrs)
+    rows, live, experts, weights = _moe_route(x, n_new, router, attrs)
+    # pairs of dead rows go to expert e, past every group: sorted last
+    pair_expert = jnp.where(live[:, None], experts, e).reshape(-1)
+    order = jnp.argsort(pair_expert, stable=True)
+    sizes = jnp.bincount(pair_expert, length=e + 1)[:e]
+    grouped = jnp.take(rows, order // k, axis=0)               # (N*k, d)
+    gate = jax.lax.ragged_dot(grouped, wg, sizes, precision=prec)
+    up = jax.lax.ragged_dot(grouped, wu, sizes, precision=prec)
+    down = jax.lax.ragged_dot(jax.nn.silu(gate) * up, wd, sizes,
+                              precision=prec)                  # (N*k, d)
+    # rows past the last group belong to no expert: zero, whatever the
+    # grouped product left there
+    scaled = jnp.where((pair_expert[order] < e)[:, None],
+                       down * weights.reshape(-1)[order][:, None], 0.0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    y = jnp.take(scaled, back, axis=0).reshape(n, k, -1).sum(axis=1)
+    return [y.reshape(x.shape).astype(x.dtype),
+            sizes[None, :].astype(jnp.int32)]
+
+
+def moe_ffn(x, n_new, router, w_gate, w_up, w_down, *, top_k: int,
+            norm_topk: bool = True, precision: Optional[str] = None,
+            backend: str = "ref"):
+    return get_impl("moe_ffn", backend)(
+        [x, n_new, router, w_gate, w_up, w_down],
+        {"top_k": top_k, "norm_topk": norm_topk, "precision": precision})
 
 
 # --------------------------------------------------------------------------- #

@@ -43,8 +43,8 @@ import numpy as np
 
 from repro.core.ir import Graph, Node, TensorSpec
 
-__all__ = ["GraphLMConfig", "init_lm_params", "build_decode_graph",
-           "build_prefill_graph", "init_cache_inputs",
+__all__ = ["GraphLMConfig", "init_lm_params", "refuse_beyond_dense",
+           "build_decode_graph", "build_prefill_graph", "init_cache_inputs",
            "build_paged_decode_graph", "build_paged_prefill_graph",
            "init_paged_cache_inputs", "build_verify_graph",
            "build_paged_verify_graph", "build_paged_verify_seq_graph",
@@ -54,8 +54,18 @@ __all__ = ["GraphLMConfig", "init_lm_params", "build_decode_graph",
 
 @dataclass(frozen=True)
 class GraphLMConfig:
-    """Shape of the graph LM.  ``d_head = d_model // n_heads``; GQA when
-    ``n_kv_heads < n_heads``."""
+    """Shape of the graph LM.  GQA when ``n_kv_heads < n_heads``;
+    ``d_head`` is ``head_dim`` when given, else ``d_model // n_heads``.
+
+    ``windows`` gives each layer's attention window (0 or an empty tuple:
+    full causal attention): a query at position i sees key j iff
+    ``0 <= i - j < window``.  ``n_experts > 0`` replaces every layer's
+    SwiGLU MLP by a dropless routed-expert FFN (``moe_ffn``): ``top_k`` of
+    ``n_experts`` SwiGLU experts of width ``d_expert`` per token, weighted
+    by the softmax of the router logits over the chosen ones
+    (``norm_topk``) or over all experts.  ``matmul_precision``
+    (``"highest"``, ...) is carried into every matmul node of the graphs;
+    None leaves each op's own default."""
 
     vocab: int = 128
     d_model: int = 64
@@ -64,10 +74,37 @@ class GraphLMConfig:
     n_kv_heads: int = 2
     d_ff: int = 128
     eps: float = 1e-6
+    head_dim: Optional[int] = None
+    windows: Tuple[int, ...] = ()
+    n_experts: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    norm_topk: bool = True
+    matmul_precision: Optional[str] = None
+
+    def __post_init__(self):
+        if self.windows and len(self.windows) != self.n_layers:
+            raise ValueError(f"{len(self.windows)} windows for "
+                             f"{self.n_layers} layers")
+        if self.n_experts and not 0 < self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts} "
+                             f"experts")
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_dim or self.d_model // self.n_heads
+
+    def window(self, layer: int) -> int:
+        """Layer ``layer``'s attention window; 0 is full attention."""
+        return self.windows[layer] if self.windows else 0
+
+    @property
+    def beyond_dense(self) -> List[str]:
+        """What this config adds to the dense decoder: "windows",
+        "experts"; the serving paths that have only the dense decoder
+        (dense KV layout, int8, speculative decoding, TP) refuse these."""
+        return ([n for n, on in (("windows", any(self.windows)),
+                                 ("experts", self.n_experts > 0)) if on])
 
 
 def init_lm_params(cfg: GraphLMConfig, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -75,8 +112,9 @@ def init_lm_params(cfg: GraphLMConfig, seed: int = 0) -> Dict[str, np.ndarray]:
     names the graph builders reference."""
     rng = np.random.default_rng(seed)
 
-    def dense(din: int, dout: int) -> np.ndarray:
-        return (rng.standard_normal((din, dout)) / np.sqrt(din)).astype(np.float32)
+    def dense(*shape: int) -> np.ndarray:
+        return (rng.standard_normal(shape)
+                / np.sqrt(shape[-2])).astype(np.float32)
 
     dm, dh = cfg.d_model, cfg.d_head
     p: Dict[str, np.ndarray] = {
@@ -91,10 +129,25 @@ def init_lm_params(cfg: GraphLMConfig, seed: int = 0) -> Dict[str, np.ndarray]:
         p[f"l{i}.wv"] = dense(dm, cfg.n_kv_heads * dh)
         p[f"l{i}.wo"] = dense(cfg.n_heads * dh, dm)
         p[f"l{i}.norm2"] = np.ones((dm,), np.float32)
-        p[f"l{i}.wg"] = dense(dm, cfg.d_ff)
-        p[f"l{i}.wu"] = dense(dm, cfg.d_ff)
-        p[f"l{i}.wd"] = dense(cfg.d_ff, dm)
+        if cfg.n_experts:
+            e, f = cfg.n_experts, cfg.d_expert
+            p[f"l{i}.router"] = dense(dm, e)
+            p[f"l{i}.we_gate"] = dense(e, dm, f)
+            p[f"l{i}.we_up"] = dense(e, dm, f)
+            p[f"l{i}.we_down"] = dense(e, f, dm)
+        else:
+            p[f"l{i}.wg"] = dense(dm, cfg.d_ff)
+            p[f"l{i}.wu"] = dense(dm, cfg.d_ff)
+            p[f"l{i}.wd"] = dense(cfg.d_ff, dm)
     return p
+
+
+def refuse_beyond_dense(cfg: GraphLMConfig, path: str) -> None:
+    """Raise for a config with windows or experts on a serving path that
+    has only the dense decoder (``path`` names it)."""
+    if cfg.beyond_dense:
+        raise ValueError(f"{path} serves the dense decoder only; this "
+                         f"config has {' and '.join(cfg.beyond_dense)}")
 
 
 def init_cache_inputs(cfg: GraphLMConfig, batch: int,
@@ -146,6 +199,13 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
     kv8 = kv_dtype == "int8"
     if kv8 and paged is None:
         raise ValueError("kv_dtype='int8' requires the paged cache layout")
+    if any(cfg.windows) and paged is None:
+        raise ValueError("attention windows need the paged cache layout")
+    if kv8 or verify:
+        refuse_beyond_dense(cfg, "int8 KV" if kv8 else "the verify step")
+    # matmul precision, carried by every node that multiplies matrices
+    prec = ({"precision": cfg.matmul_precision} if cfg.matmul_precision
+            else {})
     dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "tokens": TensorSpec((batch, t), "int32"),
@@ -170,18 +230,22 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
                 inputs[f"cache_v{i}_scale"] = sspec
 
     nodes: List[Node] = [Node("embed_lookup", "embedding",
-                              ["tokens", "embed"], ["x0"])]
+                              ["tokens", "embed"], ["x0"], dict(prec))]
     if decode:
         nodes.append(Node("kv_len", "add", ["start", "n_new"], ["kvlen"]))
     x = "x0"
     eps = {"eps": cfg.eps}
     for i in range(cfg.n_layers):
         L = f"l{i}"
+        att = dict(prec, window=cfg.window(i)) if cfg.window(i) else prec
         nodes += [
             Node(f"{L}.attn_norm", "rmsnorm", [x, f"{L}.norm1"], [f"{L}.h1"], dict(eps)),
-            Node(f"{L}.q_proj", "dense", [f"{L}.h1", f"{L}.wq"], [f"{L}.q"]),
-            Node(f"{L}.k_proj", "dense", [f"{L}.h1", f"{L}.wk"], [f"{L}.k"]),
-            Node(f"{L}.v_proj", "dense", [f"{L}.h1", f"{L}.wv"], [f"{L}.v"]),
+            Node(f"{L}.q_proj", "dense", [f"{L}.h1", f"{L}.wq"], [f"{L}.q"],
+                 dict(prec)),
+            Node(f"{L}.k_proj", "dense", [f"{L}.h1", f"{L}.wk"], [f"{L}.k"],
+                 dict(prec)),
+            Node(f"{L}.v_proj", "dense", [f"{L}.h1", f"{L}.wv"], [f"{L}.v"],
+                 dict(prec)),
             Node(f"{L}.k_heads", "reshape", [f"{L}.k"], [f"{L}.k4"],
                  {"shape": (batch, t, hk, dh)}),
             Node(f"{L}.v_heads", "reshape", [f"{L}.v"], [f"{L}.v4"],
@@ -241,7 +305,7 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
                 nodes.append(Node(
                     f"{L}.attn", "paged_decode_attention",
                     [f"{L}.qd", f"new_cache_k{i}", f"new_cache_v{i}",
-                     "block_tables", "kvlen"], [f"{L}.att"]))
+                     "block_tables", "kvlen"], [f"{L}.att"], dict(att)))
         else:
             nodes.append(Node(f"{L}.q_heads", "reshape", [f"{L}.q"],
                               [f"{L}.q4"], {"shape": (batch, t, hq, dh)}))
@@ -277,31 +341,51 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
                 nodes.append(Node(
                     f"{L}.attn", op,
                     [f"{L}.q4", f"new_cache_k{i}", f"new_cache_v{i}",
-                     "block_tables", "start"], [f"{L}.att"]))
+                     "block_tables", "start"], [f"{L}.att"], dict(att)))
         nodes += [
             Node(f"{L}.attn_flat", "reshape", [f"{L}.att"], [f"{L}.attn2"],
                  {"shape": (batch, t, hq * dh)}),
-            Node(f"{L}.o_proj", "dense", [f"{L}.attn2", f"{L}.wo"], [f"{L}.proj"]),
+            Node(f"{L}.o_proj", "dense", [f"{L}.attn2", f"{L}.wo"],
+                 [f"{L}.proj"], dict(prec)),
             Node(f"{L}.attn_res", "add", [x, f"{L}.proj"], [f"{L}.xa"]),
             Node(f"{L}.mlp_norm", "rmsnorm", [f"{L}.xa", f"{L}.norm2"],
                  [f"{L}.h2"], dict(eps)),
-            Node(f"{L}.gate_proj", "dense", [f"{L}.h2", f"{L}.wg"], [f"{L}.gate"]),
-            Node(f"{L}.up_proj", "dense", [f"{L}.h2", f"{L}.wu"], [f"{L}.up"]),
-            Node(f"{L}.swiglu", "swiglu", [f"{L}.gate", f"{L}.up"], [f"{L}.act"]),
-            Node(f"{L}.down_proj", "dense", [f"{L}.act", f"{L}.wd"], [f"{L}.down"]),
-            Node(f"{L}.mlp_res", "add", [f"{L}.xa", f"{L}.down"], [f"{L}.out"]),
         ]
+        if cfg.n_experts:
+            # rows past n_new (chunk padding, idle slots) are routed to no
+            # expert, so the per-layer counts hold live rows only
+            nodes.append(Node(
+                f"{L}.experts", "moe_ffn",
+                [f"{L}.h2", "n_new", f"{L}.router", f"{L}.we_gate",
+                 f"{L}.we_up", f"{L}.we_down"],
+                [f"{L}.down", f"{L}.moe_counts"],
+                dict(prec, top_k=cfg.top_k, norm_topk=cfg.norm_topk)))
+        else:
+            nodes += [
+                Node(f"{L}.gate_proj", "dense", [f"{L}.h2", f"{L}.wg"],
+                     [f"{L}.gate"], dict(prec)),
+                Node(f"{L}.up_proj", "dense", [f"{L}.h2", f"{L}.wu"],
+                     [f"{L}.up"], dict(prec)),
+                Node(f"{L}.swiglu", "swiglu", [f"{L}.gate", f"{L}.up"],
+                     [f"{L}.act"]),
+                Node(f"{L}.down_proj", "dense", [f"{L}.act", f"{L}.wd"],
+                     [f"{L}.down"], dict(prec)),
+            ]
+        nodes.append(Node(f"{L}.mlp_res", "add", [f"{L}.xa", f"{L}.down"],
+                          [f"{L}.out"]))
         x = f"{L}.out"
     nodes.append(Node("final_norm_n", "rmsnorm", [x, "final_norm"],
                       ["final_h"], dict(eps)))
     if decode:
         nodes += [
-            Node("lm_head", "dense", ["final_h", "head_w"], ["logits3"]),
+            Node("lm_head", "dense", ["final_h", "head_w"], ["logits3"],
+                 dict(prec)),
             Node("logits_flat", "reshape", ["logits3"], ["logits"],
                  {"shape": (batch, cfg.vocab)}),
         ]
     else:
-        nodes.append(Node("lm_head", "dense", ["final_h", "head_w"], ["logits"]))
+        nodes.append(Node("lm_head", "dense", ["final_h", "head_w"],
+                          ["logits"], dict(prec)))
     outputs = ["logits"]
     if kv8 and verify:
         # no page writes happened; hand the fp32 K/V rows of this call's
@@ -315,6 +399,12 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
             if kv8:
                 outputs += [f"new_cache_k{i}_scale",
                             f"new_cache_v{i}_scale"]
+    if cfg.n_experts:
+        # (L, E) int32: live rows routed to each expert of each layer
+        nodes.append(Node("moe_counts_all", "concat",
+                          [f"l{i}.moe_counts" for i in range(cfg.n_layers)],
+                          ["moe_counts"], {"axis": 0}))
+        outputs.append("moe_counts")
     mode = "decode" if decode else ("verify" if verify else "prefill")
     tag = ("paged_kv8_" if kv8 else "paged_") if paged is not None else ""
     g = Graph(name=f"graph_lm_{tag}{mode}_b{batch}_t{t}", inputs=inputs,
@@ -434,6 +524,7 @@ def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
     layer, the fp32 ``l{i}.k4.s{j}`` / ``l{i}.v4.s{j}`` rows."""
     if width < 1:
         raise ValueError(f"width must be >= 1, got {width}")
+    refuse_beyond_dense(cfg, "the kv8 verify step")
     dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "start": TensorSpec((batch,), "int32"),
@@ -659,6 +750,7 @@ def build_draft_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
         raise ValueError(f"spec_k must be >= 1, got {spec_k}")
     if spec_k + 1 > cache_cap:
         raise ValueError(f"spec_k {spec_k} + 1 exceeds cache cap {cache_cap}")
+    refuse_beyond_dense(cfg, "the draft model")
     dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "tokens": TensorSpec((batch, 1), "int32"),

@@ -81,6 +81,7 @@ from repro.core.selector import BackendPolicy, FixedPolicy
 from repro.ft.coordinator import Coordinator
 from repro.ft.watchdog import HangDetector, StepWatchdog
 from repro.models.graph_lm import (GraphLMConfig, build_decode_graph,
+                                   refuse_beyond_dense,
                                    build_draft_graph,
                                    build_paged_decode_graph,
                                    build_paged_prefill_graph,
@@ -221,6 +222,15 @@ class EngineMetrics:
     # for a speculative-vs-baseline speedup (prefill is identical in both)
     decode_tokens: int = 0
     decode_wall_s: float = 0.0
+    # mixture-of-experts configs: (n_layers, n_experts) live rows routed
+    # to each expert, summed over the step calls (None for a dense model)
+    expert_rows: Optional[np.ndarray] = None
+
+    def add_expert_rows(self, counts: Optional[np.ndarray]) -> None:
+        if counts is not None:
+            self.expert_rows = (counts.astype(np.int64)
+                                if self.expert_rows is None
+                                else self.expert_rows + counts)
 
     @property
     def busy_slot_fraction(self) -> float:
@@ -277,6 +287,8 @@ class EngineMetrics:
                 "decode_wall_s": self.decode_wall_s,
                 "decode_tokens_per_s": self.decode_tokens_per_s,
             },
+            "expert_rows": (None if self.expert_rows is None
+                            else self.expert_rows.tolist()),
         }
 
 
@@ -342,7 +354,7 @@ class ProgramStepper:
                                            quantize=quantize,
                                            calib_ranges=calib_ranges,
                                            mesh=mesh)
-            self.cache_names = [v for v in dec_g.outputs[1:]]  # new_cache_*
+            self._outputs(dec_g)
             cache_inputs = sorted(init_cache_inputs(cfg, 1, 1))
             self._cache_input_names = cache_inputs
             self._input_names = ("tokens", "start", "n_new", *cache_inputs)
@@ -362,6 +374,15 @@ class ProgramStepper:
             self._init_spec(params, policy=policy, quantize=quantize,
                             calib_ranges=calib_ranges, spec_k=spec_k,
                             draft_layers=draft_layers, verify_graph=verify_g)
+
+    def _outputs(self, graph) -> None:
+        """Where a step Program's outputs go: logits first, then the new
+        caches (``new_*``), then, for a mixture-of-experts config, the
+        (L, E) per-expert row counts (``moe_counts``)."""
+        self.cache_names = [v for v in graph.outputs if v.startswith("new_")]
+        self._counts_at = (graph.outputs.index("moe_counts")
+                           if "moe_counts" in graph.outputs else None)
+        self.moe_counts: Optional[np.ndarray] = None
 
     def _mesh_ctx(self):
         """serving-mesh context for compiles and Program calls (no-op when
@@ -395,7 +416,10 @@ class ProgramStepper:
         """One step Program call, in three profiler spans: staging and
         launch (``stepper.stage``), the host waiting on the device
         (``stepper.wait``), and the logits copy to the host
-        (``stepper.fetch``)."""
+        (``stepper.fetch``).  A mixture-of-experts step also fetches its
+        per-expert row counts into ``moe_counts`` and marks the trace
+        with a ``moe.counts`` event: ``hit``, the (layer, expert) pairs
+        that got a row, and ``routed``, the (row, expert) assignments."""
         with jax.profiler.TraceAnnotation("stepper.stage"):
             extra = self._stage(tokens, start, n_new)
             cache_args = [self.caches[n] for n in sorted(self.caches)]
@@ -407,6 +431,13 @@ class ProgramStepper:
             outs[0].block_until_ready()
         with jax.profiler.TraceAnnotation("stepper.fetch"):
             logits = np.asarray(outs[0])
+        if self._counts_at is not None:
+            counts = np.asarray(outs[self._counts_at])
+            self.moe_counts = counts
+            with jax.profiler.TraceAnnotation(
+                    "moe.counts", hit=int(np.count_nonzero(counts)),
+                    routed=int(counts.sum())):
+                pass
         for name, arr in zip(self.cache_names, outs[1:]):
             self.caches[name.replace("new_", "")] = arr
         return logits
@@ -641,7 +672,7 @@ class PagedProgramStepper(ProgramStepper):
                                       calib_ranges=calib_ranges, mesh=mesh)
         self.prefill_program = compile(pre_g, policy=policy, quantize=quantize,
                                        calib_ranges=calib_ranges, mesh=mesh)
-        self.cache_names = [v for v in dec_g.outputs[1:]]
+        self._outputs(dec_g)
         cache_inputs = sorted(init_paged_cache_inputs(cfg, 1, 1,
                                                       kv_dtype=kv_dtype))
         self._cache_input_names = cache_inputs
@@ -1317,6 +1348,7 @@ class Engine:
             start[s] = st.pos
             n_new[s] = n
         logits = self._guarded_call(self.stepper.prefill, tokens, start, n_new)
+        self.metrics.add_expert_rows(self.stepper.moe_counts)
         self.metrics.prefill_ticks += 1
         self.metrics.busy_slot_ticks += len(slots)
         with jax.profiler.TraceAnnotation("engine.emit"):
@@ -1344,6 +1376,7 @@ class Engine:
             start[s] = st.length
             n_new[s] = 1
         logits = self._guarded_call(self.stepper.decode, tokens, start, n_new)
+        self.metrics.add_expert_rows(self.stepper.moe_counts)
         self.metrics.decode_ticks += 1
         self.metrics.busy_slot_ticks += len(slots)
         with jax.profiler.TraceAnnotation("engine.emit"):
@@ -1658,7 +1691,7 @@ class UnbatchedReference:
         cache_inputs = sorted(init_cache_inputs(self.cfg, 1, 1))
         names = ("tokens", "start", "n_new", *cache_inputs)
         return (prog.bind(*names, donate=cache_inputs),
-                [v for v in graph.outputs[1:]])
+                [v for v in graph.outputs if v.startswith("new_")])
 
     def _prefill_for(self, chunk: int) -> Tuple[Any, List[str]]:
         if chunk not in self._prefills:
@@ -1844,10 +1877,25 @@ def build_lm_serving(cfg: Optional[GraphLMConfig] = None, *,
     their stamped NamedShardings, and attention runs the shard_map ``tp``
     backends — token-identical to the single-device engine (heads are
     computed whole per device; the only collective is an exact output
-    all-gather).  The reference stays single-device: it is the oracle."""
+    all-gather).  The reference stays single-device: it is the oracle.
+
+    A config with attention windows or routed experts
+    (:attr:`GraphLMConfig.beyond_dense`) serves on the fp32 paths only:
+    int8 weights or KV, speculative decoding and tensor parallelism
+    refuse it here, and windows need ``paged=True``."""
     cfg = cfg or GraphLMConfig()
     if kv_dtype != "float32" and not paged:
         raise ValueError("kv_dtype requires paged=True")
+    if any(cfg.windows) and not paged:
+        raise ValueError("attention windows need paged=True: the dense KV "
+                         "layout has no window")
+    for path, on in (("int8 weights", quantize is not None),
+                     ("int8 KV", kv_dtype != "float32"),
+                     ("speculative decoding", spec_k > 0),
+                     ("tensor parallelism",
+                      mesh is not None or tp is not None)):
+        if on:
+            refuse_beyond_dense(cfg, path)
     if tp is not None:
         if mesh is not None:
             raise ValueError("pass mesh or tp, not both")

@@ -96,10 +96,12 @@ def make_engine(variant, **overrides):
 
 
 # (Hq, Hk, D) of the paged-op parity cases: a small GQA default, then the
-# benchmark's two head sizes, both off the TPU's 128-lane tiling — phi3's
-# MHA heads of 96 and stablelm's GQA 4:1 heads of 160 — on flat
-# (N, P, Hk*D) pools.  The default's suffix is empty, so its ids stay.
-PAGED_HEADS = {"": (4, 2, 8), "-d96": (2, 2, 96), "-d160": (4, 1, 160)}
+# benchmark's three head sizes — phi3's MHA heads of 96 and stablelm's GQA
+# 4:1 heads of 160, both off the TPU's 128-lane tiling, and mellum2's GQA
+# 8:1 heads of 128, on it — on flat (N, P, Hk*D) pools.  The default's
+# suffix is empty, so its ids stay.
+PAGED_HEADS = {"": (4, 2, 8), "-d96": (2, 2, 96), "-d160": (4, 1, 160),
+               "-d128": (8, 1, 128)}
 
 
 def paged_head_cases(*leads):

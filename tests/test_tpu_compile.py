@@ -147,22 +147,27 @@ _ALIAS = re.compile(r"\{(\d+)\}: \((\d+), \{\}, \w+-alias\)")
 def _zero_params(cfg):
     """Weights of the graph's shapes; zeros the host never touches."""
     dm, hq, hk, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    e, f = cfg.n_experts, cfg.d_expert
     shapes = {"embed": (cfg.vocab, dm), "final_norm": (dm,),
               "head_w": (dm, cfg.vocab)}
     for i in range(cfg.n_layers):
         shapes.update({
             f"l{i}.norm1": (dm,), f"l{i}.wq": (dm, hq * dh),
             f"l{i}.wk": (dm, hk * dh), f"l{i}.wv": (dm, hk * dh),
-            f"l{i}.wo": (hq * dh, dm), f"l{i}.norm2": (dm,),
+            f"l{i}.wo": (hq * dh, dm), f"l{i}.norm2": (dm,)})
+        shapes.update({
+            f"l{i}.router": (dm, e), f"l{i}.we_gate": (e, dm, f),
+            f"l{i}.we_up": (e, dm, f), f"l{i}.we_down": (e, f, dm)}
+            if e else {
             f"l{i}.wg": (dm, cfg.d_ff), f"l{i}.wu": (dm, cfg.d_ff),
             f"l{i}.wd": (cfg.d_ff, dm)})
     return {k: np.zeros(s, np.float32) for k, s in shapes.items()}
 
 
-@pytest.mark.parametrize("cell", sorted(CELL_WIDTHS))
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
-    cfg = CELL_WIDTHS[cell]
+def _bound_step(cfg, step, one_chip):
+    """(graph, lowered step) of a paged step Program at the cells' slots,
+    chunk and pages, bound as the engine binds it: pools donated, in
+    sorted name order."""
     kw = dict(batch=SLOTS, n_blocks=N_POOL, page_size=PAGE,
               max_pages=MAX_PAGES)
     params = _zero_params(cfg)
@@ -170,7 +175,6 @@ def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
              else build_paged_prefill_graph(cfg, params, chunk=CHUNK, **kw))
     prog = compile_graph(graph)
     pools = sorted(n for n in graph.inputs if n.startswith("cache_"))
-    # bound as the engine binds it: pools donated, in sorted name order
     names = ("tokens", "start", "n_new", "block_tables", *pools)
     step_fn = prog.bind(*names, donate=pools)
     spec = {k: jax.ShapeDtypeStruct(s.shape, jnp.dtype(s.dtype),
@@ -179,8 +183,19 @@ def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
     pspec = {k: jax.ShapeDtypeStruct(np.shape(v), jnp.float32,
                                      sharding=one_chip)
              for k, v in params.items()}
-    hlo = step_fn.lower(pspec, *[spec[n] for n in names]).compile().as_text()
+    return graph, step_fn.lower(pspec, *[spec[n] for n in names])
 
+
+@pytest.mark.parametrize("cell", sorted(CELL_WIDTHS))
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
+    cfg = CELL_WIDTHS[cell]
+    graph, lowered = _bound_step(cfg, step, one_chip)
+    _check_pools(graph, lowered.compile().as_text(), cfg, cell, step)
+
+
+def _check_pools(graph, hlo, cfg, cell, step):
+    pools = sorted(n for n in graph.inputs if n.startswith("cache_"))
     pool = N_POOL * PAGE * cfg.n_kv_heads * cfg.d_head
     view = SLOTS * MAX_PAGES * PAGE * cfg.n_kv_heads * cfg.d_head
     typed = [(op, dt, int(np.prod([int(d) for d in dims.split(",") if d])))
@@ -205,3 +220,64 @@ def test_paged_step_touches_pools_only_in_place(one_chip, cell, step):
     assert sorted(alias) == sorted(graph.outputs.index(f"new_{n}")
                                    for n in pools)
     assert len(set(alias.values())) == len(pools)
+
+
+# --------------------------------------------------------------------------- #
+# The mixture-of-experts configuration at published widths (mellum2, four
+# layers: three sliding, one full).  Its steps keep the pools' guarantees
+# above.  Its routed experts run as grouped matmuls in prefill (three a
+# layer) and, at a decode step's 16 rows, as each expert's matmul batched
+# over the rows; either way there is no array the size of the experts'
+# weights gathered once per (row, expert) pair and no copy of a layer's
+# expert stack, and every matmul of the step, the experts' included,
+# states the configuration's HIGHEST precision.  The dense configurations'
+# steps state no precision they did not state before: only phi3's MHA
+# decode attention (two einsums a layer) runs at HIGHEST, as its op
+# chooses.
+# --------------------------------------------------------------------------- #
+
+MELLUM = GraphLMConfig(vocab=98304, d_model=2304, n_layers=4, n_heads=32,
+                       n_kv_heads=4, head_dim=128,
+                       windows=(1024, 1024, 1024, 0), n_experts=64, top_k=8,
+                       d_expert=896, matmul_precision="highest")
+_PRECISION = re.compile(r"precision(?:_config)? = \[([^\]]*)\]")
+
+
+def _precisions(lowered):
+    """The precision each matmul of a lowered step states, in order."""
+    return ["highest" if "HIGHEST" in p.upper() else "default"
+            for p in _PRECISION.findall(lowered.as_text())]
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_moe_step_groups_its_experts_at_highest(one_chip, step):
+    cfg = MELLUM
+    graph, lowered = _bound_step(cfg, step, one_chip)
+    text = lowered.as_text()
+    grouped = 3 * cfg.n_layers if step == "prefill" else 0
+    assert len(re.findall(r'"chlo.ragged_dot"\(', text)) == grouped
+    prec = _precisions(lowered)
+    assert prec and set(prec) == {"highest"}, prec
+    hlo = lowered.compile().as_text()
+    _check_pools(graph, hlo, cfg, "mellum2", step)
+    rows = SLOTS * (1 if step == "decode" else CHUNK)
+    per_pair = rows * cfg.top_k * cfg.d_model * cfg.d_expert
+    stack = cfg.n_experts * cfg.d_model * cfg.d_expert
+    typed = [(op, int(np.prod([int(d) for d in dims.split(",") if d])))
+             for _, dims, op in _HLO_OP.findall(hlo)]
+    assert max(n for _, n in typed) < per_pair
+    moved = [op for op, n in typed
+             if op in ("copy", "transpose", "convert") and n == stack]
+    assert not moved, f"{step}: expert stack {moved}"
+    assert "operand_precision={highest,highest}" in hlo
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_WIDTHS))
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_dense_steps_state_no_new_precision(one_chip, cell, step):
+    cfg = CELL_WIDTHS[cell]
+    _, lowered = _bound_step(cfg, step, one_chip)
+    prec = _precisions(lowered)
+    mha_decode = step == "decode" and cfg.n_heads == cfg.n_kv_heads
+    assert prec.count("highest") == (2 * cfg.n_layers if mha_decode else 0)
+    assert "ragged_dot" not in lowered.as_text()
