@@ -58,7 +58,7 @@ from repro.kernels.flash_attention import flash_chunk_attention
 from repro.kernels.ops import pallas_interpret
 
 __all__ = ["embedding", "cache_update", "chunk_attention", "greedy_token",
-           "moe_ffn",
+           "moe_ffn", "last_new_row",
            "verify_attention", "paged_verify_attention",
            "paged_verify_attention_q", "serving_mesh",
            "current_serving_mesh"]
@@ -1266,6 +1266,45 @@ def moe_ffn(x, n_new, router, w_gate, w_up, w_down, *, top_k: int,
     return get_impl("moe_ffn", backend)(
         [x, n_new, router, w_gate, w_up, w_down],
         {"top_k": top_k, "norm_topk": norm_topk, "precision": precision})
+
+
+# --------------------------------------------------------------------------- #
+# last_new_row — inputs (x (B,T,D), n_new (B,)).  A prefill chunk's next
+# token is read at each slot's last new row only, so the prefill graph
+# takes that row before the final norm and the head: they then run on B
+# rows, not B*T, and the Program returns (B, V) logits.
+# --------------------------------------------------------------------------- #
+
+def _last_new_row_shape(specs, attrs):
+    x, n_new = specs
+    if len(x.shape) != 3 or tuple(n_new.shape) != (x.shape[0],):
+        raise ValueError(f"last_new_row wants x (B, T, D) and n_new (B,), "
+                         f"got {x.shape} and {n_new.shape}")
+    return [TensorSpec((x.shape[0], 1, x.shape[2]), x.dtype)]
+
+
+def _last_new_row_cost(specs, attrs):
+    # reads one row per slot and writes it; n_new is read once
+    out = _last_new_row_shape(specs, attrs)[0]
+    return Cost(flops=0.0, bytes=2.0 * out.nbytes + specs[1].nbytes)
+
+
+defop("last_new_row", _last_new_row_shape, _last_new_row_cost,
+      doc="each slot's last new row of a chunk: x (B,T,D), n_new (B,) -> "
+          "(B,1,D), row max(n_new[b]-1, 0) of slot b")
+
+
+@impl("last_new_row", "ref",
+      note="one row gather per slot (exact); an idle slot (n_new == 0) "
+           "gives its row 0")
+def _last_new_row_ref(inputs, attrs):
+    x, n_new = inputs
+    idx = jnp.maximum(n_new.astype(jnp.int32) - 1, 0)
+    return [jnp.take_along_axis(x, idx[:, None, None], axis=1)]
+
+
+def last_new_row(x, n_new, *, backend: str = "ref"):
+    return get_impl("last_new_row", backend)([x, n_new], {})[0]
 
 
 # --------------------------------------------------------------------------- #

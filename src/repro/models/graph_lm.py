@@ -25,7 +25,8 @@ Two graph shapes per model:
 * decode:  tokens (B, 1)  — one token per slot, ``decode_attention`` hot op.
 * prefill: tokens (B, T)  — one chunk per slot, ``chunk_attention``;
   ``n_new[b] <= T`` marks the valid prefix (0 = slot idle this step), so a
-  fixed-shape Program serves ragged chunks and idle slots exactly.
+  fixed-shape Program serves ragged chunks and idle slots exactly.  Its
+  logits are (B, V), of each slot's last new row only.
 
 Value names are identical across batch/chunk variants of the same config,
 which lets one calibration (``repro.core.quant.calibrate``) drive the
@@ -374,18 +375,24 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
         nodes.append(Node(f"{L}.mlp_res", "add", [f"{L}.xa", f"{L}.down"],
                           [f"{L}.out"]))
         x = f"{L}.out"
+    if not (decode or verify):
+        # the engine reads a prefill chunk's next token at each slot's last
+        # new row alone: norm and head run on that row, as in decode
+        nodes.append(Node("last_row", "last_new_row", [x, "n_new"],
+                          ["x_last"]))
+        x = "x_last"
     nodes.append(Node("final_norm_n", "rmsnorm", [x, "final_norm"],
                       ["final_h"], dict(eps)))
-    if decode:
+    if verify:
+        nodes.append(Node("lm_head", "dense", ["final_h", "head_w"],
+                          ["logits"], dict(prec)))
+    else:
         nodes += [
             Node("lm_head", "dense", ["final_h", "head_w"], ["logits3"],
                  dict(prec)),
             Node("logits_flat", "reshape", ["logits3"], ["logits"],
                  {"shape": (batch, cfg.vocab)}),
         ]
-    else:
-        nodes.append(Node("lm_head", "dense", ["final_h", "head_w"],
-                          ["logits"], dict(prec)))
     outputs = ["logits"]
     if kv8 and verify:
         # no page writes happened; hand the fp32 K/V rows of this call's
@@ -425,9 +432,10 @@ def build_decode_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
 def build_prefill_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
                         batch: int, chunk: int, cache_cap: int) -> Graph:
     """One prefill chunk: tokens (B, T) at absolute positions
-    ``start .. start+n_new-1`` -> per-position logits (B, T, V) + updated
-    caches.  Positions >= ``n_new[b]`` are padding (outputs ignored; their
-    cache rows are overwritten by the next chunk or the first decode)."""
+    ``start .. start+n_new-1`` -> logits (B, V) of each slot's last new
+    row ``n_new[b] - 1`` (row 0 for an idle slot) + updated caches.
+    Positions >= ``n_new[b]`` are padding (their cache rows are
+    overwritten by the next chunk or the first decode)."""
     return _lm_graph(cfg, params, batch=batch, t=chunk, cache_cap=cache_cap,
                      decode=False)
 

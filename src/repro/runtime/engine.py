@@ -10,10 +10,11 @@ traffic, not just offline evaluation:
 
 * decode Program — tokens (B, 1) + caches → next-token logits, one call
   per engine decode tick over the whole fixed slot batch;
-* prefill Program — tokens (B, chunk) + caches → per-position logits; long
-  prompts are split into fixed-size chunks *interleaved with decode ticks*
-  so a newly admitted long prompt never stalls in-flight decodes for more
-  than ~one chunk (the bounded inter-token gap serve_bench measures).
+* prefill Program — tokens (B, chunk) + caches → each slot's logits at
+  its last new row, (B, V); long prompts are split into fixed-size
+  chunks *interleaved with decode ticks* so a newly admitted long prompt
+  never stalls in-flight decodes for more than ~one chunk (the bounded
+  inter-token gap serve_bench measures).
 
 Scheduling is deterministic and tick-based (wall-clock only feeds
 metrics): :class:`~repro.runtime.batching.SlotScheduler` supplies priority
@@ -98,6 +99,7 @@ from repro.runtime.kv_cache import BlockPool, kv_page_bytes
 __all__ = [
     "EngineRequest", "EngineMetrics", "Engine", "AsyncEngine",
     "ProgramStepper", "PagedProgramStepper", "UnbatchedReference",
+    "LastRowLogits",
     "build_lm_serving", "padded_len",
     "EngineCheckpoint", "CheckpointSlot", "TickFailure",
 ]
@@ -316,6 +318,29 @@ class _TPFirstPolicy(BackendPolicy):
         return self.base.choose(node, in_specs)
 
 
+class LastRowLogits:
+    """A prefill call's logits, addressed as a chunk's per-position
+    logits would be: ``out[s, n_new[s] - 1]`` is slot ``s``'s (V,) row,
+    the only position the prefill Program computes.  Any other position
+    raises IndexError, so a row that was never computed is refused
+    rather than returned wrong."""
+
+    def __init__(self, rows: np.ndarray, n_new: np.ndarray):
+        self.rows = rows                    # (B, V)
+        self.n_new = np.array(n_new)
+
+    def __getitem__(self, key) -> np.ndarray:
+        if not (isinstance(key, tuple) and len(key) == 2):
+            raise IndexError(f"index prefill logits as [slot, row], "
+                             f"not {key!r}")
+        slot, row = key
+        n = int(self.n_new[slot])
+        if n < 1 or int(row) != n - 1:
+            raise IndexError(f"slot {slot}: prefill logits hold its last "
+                             f"new row {n - 1} only, not row {row}")
+        return self.rows[slot]
+
+
 class ProgramStepper:
     """Owns the two compiled Programs plus the cache arrays they thread.
 
@@ -416,10 +441,11 @@ class ProgramStepper:
         """One step Program call, in three profiler spans: staging and
         launch (``stepper.stage``), the host waiting on the device
         (``stepper.wait``), and the logits copy to the host
-        (``stepper.fetch``).  A mixture-of-experts step also fetches its
-        per-expert row counts into ``moe_counts`` and marks the trace
-        with a ``moe.counts`` event: ``hit``, the (layer, expert) pairs
-        that got a row, and ``routed``, the (row, expert) assignments."""
+        (``stepper.fetch``, whose ``bytes`` stat is the copy's size).  A
+        mixture-of-experts step also fetches its per-expert row counts
+        into ``moe_counts`` and marks the trace with a ``moe.counts``
+        event: ``hit``, the (layer, expert) pairs that got a row, and
+        ``routed``, the (row, expert) assignments."""
         with jax.profiler.TraceAnnotation("stepper.stage"):
             extra = self._stage(tokens, start, n_new)
             cache_args = [self.caches[n] for n in sorted(self.caches)]
@@ -429,7 +455,8 @@ class ProgramStepper:
                           *[jnp.asarray(e) for e in extra], *cache_args)
         with jax.profiler.TraceAnnotation("stepper.wait"):
             outs[0].block_until_ready()
-        with jax.profiler.TraceAnnotation("stepper.fetch"):
+        with jax.profiler.TraceAnnotation("stepper.fetch",
+                                          bytes=int(outs[0].nbytes)):
             logits = np.asarray(outs[0])
         if self._counts_at is not None:
             counts = np.asarray(outs[self._counts_at])
@@ -558,9 +585,11 @@ class ProgramStepper:
         return out
 
     def prefill(self, tokens: np.ndarray, start: np.ndarray,
-                n_new: np.ndarray) -> np.ndarray:
-        """tokens (B, chunk) → logits (B, chunk, V); caches advance."""
-        return self._call(self._pre, tokens, start, n_new)
+                n_new: np.ndarray) -> LastRowLogits:
+        """tokens (B, chunk) → each slot's logits at its last new row,
+        read as ``out[s, n_new[s] - 1]``; caches advance."""
+        return LastRowLogits(self._call(self._pre, tokens, start, n_new),
+                             n_new)
 
     def decode(self, tokens: np.ndarray, start: np.ndarray,
                n_new: np.ndarray) -> np.ndarray:
@@ -1750,7 +1779,7 @@ class UnbatchedReference:
                           toks, np.asarray([pos], np.int32),
                           np.asarray([n], np.int32), "prefill")
             pos += n
-        row = logits[0, n - 1]
+        row = logits[0]
         out = [int(np.argmax(row))]
         if logits_out is not None:
             logits_out.append(row.copy())

@@ -53,7 +53,8 @@ SERVING_OPS = ("embedding", "cache_update", "chunk_attention",
                "paged_chunk_attention", "paged_decode_attention",
                "paged_verify_attention",
                "paged_cache_update_q", "paged_chunk_attention_q",
-               "paged_decode_attention_q", "paged_verify_attention_q")
+               "paged_decode_attention_q", "paged_verify_attention_q",
+               "last_new_row")
 
 
 def _first_line(text: str) -> str:

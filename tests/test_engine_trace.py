@@ -85,6 +85,19 @@ def test_the_step_span_carries_its_tick(traced):
     assert sorted(int(t) for t in seen) == ticks
 
 
+def test_the_fetch_span_carries_its_bytes(traced, engine):
+    """A prefill call fetches (B, V) logits, as a decode call does."""
+    log_dir, ticks = traced
+    path = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                         "*.xplane.pb")))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    seen = [dict(ev.stats).get("bytes") for plane in data.planes
+            for line in plane.lines for ev in line.events
+            if ev.name == "stepper.fetch"]
+    row_bytes = engine.n_slots * TINY.vocab * 4
+    assert [int(b) for b in seen] == [row_bytes] * len(ticks)
+
+
 def test_bound_programs_run_under_their_graph_names(traced, engine):
     log_dir, _ = traced
     path = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*",

@@ -247,35 +247,41 @@ def test_cache_update_q_rejects_bad_specs():
 # --------------------------------------------------------------------------- #
 
 def test_kv8_prefill_logits_bounded_and_top1_exact():
-    """One full-prompt prefill through the kv8 paged graph vs the fp32
-    dense graph: max |logit error| < 0.05 and the greedy top-1 token
-    agrees at EVERY position — the documented accuracy contract."""
+    """A prompt prefilled through the kv8 paged graph vs the fp32 dense
+    graph: max |logit error| < 0.05 and the greedy top-1 token agrees at
+    EVERY position — the documented accuracy contract.  A prefill call
+    returns its last new row's logits, so the chunk runs once for each
+    ``n_new`` in 1..t, from empty caches."""
     cfg = TINY
     params = init_lm_params(cfg, 0)
     t, page, n_blocks = 16, 8, 6
     rng = _rng()
     toks = rng.integers(0, cfg.vocab, size=(1, t)).astype(np.int32)
     start = np.zeros((1,), np.int32)
-    n_new = np.full((1,), t, np.int32)
     dense = compile(build_prefill_graph(cfg, params, batch=1, chunk=t,
                                         cache_cap=t))
-    want = np.asarray(dense(
-        tokens=toks, start=start, n_new=n_new,
-        **{f"cache_{kv}{i}": np.zeros((1, t, cfg.n_kv_heads, cfg.d_head),
-                                      np.float32)
-           for kv in "kv" for i in range(cfg.n_layers)})[0])
-    g8 = build_paged_prefill_graph(cfg, params, batch=1, chunk=t,
-                                   n_blocks=n_blocks, page_size=page,
-                                   max_pages=t // page, kv_dtype="int8")
-    feeds = {"tokens": toks, "start": start, "n_new": n_new,
-             "block_tables": np.asarray([[3, 1]], np.int32)}
-    for kv in "kv":
-        for i in range(cfg.n_layers):
-            feeds[f"cache_{kv}{i}"] = np.zeros(
-                (n_blocks, page, cfg.n_kv_heads * cfg.d_head), np.int8)
-            feeds[f"cache_{kv}{i}_scale"] = np.zeros(
-                (n_blocks, cfg.n_kv_heads), np.float32)
-    got = np.asarray(compile(g8)(**feeds)[0])
+    kv8 = compile(build_paged_prefill_graph(
+        cfg, params, batch=1, chunk=t, n_blocks=n_blocks, page_size=page,
+        max_pages=t // page, kv_dtype="int8"))
+    want, got = [], []
+    for n in range(1, t + 1):
+        n_new = np.full((1,), n, np.int32)
+        want.append(np.asarray(dense(
+            tokens=toks, start=start, n_new=n_new,
+            **{f"cache_{kv}{i}": np.zeros(
+                (1, t, cfg.n_kv_heads, cfg.d_head), np.float32)
+               for kv in "kv" for i in range(cfg.n_layers)})[0])[0])
+        feeds = {"tokens": toks, "start": start, "n_new": n_new,
+                 "block_tables": np.asarray([[3, 1]], np.int32)}
+        for kv in "kv":
+            for i in range(cfg.n_layers):
+                feeds[f"cache_{kv}{i}"] = np.zeros(
+                    (n_blocks, page, cfg.n_kv_heads * cfg.d_head), np.int8)
+                feeds[f"cache_{kv}{i}_scale"] = np.zeros(
+                    (n_blocks, cfg.n_kv_heads), np.float32)
+        got.append(np.asarray(kv8(**feeds)[0])[0])
+    want, got = np.stack(want), np.stack(got)
+    assert want.shape == got.shape == (t, cfg.vocab)
     assert np.abs(got - want).max() < 0.05, np.abs(got - want).max()
     np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
 

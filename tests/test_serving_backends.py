@@ -176,7 +176,8 @@ def test_decode_split_supports_guard():
 def _serving_ops_in(graph):
     return {n.op for n in graph.nodes} & {"embedding", "cache_update",
                                           "chunk_attention",
-                                          "decode_attention"}
+                                          "decode_attention",
+                                          "last_new_row"}
 
 
 def test_graph_lm_compiles_under_cost_model_policy():
@@ -184,7 +185,8 @@ def test_graph_lm_compiles_under_cost_model_policy():
     g = build_prefill_graph(CFG, params, batch=2, chunk=4, cache_cap=16)
     prog = compile(g, policy=CostModelPolicy())
     assert _serving_ops_in(prog.graph) == {"embedding", "cache_update",
-                                           "chunk_attention"}
+                                           "chunk_attention",
+                                           "last_new_row"}
     for name, backend in prog.assignment.items():
         assert backend  # every node resolved
     rng = _rng()

@@ -281,3 +281,25 @@ def test_dense_steps_state_no_new_precision(one_chip, cell, step):
     mha_decode = step == "decode" and cfg.n_heads == cfg.n_kv_heads
     assert prec.count("highest") == (2 * cfg.n_layers if mha_decode else 0)
     assert "ragged_dot" not in lowered.as_text()
+
+
+# --------------------------------------------------------------------------- #
+# The prefill step's head at the cells' widths (phi3, stablelm, mellum2):
+# norm and head run on each slot's last new row only, so the step returns
+# (SLOTS, V) logits and holds no float array of SLOTS x CHUNK x V elements
+# (the per-position logits once copied to the host on every prefill call).
+# The one-hot embedding's token-id compare is that size in s32 and pred.
+# --------------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("cell", sorted(CELL_WIDTHS) + ["mellum2"])
+def test_prefill_heads_last_rows_only(one_chip, cell):
+    cfg = CELL_WIDTHS.get(cell, MELLUM)
+    graph, lowered = _bound_step(cfg, "prefill", one_chip)
+    logits = lowered.out_info[graph.outputs.index("logits")]
+    assert tuple(logits.shape) == (SLOTS, cfg.vocab)
+    hlo = lowered.compile().as_text()
+    full = SLOTS * CHUNK * cfg.vocab
+    shapes = re.findall(r"\b(f32|bf16|f16)\[([\d,]+)\]", hlo)
+    wide = {f"{dt}[{dims}]" for dt, dims in shapes
+            if np.prod([int(d) for d in dims.split(",")]) == full}
+    assert not wide, f"{cell}: per-position logits {wide}"
