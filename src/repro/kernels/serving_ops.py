@@ -58,9 +58,7 @@ from repro.kernels.flash_attention import flash_chunk_attention
 from repro.kernels.ops import pallas_interpret
 
 __all__ = ["embedding", "cache_update", "chunk_attention", "greedy_token",
-           "moe_ffn", "last_new_row",
-           "verify_attention", "paged_verify_attention",
-           "paged_verify_attention_q", "serving_mesh",
+           "moe_ffn", "last_new_row", "serving_mesh",
            "current_serving_mesh"]
 
 Attrs = Dict[str, Any]
@@ -1308,18 +1306,9 @@ def last_new_row(x, n_new, *, backend: str = "ref"):
 
 
 # --------------------------------------------------------------------------- #
-# Speculative-decoding ops.
-#
-# ``verify_attention`` scores K+1 tokens (the committed next token plus K
-# draft proposals) against the cache in ONE call — shape-identical to
-# ``chunk_attention`` (a verify step IS a prefill chunk of T = K+1 rows at
-# per-sequence offsets), but registered as a distinct op so the selector /
-# autotuner can pick a backend for the verify shape independently of the
-# prefill chunk shape, and so the generated op-reference tables document
-# the speculative path.  The backends delegate to the chunk-attention
-# implementations (same offset-causal math, bit-for-bit); the ``supports``
-# guards mirror chunk_attention's (ragged K is handled above the op by
-# ``n_new`` masking, exactly like ragged prefill chunks).
+# Speculative-decoding op.  (The verify step needs none of its own: it is
+# a prefill chunk of T = K+1 rows through chunk_attention /
+# paged_chunk_attention, whose backends the policy picks at that shape.)
 #
 # ``greedy_token`` is the in-graph argmax that lets the DRAFT Program feed
 # its own greedy output back as the next step's input token — the K-step
@@ -1354,185 +1343,6 @@ def _greedy_token_ref(inputs, attrs):
 
 def greedy_token(logits, *, backend: str = "ref", **kw):
     return get_impl("greedy_token", backend)([logits], kw)[0]
-
-
-# ---- verify_attention (dense) --------------------------------------------- #
-# inputs (q (B,T,Hq,D), k (B,S,Hk,D), v (B,S,Hk,D), start (B,)); T = K+1
-
-defop("verify_attention", _chunk_attn_shape, _chunk_attn_cost,
-      doc="speculative-verify attention: score K+1 tokens (committed next "
-          "token + K draft proposals) against the dense cache in one call; "
-          "offset-causal exactly like chunk_attention (row t attends "
-          "positions <= start+t); inputs (q (B,T,Hq,D), k (B,S,Hk,D), v, "
-          "start (B,)); attrs: scale")
-
-
-@impl("verify_attention", "ref", cost_fn=_chunk_attn_ref_cost,
-      note="dense offset-causal masked attention in fp32 (delegates to the "
-           "chunk_attention oracle — a verify step is a T=K+1 chunk)")
-def _verify_attention_ref(inputs, attrs):
-    return _chunk_attention_ref(inputs, attrs)
-
-
-@impl("verify_attention", "xla",
-      note="GQA grouped inside the einsum (chunk_attention's fused "
-           "lowering at the verify shape)")
-def _verify_attention_xla(inputs, attrs):
-    return _chunk_attention_xla(inputs, attrs)
-
-
-@impl("verify_attention", "pallas", supports=_chunk_attn_pallas_supports,
-      note="flash online-softmax kernel at the T=K+1 verify shape "
-           "(block_q clamps to T, so any K passes the divisibility guard)")
-def _verify_attention_pallas(inputs, attrs):
-    return _chunk_attention_pallas(inputs, attrs)
-
-
-def verify_attention(q, k, v, start, *, scale=None, backend: str = "ref",
-                     **kw):
-    return get_impl("verify_attention", backend)(
-        [q, k, v, start], {"scale": scale, **kw})[0]
-
-
-# ---- paged_verify_attention ----------------------------------------------- #
-# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP), start)
-
-defop("paged_verify_attention", _paged_chunk_shape, _paged_chunk_cost,
-      doc="speculative-verify attention reading K/V through block tables "
-          "(paged_chunk_attention semantics at T = K+1); inputs "
-          "(q (B,T,Hq,D), pages_k (N,P,Hk*D), pages_v, tables (B,MP) "
-          "int32, start (B,)); attrs: scale")
-
-
-@impl("paged_verify_attention", "ref", cost_fn=_paged_chunk_ref_cost,
-      note="gather pages to a dense view, then the dense fp32 offset-"
-           "causal oracle")
-def _paged_verify_attention_ref(inputs, attrs):
-    return _paged_chunk_attention_ref(inputs, attrs)
-
-
-@impl("paged_verify_attention", "xla", cost_fn=_paged_chunk_xla_cost,
-      note="gather pages to a dense view + the GQA-grouped einsum")
-def _paged_verify_attention_xla(inputs, attrs):
-    return _paged_chunk_attention_xla(inputs, attrs)
-
-
-@impl("paged_verify_attention", "pallas",
-      supports=_paged_chunk_pallas_supports,
-      note="flash kernel reading pages in place via the scalar-prefetched "
-           "block table (flash_paged_chunk_attention at the verify shape)")
-def _paged_verify_attention_pallas(inputs, attrs):
-    return _paged_chunk_attention_pallas(inputs, attrs)
-
-
-def paged_verify_attention(q, pages_k, pages_v, tables, start, *, scale=None,
-                           backend: str = "ref", **kw):
-    return get_impl("paged_verify_attention", backend)(
-        [q, pages_k, pages_v, tables, start], {"scale": scale, **kw})[0]
-
-
-# ---- paged_verify_attention_q --------------------------------------------- #
-# inputs (q (B,T,Hq,D), pages_k (N,P,Hk*D) i8, k_scales (N,Hk), pages_v i8,
-#         v_scales, tables (B,MP), start, k_new (B,T,Hk,D) f32, v_new)
-#
-# TWO-SOURCE on purpose: the committed prefix streams from the int8 pages,
-# but this call's own K+1 speculative rows come in as fp32 ``k_new/v_new``
-# and are NEVER written to the pages here.  Quantize-on-write page scales
-# only ever GROW, and a scale raise requantizes the whole page — so writing
-# draft rows that later get REJECTED would permanently (and lossily) perturb
-# committed rows sharing their page, breaking token-exactness vs the
-# reference.  Accepted rows are committed afterwards by a separate
-# ``paged_cache_update_q`` Program call with ``n_new`` = accepted count.
-
-def _paged_verify_q_shape(specs, attrs):
-    q, pk, ks, kn, vn = specs[0], specs[1], specs[2], specs[7], specs[8]
-    _check_q_pool(q, pk, ks)
-    want = (q.shape[0], q.shape[1], ks.shape[1], q.shape[3])
-    for name, spec in (("k_new", kn), ("v_new", vn)):
-        if spec.shape != want:
-            raise ValueError(f"{name} {spec.shape} != (B, T, Hk, D) {want}")
-    return [specs[0]]
-
-
-def _paged_verify_q_cost(specs, attrs):
-    base = _paged_chunk_q_cost(specs[:7], attrs)
-    return Cost(flops=base.flops, bytes=base.bytes + _bytes(specs[7:]))
-
-
-def _paged_verify_q_gather_cost(specs, attrs):
-    base = _paged_chunk_q_gather_cost(specs[:7], attrs)
-    return Cost(flops=base.flops, bytes=base.bytes + _bytes(specs[7:]))
-
-
-defop("paged_verify_attention_q", _paged_verify_q_shape,
-      _paged_verify_q_cost,
-      doc="speculative-verify attention over int8 pages: the committed "
-          "prefix dequantizes from the pages, this call's K+1 rows read "
-          "from fp32 k_new/v_new (two-source — speculative rows are never "
-          "quantized into pages before acceptance); inputs (q (B,T,Hq,D), "
-          "pages_k int8, k_scales (N,Hk), pages_v int8, v_scales, tables "
-          "(B,MP) int32, start (B,), k_new (B,T,Hk,D), v_new); attrs: scale")
-
-
-def _patch_new_rows(dense, new, start):
-    """Overlay this call's fp32 rows onto the dequantized gather at rows
-    ``start + 0..T-1`` (per batch); rows past the dense view drop."""
-    b, t = new.shape[0], new.shape[1]
-    pos = jnp.asarray(start)[:, None] + jnp.arange(t)[None, :]
-    bi = jnp.arange(b)[:, None]
-    return jnp.asarray(dense).at[bi, pos].set(jnp.asarray(new), mode="drop")
-
-
-def _paged_verify_q_sources(inputs):
-    q, pk, ks, pv, vs, tables, start, kn, vn = inputs
-    d = q.shape[-1]
-    k = _patch_new_rows(_split_heads(_gather_pages_q(pk, ks, tables), d),
-                        kn, start)
-    v = _patch_new_rows(_split_heads(_gather_pages_q(pv, vs, tables), d),
-                        vn, start)
-    return q, k, v, start
-
-
-@impl("paged_verify_attention_q", "ref", cost_fn=_paged_verify_q_gather_cost,
-      note="dequantize after the gather, patch in the fp32 speculative "
-           "rows, then the dense fp32 offset-causal oracle")
-def _paged_verify_attention_q_ref(inputs, attrs):
-    q, k, v, start = _paged_verify_q_sources(inputs)
-    return _chunk_attention_ref([q, k, v, start], attrs)
-
-
-@impl("paged_verify_attention_q", "xla", cost_fn=_paged_verify_q_gather_cost,
-      note="dequantize after the gather, patch in the fp32 speculative "
-           "rows + the GQA-grouped einsum")
-def _paged_verify_attention_q_xla(inputs, attrs):
-    q, k, v, start = _paged_verify_q_sources(inputs)
-    return _chunk_attention_xla([q, k, v, start], attrs)
-
-
-def _paged_verify_q_pallas_supports(specs, attrs):
-    """The dense flash kernel runs on the patched gather: T % block_q == 0
-    (block clamped to T) and whole GQA groups."""
-    q, pk = specs[0], specs[1]
-    bq = min(int(attrs.get("block_q", 256)), q.shape[1])
-    return q.shape[1] % bq == 0 and _whole_groups(q, pk)
-
-
-@impl("paged_verify_attention_q", "pallas",
-      supports=_paged_verify_q_pallas_supports,
-      note="XLA gather/dequant/patch feeding the dense flash online-"
-           "softmax kernel at the verify shape (the two-source patch "
-           "cannot stream pages in place)")
-def _paged_verify_attention_q_pallas(inputs, attrs):
-    q, k, v, start = _paged_verify_q_sources(inputs)
-    return _chunk_attention_pallas([q, k, v, start], attrs)
-
-
-def paged_verify_attention_q(q, pages_k, k_scales, pages_v, v_scales, tables,
-                             start, k_new, v_new, *, scale=None,
-                             backend: str = "ref", **kw):
-    return get_impl("paged_verify_attention_q", backend)(
-        [q, pages_k, k_scales, pages_v, v_scales, tables, start,
-         k_new, v_new], {"scale": scale, **kw})[0]
 
 
 # --------------------------------------------------------------------------- #
@@ -1616,16 +1426,6 @@ def _decode_attention_tp(inputs, attrs):
                      (_HS3, _HS4, _HS4, _REP), _HS3)]
 
 
-@impl("verify_attention", "tp", supports=_tp_attn_supports,
-      cost_fn=_tp_cost_fn("verify_attention"), note=_TP_NOTE)
-def _verify_attention_tp(inputs, attrs):
-    q, k, v, start = inputs
-    def local(q_, k_, v_, s_):
-        return _verify_attention_xla([q_, k_, v_, s_], attrs)[0]
-    return [_tp_call(local, (q, k, v, start),
-                     (_HS4, _HS4, _HS4, _REP), _HS4)]
-
-
 @impl("paged_chunk_attention", "tp", supports=_tp_attn_supports,
       cost_fn=_tp_cost_fn("paged_chunk_attention"), note=_TP_NOTE)
 def _paged_chunk_attention_tp(inputs, attrs):
@@ -1644,16 +1444,6 @@ def _paged_decode_attention_tp(inputs, attrs):
         return _paged_decode_attention_xla([q_, pk_, pv_, t_, l_], attrs)[0]
     return [_tp_call(local, (q, pk, pv, tables, lengths),
                      (_HS3, _POOL, _POOL, _REP, _REP), _HS3)]
-
-
-@impl("paged_verify_attention", "tp", supports=_tp_attn_supports,
-      cost_fn=_tp_cost_fn("paged_verify_attention"), note=_TP_NOTE)
-def _paged_verify_attention_tp(inputs, attrs):
-    q, pk, pv, tables, start = inputs
-    def local(q_, pk_, pv_, t_, s_):
-        return _paged_verify_attention_xla([q_, pk_, pv_, t_, s_], attrs)[0]
-    return [_tp_call(local, (q, pk, pv, tables, start),
-                     (_HS4, _POOL, _POOL, _REP, _REP), _HS4)]
 
 
 @impl("paged_chunk_attention_q", "tp", supports=_tp_attn_supports,
@@ -1678,21 +1468,8 @@ def _paged_decode_attention_q_tp(inputs, attrs):
                      (_HS3, _POOL, _SS2, _POOL, _SS2, _REP, _REP), _HS3)]
 
 
-@impl("paged_verify_attention_q", "tp", supports=_tp_attn_supports,
-      cost_fn=_tp_cost_fn("paged_verify_attention_q"), note=_TP_NOTE)
-def _paged_verify_attention_q_tp(inputs, attrs):
-    q, pk, ks, pv, vs, tables, start, kn, vn = inputs
-    def local(q_, pk_, ks_, pv_, vs_, t_, s_, kn_, vn_):
-        return _paged_verify_attention_q_xla(
-            [q_, pk_, ks_, pv_, vs_, t_, s_, kn_, vn_], attrs)[0]
-    return [_tp_call(local, (q, pk, ks, pv, vs, tables, start, kn, vn),
-                     (_HS4, _POOL, _SS2, _POOL, _SS2, _REP, _REP, _HS4, _HS4),
-                     _HS4)]
-
-
 # the ops whose ``tp`` backend the engine prefers when given a mesh
 TP_ATTENTION_OPS = (
-    "chunk_attention", "decode_attention", "verify_attention",
-    "paged_chunk_attention", "paged_decode_attention",
-    "paged_verify_attention", "paged_chunk_attention_q",
-    "paged_decode_attention_q", "paged_verify_attention_q")
+    "chunk_attention", "decode_attention", "paged_chunk_attention",
+    "paged_decode_attention", "paged_chunk_attention_q",
+    "paged_decode_attention_q")

@@ -38,7 +38,7 @@ against each other.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -151,15 +151,31 @@ def refuse_beyond_dense(cfg: GraphLMConfig, path: str) -> None:
                          f"config has {' and '.join(cfg.beyond_dense)}")
 
 
+def _cache_specs(cfg: GraphLMConfig, shape: Tuple[int, ...],
+                 kv_dtype: str = "float32") -> Dict[str, TensorSpec]:
+    """Cache input specs in graph order: each layer's K and V caches of
+    ``shape`` and, for int8 pools, their (n_blocks, Hk) scale sidecars."""
+    if kv_dtype not in ("float32", "int8"):
+        raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
+    out: Dict[str, TensorSpec] = {}
+    for i in range(cfg.n_layers):
+        out[f"cache_k{i}"] = out[f"cache_v{i}"] = TensorSpec(shape, kv_dtype)
+        if kv_dtype == "int8":
+            sspec = TensorSpec((shape[0], cfg.n_kv_heads), "float32")
+            out[f"cache_k{i}_scale"] = out[f"cache_v{i}_scale"] = sspec
+    return out
+
+
+def _paged_shape(cfg: GraphLMConfig, n_blocks: int,
+                 page_size: int) -> Tuple[int, int, int]:
+    return (n_blocks, page_size, cfg.n_kv_heads * cfg.d_head)
+
+
 def init_cache_inputs(cfg: GraphLMConfig, batch: int,
                       cache_cap: int) -> Dict[str, np.ndarray]:
     """Zeroed cache arrays matching the graph's cache input names."""
-    shape = (batch, cache_cap, cfg.n_kv_heads, cfg.d_head)
-    out: Dict[str, np.ndarray] = {}
-    for i in range(cfg.n_layers):
-        out[f"cache_k{i}"] = np.zeros(shape, np.float32)
-        out[f"cache_v{i}"] = np.zeros(shape, np.float32)
-    return out
+    specs = _cache_specs(cfg, (batch, cache_cap, cfg.n_kv_heads, cfg.d_head))
+    return {n: np.zeros(s.shape, s.dtype) for n, s in specs.items()}
 
 
 def init_paged_cache_inputs(cfg: GraphLMConfig, n_blocks: int,
@@ -174,19 +190,212 @@ def init_paged_cache_inputs(cfg: GraphLMConfig, n_blocks: int,
     :mod:`repro.kernels.serving_ops`).  With ``kv_dtype="int8"`` the
     pools are int8 and each gains a ``cache_{k,v}{i}_scale`` sidecar
     ((n_blocks, Hk) float32, all zeros = every page empty)."""
-    if kv_dtype not in ("float32", "int8"):
-        raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
-    shape = (n_blocks, page_size, cfg.n_kv_heads * cfg.d_head)
-    dt = np.int8 if kv_dtype == "int8" else np.float32
-    out: Dict[str, np.ndarray] = {}
+    specs = _cache_specs(cfg, _paged_shape(cfg, n_blocks, page_size),
+                         kv_dtype)
+    return {n: np.zeros(s.shape, s.dtype) for n, s in specs.items()}
+
+
+# --------------------------------------------------------------------------- #
+# One decoder layer, for every serving graph.  A pass of the decoder stack
+# (the one pass of a step graph, or one stage of an unrolled graph) names
+# the values it shares across layers in a _Pass; a _KV flavour names the
+# ops that write this pass's K/V rows into the cache and attend over it.
+# --------------------------------------------------------------------------- #
+
+@dataclass(frozen=True)
+class _KV:
+    """One KV-cache layout: its write op, its attention ops for a
+    one-row decode pass and a T-row chunk, whether its pools are read
+    through ``block_tables``, whether each pool has an int8 scale sidecar
+    ``{pool}_scale``, and whether the attention node carries the layer's
+    precision and window attrs (windows need the paged fp32 layout; the
+    dense and int8 attention nodes run at their op's default precision)."""
+
+    write: str
+    decode: str
+    chunk: str
+    paged: bool
+    scaled: bool
+    attn_attrs: bool
+
+
+_DENSE = _KV("cache_update", "decode_attention", "chunk_attention",
+             paged=False, scaled=False, attn_attrs=False)
+_PAGED = _KV("paged_cache_update", "paged_decode_attention",
+             "paged_chunk_attention", paged=True, scaled=False,
+             attn_attrs=True)
+_PAGED_Q = _KV("paged_cache_update_q", "paged_decode_attention_q",
+               "paged_chunk_attention_q", paged=True, scaled=True,
+               attn_attrs=False)
+
+
+@dataclass(frozen=True)
+class _Pass:
+    """The names one pass of the decoder stack shares across its layers:
+    the value-name suffix, the ``start`` and ``n_new`` inputs, ``kvlen``
+    (a one-row decode pass; None: a chunk, which attends from ``start``)
+    and ``cache(pool)`` -> (input, output) name of a cache buffer."""
+
+    sfx: str
+    start: str
+    n_new: str
+    kvlen: Optional[str]
+    cache: Callable[[str], Tuple[str, str]]
+
+
+def _step_pass(decode: bool) -> _Pass:
+    """The one pass of a step graph: cache ``c`` in, ``new_c`` out."""
+    return _Pass("", "start", "n_new", "kvlen" if decode else None,
+                 lambda c: (c, f"new_{c}"))
+
+
+def _stage_pass(nodes: List[Node], j: int, n: int, n_new: str,
+                final: Callable[[str], str] = lambda c: f"new_{c}") -> _Pass:
+    """Stage ``j`` of ``n`` of an unrolled graph: its values carry
+    ``.s{j}``; it starts one row after stage ``j - 1`` (the ``step_pos``
+    node, appended here, adds the ``spec.one`` ones-vector param); it reads
+    each cache buffer as the stage before left it and hands it on, the
+    last stage's output named ``final(c)``."""
+    sfx = f".s{j}"
+    start = "start"
+    if j:
+        start = f"start{sfx}"
+        nodes.append(Node(f"step_pos{sfx}", "add",
+                          ["start" if j == 1 else f"start.s{j - 1}",
+                           "spec.one"], [start]))
+
+    def cache(c: str) -> Tuple[str, str]:
+        return (c if j == 0 else f"{c}{sfx}",
+                final(c) if j == n - 1 else f"{c}.s{j + 1}")
+    return _Pass(sfx, start, n_new, f"kvlen{sfx}", cache)
+
+
+def _buffers(kv: _KV, pool: str) -> List[str]:
+    return [pool, f"{pool}_scale"] if kv.scaled else [pool]
+
+
+def _kv_write(kv: _KV, name: str, pool: str, rows: str, ps: _Pass) -> Node:
+    """Node ``name`` writing ``rows`` into cache ``pool`` (``cache_k0``,
+    ...) at rows ``start .. start+n_new-1`` of each slot."""
+    bufs = [ps.cache(b) for b in _buffers(kv, pool)]
+    return Node(name, kv.write,
+                [b[0] for b in bufs] + [rows]
+                + (["block_tables"] if kv.paged else [])
+                + [ps.start, ps.n_new],
+                [b[1] for b in bufs])
+
+
+def _kv_attend(kv: _KV, name: str, q: str, layer: int, ps: _Pass, out: str,
+               attrs: Dict[str, Any]) -> Node:
+    """Node ``name`` attending ``q`` over layer ``layer``'s written cache."""
+    cache = [ps.cache(b)[1] for pool in (f"cache_k{layer}", f"cache_v{layer}")
+             for b in _buffers(kv, pool)]
+    return Node(name, kv.decode if ps.kvlen else kv.chunk,
+                [q] + cache + (["block_tables"] if kv.paged else [])
+                + [ps.kvlen or ps.start], [out],
+                dict(attrs) if kv.attn_attrs else {})
+
+
+def _precision(cfg: GraphLMConfig) -> Dict[str, Any]:
+    """The attrs every node that multiplies matrices carries."""
+    return ({"precision": cfg.matmul_precision} if cfg.matmul_precision
+            else {})
+
+
+def _layer(nodes: List[Node], cfg: GraphLMConfig, i: int, x: str, kv: _KV,
+           ps: _Pass, *, batch: int, t: int) -> str:
+    """Append decoder layer ``i`` over input ``x`` ((B, t, D) rows of pass
+    ``ps``) and return the name of its output."""
+    L, dh, hq, hk = f"l{i}", cfg.d_head, cfg.n_heads, cfg.n_kv_heads
+    prec, eps = _precision(cfg), {"eps": cfg.eps}
+
+    def v(name: str) -> str:
+        return f"{L}.{name}{ps.sfx}"
+
+    decode = ps.kvlen is not None
+    q = v("qd" if decode else "q4")
+    att = dict(prec, window=cfg.window(i)) if cfg.window(i) else prec
+    nodes += [
+        Node(v("attn_norm"), "rmsnorm", [x, f"{L}.norm1"], [v("h1")],
+             dict(eps)),
+        Node(v("q_proj"), "dense", [v("h1"), f"{L}.wq"], [v("q")],
+             dict(prec)),
+        Node(v("k_proj"), "dense", [v("h1"), f"{L}.wk"], [v("k")],
+             dict(prec)),
+        Node(v("v_proj"), "dense", [v("h1"), f"{L}.wv"], [v("v")],
+             dict(prec)),
+        Node(v("k_heads"), "reshape", [v("k")], [v("k4")],
+             {"shape": (batch, t, hk, dh)}),
+        Node(v("v_heads"), "reshape", [v("v")], [v("v4")],
+             {"shape": (batch, t, hk, dh)}),
+        _kv_write(kv, v("k_write"), f"cache_k{i}", v("k4"), ps),
+        _kv_write(kv, v("v_write"), f"cache_v{i}", v("v4"), ps),
+        Node(v("q_heads"), "reshape", [v("q")], [q],
+             {"shape": (batch, hq, dh) if decode else (batch, t, hq, dh)}),
+        _kv_attend(kv, v("attn"), q, i, ps, v("att"), att),
+        Node(v("attn_flat"), "reshape", [v("att")], [v("attn2")],
+             {"shape": (batch, t, hq * dh)}),
+        Node(v("o_proj"), "dense", [v("attn2"), f"{L}.wo"], [v("proj")],
+             dict(prec)),
+        Node(v("attn_res"), "add", [x, v("proj")], [v("xa")]),
+        Node(v("mlp_norm"), "rmsnorm", [v("xa"), f"{L}.norm2"], [v("h2")],
+             dict(eps)),
+    ]
+    if cfg.n_experts:
+        # rows past n_new (chunk padding, idle slots) are routed to no
+        # expert, so the per-layer counts hold live rows only
+        nodes.append(Node(
+            v("experts"), "moe_ffn",
+            [v("h2"), ps.n_new, f"{L}.router", f"{L}.we_gate",
+             f"{L}.we_up", f"{L}.we_down"],
+            [v("down"), v("moe_counts")],
+            dict(prec, top_k=cfg.top_k, norm_topk=cfg.norm_topk)))
+    else:
+        nodes += [
+            Node(v("gate_proj"), "dense", [v("h2"), f"{L}.wg"], [v("gate")],
+                 dict(prec)),
+            Node(v("up_proj"), "dense", [v("h2"), f"{L}.wu"], [v("up")],
+                 dict(prec)),
+            Node(v("swiglu"), "swiglu", [v("gate"), v("up")], [v("act")]),
+            Node(v("down_proj"), "dense", [v("act"), f"{L}.wd"], [v("down")],
+                 dict(prec)),
+        ]
+    nodes.append(Node(v("mlp_res"), "add", [v("xa"), v("down")], [v("out")]))
+    return v("out")
+
+
+def _stack(nodes: List[Node], cfg: GraphLMConfig, kv: _KV, ps: _Pass,
+           tokens: str, *, batch: int, t: int) -> str:
+    """Append the embedding, a decode pass's key count and every decoder
+    layer over ``tokens`` ((B, t) ids); return the last layer's output."""
+    nodes.append(Node(f"embed_lookup{ps.sfx}", "embedding",
+                      [tokens, "embed"], [f"x0{ps.sfx}"], _precision(cfg)))
+    if ps.kvlen:
+        nodes.append(Node(f"kv_len{ps.sfx}", "add", [ps.start, ps.n_new],
+                          [ps.kvlen]))
+    x = f"x0{ps.sfx}"
     for i in range(cfg.n_layers):
-        out[f"cache_k{i}"] = np.zeros(shape, dt)
-        out[f"cache_v{i}"] = np.zeros(shape, dt)
-        if kv_dtype == "int8":
-            sshape = (n_blocks, cfg.n_kv_heads)
-            out[f"cache_k{i}_scale"] = np.zeros(sshape, np.float32)
-            out[f"cache_v{i}_scale"] = np.zeros(sshape, np.float32)
-    return out
+        x = _layer(nodes, cfg, i, x, kv, ps, batch=batch, t=t)
+    return x
+
+
+def _head(nodes: List[Node], cfg: GraphLMConfig, x: str, *, batch: int,
+          sfx: str = "", per_row: bool = False) -> None:
+    """Append the final norm and the LM head over ``x``: ``logits{sfx}``
+    is (B, V) from one row per slot, or with ``per_row`` (B, T, V)."""
+    nodes.append(Node(f"final_norm_n{sfx}", "rmsnorm", [x, "final_norm"],
+                      [f"final_h{sfx}"], {"eps": cfg.eps}))
+    if per_row:
+        nodes.append(Node(f"lm_head{sfx}", "dense",
+                          [f"final_h{sfx}", "head_w"], [f"logits{sfx}"],
+                          _precision(cfg)))
+        return
+    nodes += [
+        Node(f"lm_head{sfx}", "dense", [f"final_h{sfx}", "head_w"],
+             [f"logits3{sfx}"], _precision(cfg)),
+        Node(f"logits_flat{sfx}", "reshape", [f"logits3{sfx}"],
+             [f"logits{sfx}"], {"shape": (batch, cfg.vocab)}),
+    ]
 
 
 def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
@@ -195,8 +404,6 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
               kv_dtype: str = "float32") -> Graph:
     if t > cache_cap:
         raise ValueError(f"chunk {t} exceeds cache capacity {cache_cap}")
-    if kv_dtype not in ("float32", "int8"):
-        raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
     kv8 = kv_dtype == "int8"
     if kv8 and paged is None:
         raise ValueError("kv_dtype='int8' requires the paged cache layout")
@@ -204,208 +411,34 @@ def _lm_graph(cfg: GraphLMConfig, params: Dict[str, Any], *, batch: int,
         raise ValueError("attention windows need the paged cache layout")
     if kv8 or verify:
         refuse_beyond_dense(cfg, "int8 KV" if kv8 else "the verify step")
-    # matmul precision, carried by every node that multiplies matrices
-    prec = ({"precision": cfg.matmul_precision} if cfg.matmul_precision
-            else {})
-    dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "tokens": TensorSpec((batch, t), "int32"),
         "start": TensorSpec((batch,), "int32"),
         "n_new": TensorSpec((batch,), "int32"),
     }
     if paged is None:
-        for i in range(cfg.n_layers):
-            spec = TensorSpec((batch, cache_cap, hk, dh), "float32")
-            inputs[f"cache_k{i}"] = spec
-            inputs[f"cache_v{i}"] = spec
+        kv = _DENSE
+        caches = _cache_specs(cfg, (batch, cache_cap, cfg.n_kv_heads,
+                                    cfg.d_head), kv_dtype)
     else:
         n_blocks, page_size, max_pages = paged
+        kv = _PAGED_Q if kv8 else _PAGED
         inputs["block_tables"] = TensorSpec((batch, max_pages), "int32")
-        for i in range(cfg.n_layers):
-            spec = TensorSpec((n_blocks, page_size, hk * dh), kv_dtype)
-            inputs[f"cache_k{i}"] = spec
-            inputs[f"cache_v{i}"] = spec
-            if kv8:
-                sspec = TensorSpec((n_blocks, hk), "float32")
-                inputs[f"cache_k{i}_scale"] = sspec
-                inputs[f"cache_v{i}_scale"] = sspec
+        caches = _cache_specs(cfg, _paged_shape(cfg, n_blocks, page_size),
+                              kv_dtype)
+    inputs.update(caches)
 
-    nodes: List[Node] = [Node("embed_lookup", "embedding",
-                              ["tokens", "embed"], ["x0"], dict(prec))]
-    if decode:
-        nodes.append(Node("kv_len", "add", ["start", "n_new"], ["kvlen"]))
-    x = "x0"
-    eps = {"eps": cfg.eps}
-    for i in range(cfg.n_layers):
-        L = f"l{i}"
-        att = dict(prec, window=cfg.window(i)) if cfg.window(i) else prec
-        nodes += [
-            Node(f"{L}.attn_norm", "rmsnorm", [x, f"{L}.norm1"], [f"{L}.h1"], dict(eps)),
-            Node(f"{L}.q_proj", "dense", [f"{L}.h1", f"{L}.wq"], [f"{L}.q"],
-                 dict(prec)),
-            Node(f"{L}.k_proj", "dense", [f"{L}.h1", f"{L}.wk"], [f"{L}.k"],
-                 dict(prec)),
-            Node(f"{L}.v_proj", "dense", [f"{L}.h1", f"{L}.wv"], [f"{L}.v"],
-                 dict(prec)),
-            Node(f"{L}.k_heads", "reshape", [f"{L}.k"], [f"{L}.k4"],
-                 {"shape": (batch, t, hk, dh)}),
-            Node(f"{L}.v_heads", "reshape", [f"{L}.v"], [f"{L}.v4"],
-                 {"shape": (batch, t, hk, dh)}),
-        ]
-        if paged is None:
-            nodes += [
-                Node(f"{L}.k_write", "cache_update",
-                     [f"cache_k{i}", f"{L}.k4", "start", "n_new"],
-                     [f"new_cache_k{i}"]),
-                Node(f"{L}.v_write", "cache_update",
-                     [f"cache_v{i}", f"{L}.v4", "start", "n_new"],
-                     [f"new_cache_v{i}"]),
-            ]
-        elif kv8:
-            # kv8 VERIFY never writes pages: quantize-on-write scales only
-            # grow, and a raise lossily requantizes the whole page, so a
-            # rejected draft row would permanently perturb committed rows
-            # sharing its page.  Attention reads the new rows from the
-            # fp32 k4/v4 instead (two-source) and accepted rows commit via
-            # the separate spec-commit Program.
-            if not verify:
-                nodes += [
-                    Node(f"{L}.k_write", "paged_cache_update_q",
-                         [f"cache_k{i}", f"cache_k{i}_scale", f"{L}.k4",
-                          "block_tables", "start", "n_new"],
-                         [f"new_cache_k{i}", f"new_cache_k{i}_scale"]),
-                    Node(f"{L}.v_write", "paged_cache_update_q",
-                         [f"cache_v{i}", f"cache_v{i}_scale", f"{L}.v4",
-                          "block_tables", "start", "n_new"],
-                         [f"new_cache_v{i}", f"new_cache_v{i}_scale"]),
-                ]
-        else:
-            nodes += [
-                Node(f"{L}.k_write", "paged_cache_update",
-                     [f"cache_k{i}", f"{L}.k4", "block_tables", "start", "n_new"],
-                     [f"new_cache_k{i}"]),
-                Node(f"{L}.v_write", "paged_cache_update",
-                     [f"cache_v{i}", f"{L}.v4", "block_tables", "start", "n_new"],
-                     [f"new_cache_v{i}"]),
-            ]
-        if decode:
-            nodes.append(Node(f"{L}.q_heads", "reshape", [f"{L}.q"],
-                              [f"{L}.qd"], {"shape": (batch, hq, dh)}))
-            if paged is None:
-                nodes.append(Node(
-                    f"{L}.attn", "decode_attention",
-                    [f"{L}.qd", f"new_cache_k{i}", f"new_cache_v{i}", "kvlen"],
-                    [f"{L}.att"]))
-            elif kv8:
-                nodes.append(Node(
-                    f"{L}.attn", "paged_decode_attention_q",
-                    [f"{L}.qd", f"new_cache_k{i}", f"new_cache_k{i}_scale",
-                     f"new_cache_v{i}", f"new_cache_v{i}_scale",
-                     "block_tables", "kvlen"], [f"{L}.att"]))
-            else:
-                nodes.append(Node(
-                    f"{L}.attn", "paged_decode_attention",
-                    [f"{L}.qd", f"new_cache_k{i}", f"new_cache_v{i}",
-                     "block_tables", "kvlen"], [f"{L}.att"], dict(att)))
-        else:
-            nodes.append(Node(f"{L}.q_heads", "reshape", [f"{L}.q"],
-                              [f"{L}.q4"], {"shape": (batch, t, hq, dh)}))
-            # a verify step IS a prefill chunk of T = K+1 rows, but it runs
-            # through the verify_attention op family so the selector can
-            # pick a backend for the verify shape independently; value
-            # names stay identical to the prefill variant, so one
-            # calibration drives both
-            if paged is None:
-                op = "verify_attention" if verify else "chunk_attention"
-                nodes.append(Node(
-                    f"{L}.attn", op,
-                    [f"{L}.q4", f"new_cache_k{i}", f"new_cache_v{i}", "start"],
-                    [f"{L}.att"]))
-            elif kv8:
-                if verify:
-                    nodes.append(Node(
-                        f"{L}.attn", "paged_verify_attention_q",
-                        [f"{L}.q4", f"cache_k{i}", f"cache_k{i}_scale",
-                         f"cache_v{i}", f"cache_v{i}_scale",
-                         "block_tables", "start", f"{L}.k4", f"{L}.v4"],
-                        [f"{L}.att"]))
-                else:
-                    nodes.append(Node(
-                        f"{L}.attn", "paged_chunk_attention_q",
-                        [f"{L}.q4", f"new_cache_k{i}",
-                         f"new_cache_k{i}_scale", f"new_cache_v{i}",
-                         f"new_cache_v{i}_scale", "block_tables", "start"],
-                        [f"{L}.att"]))
-            else:
-                op = ("paged_verify_attention" if verify
-                      else "paged_chunk_attention")
-                nodes.append(Node(
-                    f"{L}.attn", op,
-                    [f"{L}.q4", f"new_cache_k{i}", f"new_cache_v{i}",
-                     "block_tables", "start"], [f"{L}.att"], dict(att)))
-        nodes += [
-            Node(f"{L}.attn_flat", "reshape", [f"{L}.att"], [f"{L}.attn2"],
-                 {"shape": (batch, t, hq * dh)}),
-            Node(f"{L}.o_proj", "dense", [f"{L}.attn2", f"{L}.wo"],
-                 [f"{L}.proj"], dict(prec)),
-            Node(f"{L}.attn_res", "add", [x, f"{L}.proj"], [f"{L}.xa"]),
-            Node(f"{L}.mlp_norm", "rmsnorm", [f"{L}.xa", f"{L}.norm2"],
-                 [f"{L}.h2"], dict(eps)),
-        ]
-        if cfg.n_experts:
-            # rows past n_new (chunk padding, idle slots) are routed to no
-            # expert, so the per-layer counts hold live rows only
-            nodes.append(Node(
-                f"{L}.experts", "moe_ffn",
-                [f"{L}.h2", "n_new", f"{L}.router", f"{L}.we_gate",
-                 f"{L}.we_up", f"{L}.we_down"],
-                [f"{L}.down", f"{L}.moe_counts"],
-                dict(prec, top_k=cfg.top_k, norm_topk=cfg.norm_topk)))
-        else:
-            nodes += [
-                Node(f"{L}.gate_proj", "dense", [f"{L}.h2", f"{L}.wg"],
-                     [f"{L}.gate"], dict(prec)),
-                Node(f"{L}.up_proj", "dense", [f"{L}.h2", f"{L}.wu"],
-                     [f"{L}.up"], dict(prec)),
-                Node(f"{L}.swiglu", "swiglu", [f"{L}.gate", f"{L}.up"],
-                     [f"{L}.act"]),
-                Node(f"{L}.down_proj", "dense", [f"{L}.act", f"{L}.wd"],
-                     [f"{L}.down"], dict(prec)),
-            ]
-        nodes.append(Node(f"{L}.mlp_res", "add", [f"{L}.xa", f"{L}.down"],
-                          [f"{L}.out"]))
-        x = f"{L}.out"
+    nodes: List[Node] = []
+    x = _stack(nodes, cfg, kv, _step_pass(decode), "tokens",
+               batch=batch, t=t)
     if not (decode or verify):
         # the engine reads a prefill chunk's next token at each slot's last
         # new row alone: norm and head run on that row, as in decode
         nodes.append(Node("last_row", "last_new_row", [x, "n_new"],
                           ["x_last"]))
         x = "x_last"
-    nodes.append(Node("final_norm_n", "rmsnorm", [x, "final_norm"],
-                      ["final_h"], dict(eps)))
-    if verify:
-        nodes.append(Node("lm_head", "dense", ["final_h", "head_w"],
-                          ["logits"], dict(prec)))
-    else:
-        nodes += [
-            Node("lm_head", "dense", ["final_h", "head_w"], ["logits3"],
-                 dict(prec)),
-            Node("logits_flat", "reshape", ["logits3"], ["logits"],
-                 {"shape": (batch, cfg.vocab)}),
-        ]
-    outputs = ["logits"]
-    if kv8 and verify:
-        # no page writes happened; hand the fp32 K/V rows of this call's
-        # speculative chunk back to the engine for the post-acceptance
-        # spec-commit write
-        for i in range(cfg.n_layers):
-            outputs += [f"l{i}.k4", f"l{i}.v4"]
-    else:
-        for i in range(cfg.n_layers):
-            outputs += [f"new_cache_k{i}", f"new_cache_v{i}"]
-            if kv8:
-                outputs += [f"new_cache_k{i}_scale",
-                            f"new_cache_v{i}_scale"]
+    _head(nodes, cfg, x, batch=batch, per_row=verify)
+    outputs = ["logits"] + [f"new_{c}" for c in caches]
     if cfg.n_experts:
         # (L, E) int32: live rows routed to each expert of each layer
         nodes.append(Node("moe_counts_all", "concat",
@@ -480,27 +513,25 @@ def build_verify_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
     """Speculative-verify step: tokens (B, width) — the committed next
     token plus up to ``width - 1`` draft proposals per slot — scored
     against the dense cache in one call, returning per-position logits
-    (B, width, V).  Structurally a prefill chunk of T = ``width`` rows
-    (``n_new[b] <= width`` marks the valid prefix, 0 = idle), but the
-    attention runs through ``verify_attention`` so backend selection for
-    the verify shape is independent of the prefill chunk.  Value names
-    match the prefill variant exactly — one calibration drives both, which
-    is what keeps int8 speculative decode token-exact."""
+    (B, width, V).  It is a prefill chunk of T = ``width`` rows
+    (``n_new[b] <= width`` marks the valid prefix, 0 = idle) whose head
+    runs on every row; the backend policy picks the chunk attention's
+    backend at this shape.  Value names match the prefill variant exactly
+    — one calibration drives both, which is what keeps int8 speculative
+    decode token-exact."""
     return _lm_graph(cfg, params, batch=batch, t=width, cache_cap=cache_cap,
                      decode=False, verify=True)
 
 
 def build_paged_verify_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
                              batch: int, width: int, n_blocks: int,
-                             page_size: int, max_pages: int,
-                             kv_dtype: str = "float32") -> Graph:
-    """Paged speculative-verify step — see :func:`build_verify_graph`;
-    cache layout and ``kv_dtype`` as in :func:`build_paged_decode_graph`
-    (``paged_verify_attention`` / ``paged_verify_attention_q``)."""
+                             page_size: int, max_pages: int) -> Graph:
+    """Paged fp32 speculative-verify step — see :func:`build_verify_graph`;
+    cache layout as in :func:`build_paged_decode_graph`.  (The int8 pools'
+    verify is :func:`build_paged_verify_seq_graph`.)"""
     return _lm_graph(cfg, params, batch=batch, t=width,
                      cache_cap=max_pages * page_size, decode=False,
-                     verify=True, paged=(n_blocks, page_size, max_pages),
-                     kv_dtype=kv_dtype)
+                     verify=True, paged=(n_blocks, page_size, max_pages))
 
 
 def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
@@ -516,13 +547,14 @@ def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
     rows would then flip tokens vs a non-speculative run.  This variant
     IS plain decode, stage by stage: stage ``j`` embeds its own token
     input (``tokens.s{j}``), quantize-writes that row in-graph, and runs
-    ``paged_decode_attention_q`` at exactly the decode shapes — so every
-    stage's logits are bit-identical to the decode Program at the same
-    position, dispatched once instead of ``width`` times.  The threaded
-    page state is DISCARDED (it includes later-rejected rows); instead
-    each stage's fp32 ``k4``/``v4`` rows are returned so the spec-commit
-    replay (:func:`build_spec_commit_graph`) can rebuild the accepted
-    prefix of the very same write sequence against the live pages.
+    ``paged_decode_attention_q`` at exactly the decode shapes — the same
+    layer the decode Program runs — so every stage's logits are
+    bit-identical to the decode Program at the same position, dispatched
+    once instead of ``width`` times.  The threaded page state is
+    DISCARDED (it includes later-rejected rows); instead each stage's fp32
+    ``k4``/``v4`` rows are returned so the spec-commit replay
+    (:func:`build_spec_commit_graph`) can rebuild the accepted prefix of
+    the very same write sequence against the live pages.
 
     Stage masks ``n_new.s{j}`` are 1 while ``j`` is inside the slot's fed
     width, else 0 (idle stage: no write, garbage logits, ignored); the
@@ -533,7 +565,6 @@ def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
     if width < 1:
         raise ValueError(f"width must be >= 1, got {width}")
     refuse_beyond_dense(cfg, "the kv8 verify step")
-    dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "start": TensorSpec((batch,), "int32"),
         "block_tables": TensorSpec((batch, max_pages), "int32"),
@@ -541,101 +572,17 @@ def build_paged_verify_seq_graph(cfg: GraphLMConfig, params: Dict[str, Any],
     for j in range(width):
         inputs[f"tokens.s{j}"] = TensorSpec((batch, 1), "int32")
         inputs[f"n_new.s{j}"] = TensorSpec((batch,), "int32")
-    for i in range(cfg.n_layers):
-        spec = TensorSpec((n_blocks, page_size, hk * dh), "int8")
-        sspec = TensorSpec((n_blocks, hk), "float32")
-        inputs[f"cache_k{i}"] = spec
-        inputs[f"cache_v{i}"] = spec
-        inputs[f"cache_k{i}_scale"] = sspec
-        inputs[f"cache_v{i}_scale"] = sspec
+    inputs.update(_cache_specs(cfg, _paged_shape(cfg, n_blocks, page_size),
+                               "int8"))
     p = dict(params)
     p["spec.one"] = np.ones((batch,), np.int32)
     nodes: List[Node] = []
-    eps = {"eps": cfg.eps}
     for j in range(width):
-        sfx = f".s{j}"
-        last = j == width - 1
-        if j == 0:
-            start_name = "start"
-        else:
-            start_name = f"start{sfx}"
-            prev = "start" if j == 1 else f"start.s{j - 1}"
-            nodes.append(Node(f"step_pos{sfx}", "add", [prev, "spec.one"],
-                              [start_name]))
-        nodes += [
-            Node(f"embed_lookup{sfx}", "embedding",
-                 [f"tokens{sfx}", "embed"], [f"x0{sfx}"]),
-            Node(f"kv_len{sfx}", "add", [start_name, f"n_new{sfx}"],
-                 [f"kvlen{sfx}"]),
-        ]
-        x = f"x0{sfx}"
-        for i in range(cfg.n_layers):
-            L = f"l{i}"
-            ck_in = f"cache_k{i}" if j == 0 else f"cache_k{i}{sfx}"
-            cv_in = f"cache_v{i}" if j == 0 else f"cache_v{i}{sfx}"
-            cks_in = (f"cache_k{i}_scale" if j == 0
-                      else f"cache_k{i}_scale{sfx}")
-            cvs_in = (f"cache_v{i}_scale" if j == 0
-                      else f"cache_v{i}_scale{sfx}")
-            ck_out = f"cache_k{i}.sout{j}" if last else f"cache_k{i}.s{j + 1}"
-            cv_out = f"cache_v{i}.sout{j}" if last else f"cache_v{i}.s{j + 1}"
-            cks_out = (f"cache_k{i}_scale.sout{j}" if last
-                       else f"cache_k{i}_scale.s{j + 1}")
-            cvs_out = (f"cache_v{i}_scale.sout{j}" if last
-                       else f"cache_v{i}_scale.s{j + 1}")
-            nodes += [
-                Node(f"{L}.attn_norm{sfx}", "rmsnorm", [x, f"{L}.norm1"],
-                     [f"{L}.h1{sfx}"], dict(eps)),
-                Node(f"{L}.q_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wq"],
-                     [f"{L}.q{sfx}"]),
-                Node(f"{L}.k_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wk"],
-                     [f"{L}.k{sfx}"]),
-                Node(f"{L}.v_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wv"],
-                     [f"{L}.v{sfx}"]),
-                Node(f"{L}.k_heads{sfx}", "reshape", [f"{L}.k{sfx}"],
-                     [f"{L}.k4{sfx}"], {"shape": (batch, 1, hk, dh)}),
-                Node(f"{L}.v_heads{sfx}", "reshape", [f"{L}.v{sfx}"],
-                     [f"{L}.v4{sfx}"], {"shape": (batch, 1, hk, dh)}),
-                Node(f"{L}.k_write{sfx}", "paged_cache_update_q",
-                     [ck_in, cks_in, f"{L}.k4{sfx}", "block_tables",
-                      start_name, f"n_new{sfx}"], [ck_out, cks_out]),
-                Node(f"{L}.v_write{sfx}", "paged_cache_update_q",
-                     [cv_in, cvs_in, f"{L}.v4{sfx}", "block_tables",
-                      start_name, f"n_new{sfx}"], [cv_out, cvs_out]),
-                Node(f"{L}.q_heads{sfx}", "reshape", [f"{L}.q{sfx}"],
-                     [f"{L}.qd{sfx}"], {"shape": (batch, hq, dh)}),
-                Node(f"{L}.attn{sfx}", "paged_decode_attention_q",
-                     [f"{L}.qd{sfx}", ck_out, cks_out, cv_out, cvs_out,
-                      "block_tables", f"kvlen{sfx}"], [f"{L}.att{sfx}"]),
-                Node(f"{L}.attn_flat{sfx}", "reshape", [f"{L}.att{sfx}"],
-                     [f"{L}.attn2{sfx}"], {"shape": (batch, 1, hq * dh)}),
-                Node(f"{L}.o_proj{sfx}", "dense",
-                     [f"{L}.attn2{sfx}", f"{L}.wo"], [f"{L}.proj{sfx}"]),
-                Node(f"{L}.attn_res{sfx}", "add", [x, f"{L}.proj{sfx}"],
-                     [f"{L}.xa{sfx}"]),
-                Node(f"{L}.mlp_norm{sfx}", "rmsnorm",
-                     [f"{L}.xa{sfx}", f"{L}.norm2"], [f"{L}.h2{sfx}"],
-                     dict(eps)),
-                Node(f"{L}.gate_proj{sfx}", "dense",
-                     [f"{L}.h2{sfx}", f"{L}.wg"], [f"{L}.gate{sfx}"]),
-                Node(f"{L}.up_proj{sfx}", "dense",
-                     [f"{L}.h2{sfx}", f"{L}.wu"], [f"{L}.up{sfx}"]),
-                Node(f"{L}.swiglu{sfx}", "swiglu",
-                     [f"{L}.gate{sfx}", f"{L}.up{sfx}"], [f"{L}.act{sfx}"]),
-                Node(f"{L}.down_proj{sfx}", "dense",
-                     [f"{L}.act{sfx}", f"{L}.wd"], [f"{L}.down{sfx}"]),
-                Node(f"{L}.mlp_res{sfx}", "add",
-                     [f"{L}.xa{sfx}", f"{L}.down{sfx}"], [f"{L}.out{sfx}"]),
-            ]
-            x = f"{L}.out{sfx}"
-        nodes += [
-            Node(f"final_norm_n{sfx}", "rmsnorm", [x, "final_norm"],
-                 [f"final_h{sfx}"], dict(eps)),
-            Node(f"lm_head{sfx}", "dense", [f"final_h{sfx}", "head_w"],
-                 [f"logits3{sfx}"]),
-            Node(f"logits_flat{sfx}", "reshape", [f"logits3{sfx}"],
-                 [f"logits{sfx}"], {"shape": (batch, cfg.vocab)}),
-        ]
+        ps = _stage_pass(nodes, j, width, f"n_new.s{j}",
+                         final=lambda c: f"{c}.sout{width - 1}")
+        x = _stack(nodes, cfg, _PAGED_Q, ps, f"tokens{ps.sfx}",
+                   batch=batch, t=1)
+        _head(nodes, cfg, x, batch=batch, sfx=ps.sfx)
     outputs = [f"logits.s{j}" for j in range(width)]
     for j in range(width):
         for i in range(cfg.n_layers):
@@ -675,52 +622,21 @@ def build_spec_commit_graph(cfg: GraphLMConfig, *, batch: int, width: int,
                                                   "float32")
             inputs[f"v_new{i}.s{j}"] = TensorSpec((batch, 1, hk, dh),
                                                   "float32")
-    for i in range(cfg.n_layers):
-        inputs[f"cache_k{i}"] = TensorSpec((n_blocks, page_size, hk * dh),
-                                           "int8")
-        inputs[f"cache_v{i}"] = TensorSpec((n_blocks, page_size, hk * dh),
-                                           "int8")
-        inputs[f"cache_k{i}_scale"] = TensorSpec((n_blocks, hk), "float32")
-        inputs[f"cache_v{i}_scale"] = TensorSpec((n_blocks, hk), "float32")
-    p = {"spec.one": np.ones((batch,), np.int32)}
+    caches = _cache_specs(cfg, _paged_shape(cfg, n_blocks, page_size), "int8")
+    inputs.update(caches)
     nodes: List[Node] = []
     for j in range(width):
-        sfx = f".s{j}"
-        last = j == width - 1
-        if j == 0:
-            start_name = "start"
-        else:
-            start_name = f"start{sfx}"
-            prev = "start" if j == 1 else f"start.s{j - 1}"
-            nodes.append(Node(f"step_pos{sfx}", "add", [prev, "spec.one"],
-                              [start_name]))
+        ps = _stage_pass(nodes, j, width, f"n_new.s{j}")
         for i in range(cfg.n_layers):
-            ck_in = f"cache_k{i}" if j == 0 else f"cache_k{i}{sfx}"
-            cv_in = f"cache_v{i}" if j == 0 else f"cache_v{i}{sfx}"
-            cks_in = (f"cache_k{i}_scale" if j == 0
-                      else f"cache_k{i}_scale{sfx}")
-            cvs_in = (f"cache_v{i}_scale" if j == 0
-                      else f"cache_v{i}_scale{sfx}")
-            ck_out = f"new_cache_k{i}" if last else f"cache_k{i}.s{j + 1}"
-            cv_out = f"new_cache_v{i}" if last else f"cache_v{i}.s{j + 1}"
-            cks_out = (f"new_cache_k{i}_scale" if last
-                       else f"cache_k{i}_scale.s{j + 1}")
-            cvs_out = (f"new_cache_v{i}_scale" if last
-                       else f"cache_v{i}_scale.s{j + 1}")
             nodes += [
-                Node(f"l{i}.k_commit{sfx}", "paged_cache_update_q",
-                     [ck_in, cks_in, f"k_new{i}{sfx}", "block_tables",
-                      start_name, f"n_new{sfx}"], [ck_out, cks_out]),
-                Node(f"l{i}.v_commit{sfx}", "paged_cache_update_q",
-                     [cv_in, cvs_in, f"v_new{i}{sfx}", "block_tables",
-                      start_name, f"n_new{sfx}"], [cv_out, cvs_out]),
+                _kv_write(_PAGED_Q, f"l{i}.k_commit{ps.sfx}", f"cache_k{i}",
+                          f"k_new{i}{ps.sfx}", ps),
+                _kv_write(_PAGED_Q, f"l{i}.v_commit{ps.sfx}", f"cache_v{i}",
+                          f"v_new{i}{ps.sfx}", ps),
             ]
-    outputs: List[str] = []
-    for i in range(cfg.n_layers):
-        outputs += [f"new_cache_k{i}", f"new_cache_v{i}",
-                    f"new_cache_k{i}_scale", f"new_cache_v{i}_scale"]
     g = Graph(name=f"graph_lm_spec_commit_b{batch}_t{width}", inputs=inputs,
-              outputs=outputs, nodes=nodes, params=p)
+              outputs=[f"new_{c}" for c in caches], nodes=nodes,
+              params={"spec.one": np.ones((batch,), np.int32)})
     g.validate()
     return g
 
@@ -759,101 +675,26 @@ def build_draft_graph(cfg: GraphLMConfig, params: Dict[str, Any], *,
     if spec_k + 1 > cache_cap:
         raise ValueError(f"spec_k {spec_k} + 1 exceeds cache cap {cache_cap}")
     refuse_beyond_dense(cfg, "the draft model")
-    dm, dh, hq, hk = cfg.d_model, cfg.d_head, cfg.n_heads, cfg.n_kv_heads
     inputs: Dict[str, TensorSpec] = {
         "tokens": TensorSpec((batch, 1), "int32"),
         "start": TensorSpec((batch,), "int32"),
         "n_new": TensorSpec((batch,), "int32"),
     }
-    for i in range(cfg.n_layers):
-        spec = TensorSpec((batch, cache_cap, hk, dh), "float32")
-        inputs[f"cache_k{i}"] = spec
-        inputs[f"cache_v{i}"] = spec
+    caches = _cache_specs(cfg, (batch, cache_cap, cfg.n_kv_heads, cfg.d_head))
+    inputs.update(caches)
     p = dict(params)
     p["spec.one"] = np.ones((batch,), np.int32)
     nodes: List[Node] = []
-    eps = {"eps": cfg.eps}
     for s in range(spec_k + 1):
-        sfx = f".s{s}"
-        last = s == spec_k
+        ps = _stage_pass(nodes, s, spec_k + 1, "n_new")
         tok = "tokens" if s == 0 else f"draft_tok.s{s - 1}"
-        if s == 0:
-            start_name = "start"
-        else:
-            start_name = f"start{sfx}"
-            prev = "start" if s == 1 else f"start.s{s - 1}"
-            nodes.append(Node(f"step_pos{sfx}", "add", [prev, "spec.one"],
-                              [start_name]))
-        nodes += [
-            Node(f"embed_lookup{sfx}", "embedding", [tok, "embed"],
-                 [f"x0{sfx}"]),
-            Node(f"kv_len{sfx}", "add", [start_name, "n_new"],
-                 [f"kvlen{sfx}"]),
-        ]
-        x = f"x0{sfx}"
-        for i in range(cfg.n_layers):
-            L = f"l{i}"
-            ck_in = f"cache_k{i}" if s == 0 else f"cache_k{i}{sfx}"
-            cv_in = f"cache_v{i}" if s == 0 else f"cache_v{i}{sfx}"
-            ck_out = f"new_cache_k{i}" if last else f"cache_k{i}.s{s + 1}"
-            cv_out = f"new_cache_v{i}" if last else f"cache_v{i}.s{s + 1}"
-            nodes += [
-                Node(f"{L}.attn_norm{sfx}", "rmsnorm", [x, f"{L}.norm1"],
-                     [f"{L}.h1{sfx}"], dict(eps)),
-                Node(f"{L}.q_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wq"],
-                     [f"{L}.q{sfx}"]),
-                Node(f"{L}.k_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wk"],
-                     [f"{L}.k{sfx}"]),
-                Node(f"{L}.v_proj{sfx}", "dense", [f"{L}.h1{sfx}", f"{L}.wv"],
-                     [f"{L}.v{sfx}"]),
-                Node(f"{L}.k_heads{sfx}", "reshape", [f"{L}.k{sfx}"],
-                     [f"{L}.k4{sfx}"], {"shape": (batch, 1, hk, dh)}),
-                Node(f"{L}.v_heads{sfx}", "reshape", [f"{L}.v{sfx}"],
-                     [f"{L}.v4{sfx}"], {"shape": (batch, 1, hk, dh)}),
-                Node(f"{L}.k_write{sfx}", "cache_update",
-                     [ck_in, f"{L}.k4{sfx}", start_name, "n_new"], [ck_out]),
-                Node(f"{L}.v_write{sfx}", "cache_update",
-                     [cv_in, f"{L}.v4{sfx}", start_name, "n_new"], [cv_out]),
-                Node(f"{L}.q_heads{sfx}", "reshape", [f"{L}.q{sfx}"],
-                     [f"{L}.qd{sfx}"], {"shape": (batch, hq, dh)}),
-                Node(f"{L}.attn{sfx}", "decode_attention",
-                     [f"{L}.qd{sfx}", ck_out, cv_out, f"kvlen{sfx}"],
-                     [f"{L}.att{sfx}"]),
-                Node(f"{L}.attn_flat{sfx}", "reshape", [f"{L}.att{sfx}"],
-                     [f"{L}.attn2{sfx}"], {"shape": (batch, 1, hq * dh)}),
-                Node(f"{L}.o_proj{sfx}", "dense",
-                     [f"{L}.attn2{sfx}", f"{L}.wo"], [f"{L}.proj{sfx}"]),
-                Node(f"{L}.attn_res{sfx}", "add", [x, f"{L}.proj{sfx}"],
-                     [f"{L}.xa{sfx}"]),
-                Node(f"{L}.mlp_norm{sfx}", "rmsnorm",
-                     [f"{L}.xa{sfx}", f"{L}.norm2"], [f"{L}.h2{sfx}"],
-                     dict(eps)),
-                Node(f"{L}.gate_proj{sfx}", "dense",
-                     [f"{L}.h2{sfx}", f"{L}.wg"], [f"{L}.gate{sfx}"]),
-                Node(f"{L}.up_proj{sfx}", "dense",
-                     [f"{L}.h2{sfx}", f"{L}.wu"], [f"{L}.up{sfx}"]),
-                Node(f"{L}.swiglu{sfx}", "swiglu",
-                     [f"{L}.gate{sfx}", f"{L}.up{sfx}"], [f"{L}.act{sfx}"]),
-                Node(f"{L}.down_proj{sfx}", "dense",
-                     [f"{L}.act{sfx}", f"{L}.wd"], [f"{L}.down{sfx}"]),
-                Node(f"{L}.mlp_res{sfx}", "add",
-                     [f"{L}.xa{sfx}", f"{L}.down{sfx}"], [f"{L}.out{sfx}"]),
-            ]
-            x = f"{L}.out{sfx}"
-        if not last:
-            nodes += [
-                Node(f"final_norm_n{sfx}", "rmsnorm", [x, "final_norm"],
-                     [f"final_h{sfx}"], dict(eps)),
-                Node(f"lm_head{sfx}", "dense", [f"final_h{sfx}", "head_w"],
-                     [f"logits3{sfx}"]),
-                Node(f"logits_flat{sfx}", "reshape", [f"logits3{sfx}"],
-                     [f"logits{sfx}"], {"shape": (batch, cfg.vocab)}),
-                Node(f"greedy{sfx}", "greedy_token", [f"logits{sfx}"],
-                     [f"draft_tok{sfx}"]),
-            ]
+        x = _stack(nodes, cfg, _DENSE, ps, tok, batch=batch, t=1)
+        if s < spec_k:
+            _head(nodes, cfg, x, batch=batch, sfx=ps.sfx)
+            nodes.append(Node(f"greedy{ps.sfx}", "greedy_token",
+                              [f"logits{ps.sfx}"], [f"draft_tok{ps.sfx}"]))
     outputs = [f"draft_tok.s{s}" for s in range(spec_k)]
-    for i in range(cfg.n_layers):
-        outputs += [f"new_cache_k{i}", f"new_cache_v{i}"]
+    outputs += [f"new_{c}" for c in caches]
     g = Graph(name=f"graph_lm_draft_b{batch}_k{spec_k}", inputs=inputs,
               outputs=outputs, nodes=nodes, params=p)
     g.validate()

@@ -739,8 +739,7 @@ class PagedProgramStepper(ProgramStepper):
                                                 width=w,
                                                 n_blocks=n_blocks,
                                                 page_size=page_size,
-                                                max_pages=max_pages,
-                                                kv_dtype=kv_dtype)
+                                                max_pages=max_pages)
         self._init_spec(params, policy=policy, quantize=quantize,
                         calib_ranges=calib_ranges, spec_k=spec_k,
                         draft_layers=draft_layers, verify_graph=verify_g,

@@ -48,13 +48,11 @@ _MARKER_RE = re.compile(
 # the speculative draft/verify Programs), dense and paged — the §6
 # reference table documents exactly these
 SERVING_OPS = ("embedding", "cache_update", "chunk_attention",
-               "decode_attention", "verify_attention", "greedy_token",
+               "decode_attention", "greedy_token",
                "paged_cache_update",
                "paged_chunk_attention", "paged_decode_attention",
-               "paged_verify_attention",
                "paged_cache_update_q", "paged_chunk_attention_q",
-               "paged_decode_attention_q", "paged_verify_attention_q",
-               "last_new_row")
+               "paged_decode_attention_q", "last_new_row")
 
 
 def _first_line(text: str) -> str:

@@ -257,67 +257,59 @@ def test_engine_runs_under_fixed_pallas_policy():
 
 
 # --------------------------------------------------------------------------- #
-# verify_attention family (speculative decoding) — backend parity and the
-# explicit-zero attr sweep (the PR 4 `attrs.get(...) or default` bug class)
+# the verify shape (speculative decoding) — a verify step is a chunk of
+# T = K+1 rows through chunk_attention / paged_chunk_attention, so every
+# backend must hold at that shape, and the explicit-zero scale with it
+# (the `attrs.get(...) or default` bug class)
 # --------------------------------------------------------------------------- #
 
-def _paged_verify_case(*, b=2, t=4, n_blocks=8, page=4, mp=4, hq=4, hk=2,
-                       d=8, start=(0, 7)):
-    """Flat (N, P, Hk*D) int8 pages under a scrambled block layout + this
-    call's fp32 rows, plus the patched DENSE fp32 equivalent the
-    two-source op must match."""
-    rng = _rng()
-    start = np.asarray(start, np.int32)
-    tables = rng.permutation(n_blocks)[:b * mp].reshape(b, mp).astype(np.int32)
-    dense = rng.standard_normal((n_blocks, page, hk, d)).astype(np.float32)
-    amax = np.abs(dense).max(axis=(1, 3))
-    scales = (amax / 127.0).astype(np.float32)
-    pages = np.clip(np.round(dense / np.where(scales > 0, scales, 1.0)
-                             [:, None, :, None]), -127, 127).astype(np.int8)
-    deq = pages.astype(np.float32) * scales[:, None, :, None]
-    q = rng.standard_normal((b, t, hq, d)).astype(np.float32)
-    k_new = rng.standard_normal((b, t, hk, d)).astype(np.float32)
-    v_new = rng.standard_normal((b, t, hk, d)).astype(np.float32)
-    k_dense = np.stack([deq[tables[bi]].reshape(mp * page, hk, d)
-                        for bi in range(b)])
-    v_dense = k_dense.copy()
+def _np_chunk_attention(q, k, v, start, scale):
+    """Offset-causal attention in float64: query row t of slot b sits at
+    position start[b] + t and sees keys 0 .. start[b] + t; query head h
+    reads KV head h // (Hq // Hk)."""
+    b, t, hq, d = q.shape
+    group = hq // k.shape[2]
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    out = np.zeros(q.shape, np.float64)
     for bi in range(b):
         for ti in range(t):
-            k_dense[bi, start[bi] + ti] = k_new[bi, ti]
-            v_dense[bi, start[bi] + ti] = v_new[bi, ti]
-    pages = pages.reshape(n_blocks, page, hk * d)
-    return (q, pages, scales, pages.copy(), scales.copy(), tables, start,
-            k_new, v_new, k_dense, v_dense)
+            n = start[bi] + ti + 1
+            for h in range(hq):
+                kh = k[bi, :n, h // group].astype(np.float64)
+                logits = kh @ q[bi, ti, h].astype(np.float64) * scale
+                w = np.exp(logits - logits.max())
+                out[bi, ti, h] = (w / w.sum()) @ v[bi, :n, h // group]
+    return out
 
 
 @pytest.mark.parametrize("scale", [None, 0.0, 2.0])
-def test_verify_attention_matches_chunk_attention(scale):
-    """verify_attention IS offset-causal chunk attention at T = K+1 — and
-    an explicit scale=0.0 must survive to every backend (not be swallowed
-    by a falsy-default fallback)."""
-    from repro.kernels.serving_ops import verify_attention
+def test_chunk_attention_at_verify_shape(scale):
+    """T = K+1 = 4 rows, one slot ending at the cache's last row; an
+    explicit scale=0.0 must survive to every backend (not be swallowed by
+    a falsy-default fallback)."""
     rng = _rng()
     q = rng.standard_normal((2, 4, 4, 8)).astype(np.float32)
     k = rng.standard_normal((2, 16, 2, 8)).astype(np.float32)
     v = rng.standard_normal((2, 16, 2, 8)).astype(np.float32)
     start = np.asarray([0, 12], np.int32)
-    want = np.asarray(chunk_attention(q, k, v, start, scale=scale,
-                                      backend="ref"))
+    want = _np_chunk_attention(q, k, v, start, scale)
     for backend in ("ref", "xla", "pallas"):
-        assert backend in backends_for("verify_attention")
-        out = np.asarray(verify_attention(q, k, v, start, scale=scale,
-                                          backend=backend, interpret=True))
+        assert backend in backends_for("chunk_attention")
+        out = np.asarray(chunk_attention(q, k, v, start, scale=scale,
+                                         backend=backend, interpret=True))
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5,
                                    err_msg=f"{backend} scale={scale}")
     if scale == 0.0:
-        dflt = np.asarray(verify_attention(q, k, v, start, backend="ref"))
+        dflt = np.asarray(chunk_attention(q, k, v, start, backend="ref"))
         assert not np.allclose(want, dflt), \
             "scale=0.0 was swallowed by a falsy default"
 
 
 @pytest.mark.parametrize("scale,hq,hk,d", paged_head_cases(None, 0.0))
-def test_paged_verify_attention_backend_parity(scale, hq, hk, d):
-    from repro.kernels.serving_ops import paged_verify_attention
+def test_paged_chunk_attention_at_verify_shape(scale, hq, hk, d):
+    """T = K+1 = 4 rows read through a scrambled block table: every
+    backend equals offset-causal attention over the gathered pages."""
+    from repro.kernels.serving_ops import paged_chunk_attention
     rng = _rng()
     b, t, n_blocks, page, mp = 2, 4, 8, 4, 4
     q = rng.standard_normal((b, t, hq, d)).astype(np.float32)
@@ -330,52 +322,34 @@ def test_paged_verify_attention_backend_parity(scale, hq, hk, d):
                    for bi in range(b)])
     vd = np.stack([pv[tables[bi]].reshape(mp * page, hk, d)
                    for bi in range(b)])
-    want = np.asarray(chunk_attention(q, kd, vd, start, scale=scale,
-                                      backend="ref"))
+    want = _np_chunk_attention(q, kd, vd, start, scale)
     flat = (n_blocks, page, hk * d)
     for backend in ("ref", "xla", "pallas"):
-        assert backend in backends_for("paged_verify_attention")
-        out = np.asarray(paged_verify_attention(
+        assert backend in backends_for("paged_chunk_attention")
+        out = np.asarray(paged_chunk_attention(
             q, pk.reshape(flat), pv.reshape(flat), tables, start,
             scale=scale, backend=backend, interpret=True))
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5,
                                    err_msg=f"{backend} scale={scale}")
 
 
-@pytest.mark.parametrize("scale,hq,hk,d", paged_head_cases(None, 0.0))
-def test_paged_verify_attention_q_two_source_parity(scale, hq, hk, d):
-    """The two-source kv8 verify op: committed prefix dequantized from the
-    int8 pages, this call's K+1 rows patched in from fp32 — all backends
-    must match the patched-dense fp32 oracle, scale=0.0 included."""
-    from repro.kernels.serving_ops import paged_verify_attention_q
-    (q, pk, ks, pv, vs, tables, start, k_new, v_new,
-     k_dense, v_dense) = _paged_verify_case(hq=hq, hk=hk, d=d)
-    want = np.asarray(chunk_attention(q, k_dense, v_dense, start,
-                                      scale=scale, backend="ref"))
-    for backend in ("ref", "xla", "pallas"):
-        assert backend in backends_for("paged_verify_attention_q")
-        out = np.asarray(paged_verify_attention_q(
-            q, pk, ks, pv, vs, tables, start, k_new, v_new, scale=scale,
-            backend=backend, interpret=True))
-        np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5,
-                                   err_msg=f"{backend} scale={scale}")
-
-
-def test_verify_attention_pallas_supports_guards():
-    """Ragged T must filter the pallas paths out, never crash them."""
+def test_chunk_attention_pallas_guards_at_verify_shape():
+    """A ragged verify width (T = 3 with block_q = 2) must filter the
+    pallas paths of both chunk ops out, never crash them."""
     from repro.core.ir import TensorSpec
-    # T=3 with block_q=2: 3 % 2 != 0
     dense = [TensorSpec((1, 3, 2, 8)), TensorSpec((1, 16, 1, 8)),
              TensorSpec((1, 16, 1, 8)), TensorSpec((1,), "int32")]
-    avail = backends_for("verify_attention", dense, {"block_q": 2})
+    avail = backends_for("chunk_attention", dense, {"block_q": 2})
     assert "pallas" not in avail and {"ref", "xla"} <= set(avail)
-    qspecs = [TensorSpec((1, 3, 2, 8)), TensorSpec((8, 4, 8), "int8"),
-              TensorSpec((8, 1)), TensorSpec((8, 4, 8), "int8"),
-              TensorSpec((8, 1)), TensorSpec((1, 4), "int32"),
-              TensorSpec((1,), "int32"), TensorSpec((1, 3, 1, 8)),
-              TensorSpec((1, 3, 1, 8))]
-    avail = backends_for("paged_verify_attention_q", qspecs, {"block_q": 2})
+    paged = [TensorSpec((1, 3, 2, 8)), TensorSpec((8, 8, 8)),
+             TensorSpec((8, 8, 8)), TensorSpec((1, 4), "int32"),
+             TensorSpec((1,), "int32")]
+    avail = backends_for("paged_chunk_attention", paged, {"block_q": 2})
     assert "pallas" not in avail and {"ref", "xla"} <= set(avail)
+    # the same specs at an even width keep pallas, so the guard is T's
+    paged[0] = TensorSpec((1, 4, 2, 8))
+    assert "pallas" in backends_for("paged_chunk_attention", paged,
+                                    {"block_q": 2})
 
 
 def test_greedy_token_argmax():
